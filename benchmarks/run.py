@@ -42,6 +42,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import place_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -57,6 +59,7 @@ def main() -> None:
     ap.add_argument("--json-dir", default=None,
                     help="write BENCH_<SECTION>.json files here")
     args = ap.parse_args()
+    place_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     section_rows = {}

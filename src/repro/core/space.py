@@ -22,7 +22,7 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.hw.profiles import HardwareProfile, active_profile, dtype_bytes
+from repro.hw.profiles import HardwareProfile, active_profile
 
 Config = Dict[str, int]
 
@@ -154,20 +154,21 @@ class SearchSpace:
 # Constraint builders shared by the kernel spaces
 # ---------------------------------------------------------------------------
 
-def vmem_fits(bytes_per_elem: int, buffers: int = 2,
-              spec: Optional[HardwareProfile] = None):
-    """Double-buffered fast-memory footprint must fit the profile's budget.
+def plan_fits(spec: Optional[HardwareProfile] = None):
+    """Every launch of the config's StagePlan fits the scoped VMEM the
+    kernels are compiled under (``vmem_budget``).
 
-    footprint = rows_per_program * tile_n * bytes_per_elem * buffers
-    The analogue of the paper's 48KB shared-memory-per-block constraint
-    (which is literally what it becomes under the ``gpu_sm`` profile).
+    The plan counts the double-buffered pipeline blocks, the scratch and
+    the temporaries of the in-kernel fold, so a config this admits is one
+    Mosaic accepts — the tuners can never choose a kernel that does not
+    compile.
     """
     spec = spec if spec is not None else active_profile()
 
     def check(cfg: Config, wl: Workload) -> bool:
-        tile_n = cfg.get("tile_n", wl.n)
-        rows = cfg.get("rows_per_program", 1)
-        return rows * tile_n * bytes_per_elem * buffers <= spec.vmem_budget
+        # late import: the planner builds on this module
+        from repro.kernels.blocks.plan import plan_for
+        return plan_for(wl, cfg, profile=spec).vmem_bytes <= spec.vmem_budget
 
     return check
 
@@ -225,7 +226,6 @@ def in_register_rule(spec: Optional[HardwareProfile] = None):
 def scan_space(wl: Workload,
                spec: Optional[HardwareProfile] = None) -> SearchSpace:
     spec = spec if spec is not None else active_profile()
-    eb = dtype_bytes(wl.dtype)
     max_rows = floor_pow2(min(512, max(wl.batch, 1)))
     # variant-aware knob pruning: the linrec kernel's fold order is fixed
     # by the (a, b) composition algebra, so sweeping `unroll` there only
@@ -251,11 +251,11 @@ def scan_space(wl: Workload,
         wl,
         params,
         constraints=(
-            vmem_fits(eb, spec=spec),
             tile_divides_n(),
             rows_divide_batch(),
             radix_compatible(),
             in_register_rule(spec),
+            plan_fits(spec),
         ),
         spec=spec,
     )
@@ -271,8 +271,6 @@ def linrec_space(wl: Workload,
 def tridiag_space(wl: Workload,
                   spec: Optional[HardwareProfile] = None) -> SearchSpace:
     spec = spec if spec is not None else active_profile()
-    # each element is an equation: 4 coefficients (a,b,c,d)
-    eb = 4 * dtype_bytes(wl.dtype)
     if wl.variant in ("cr", "lf", "thomas"):
         # these variants consume no tuned knobs at all (XLA-fused solves);
         # a singleton space keeps sweeps/datasets free of duplicate configs
@@ -283,7 +281,7 @@ def tridiag_space(wl: Workload,
             ParamSpec("unroll", (1,)),
             ParamSpec("in_register", (0,)),
         ]
-        return SearchSpace(wl, params, constraints=(vmem_fits(eb, spec=spec),),
+        return SearchSpace(wl, params, constraints=(plan_fits(spec),),
                            spec=spec)
     max_rows = floor_pow2(min(256, max(wl.batch, 1)))
     radix_dom = (2, 4, 8) if wl.variant == "wm" else (2,)  # paper: only WM retunes r
@@ -303,10 +301,10 @@ def tridiag_space(wl: Workload,
         wl,
         params,
         constraints=(
-            vmem_fits(eb, spec=spec),
             rows_divide_batch(),
             radix_compatible(),
             in_register_rule(spec),
+            plan_fits(spec),
         ),
         spec=spec,
     )
@@ -315,7 +313,6 @@ def tridiag_space(wl: Workload,
 def fft_space(wl: Workload,
               spec: Optional[HardwareProfile] = None) -> SearchSpace:
     spec = spec if spec is not None else active_profile()
-    eb = 2 * dtype_bytes(wl.dtype)  # complex: interleaved re/im
     max_rows = floor_pow2(min(256, max(wl.batch, 1)))
     params = [
         ParamSpec("tile_n", (wl.n,)),
@@ -327,8 +324,8 @@ def fft_space(wl: Workload,
     return SearchSpace(
         wl,
         params,
-        constraints=(vmem_fits(eb, spec=spec), rows_divide_batch(),
-                     radix_compatible()),
+        constraints=(rows_divide_batch(), radix_compatible(),
+                     plan_fits(spec)),
         spec=spec,
     )
 
@@ -341,7 +338,6 @@ def large_fft_space(wl: Workload, max_tile: int = 4096,
     the per-pass working-set S; m = ceil(log(N)/log(S)).
     """
     spec = spec if spec is not None else active_profile()
-    eb = 2 * dtype_bytes(wl.dtype)
     max_rows = floor_pow2(min(64, max(wl.batch, 1)))
     tiles = tuple(v for v in pow2_range(256, max_tile))
     params = [
@@ -358,8 +354,8 @@ def large_fft_space(wl: Workload, max_tile: int = 4096,
     return SearchSpace(
         wl,
         params,
-        constraints=(vmem_fits(eb, spec=spec), rows_divide_batch(),
-                     radix_compatible(), tile_le_n),
+        constraints=(rows_divide_batch(), radix_compatible(), tile_le_n,
+                     plan_fits(spec)),
         spec=spec,
     )
 
@@ -383,16 +379,12 @@ def attention_space(wl: Workload,
         ParamSpec("in_register", (0,)),
     ]
 
-    def blocks_fit(cfg: Config, w: Workload) -> bool:
-        head_dim = 128
-        eb = 2  # bf16
-        # q-block + k-block + v-block + scores
-        foot = (cfg["block_q"] + 2 * cfg["block_k"]) * head_dim * eb
-        foot += cfg["block_q"] * cfg["block_k"] * 4
-        return foot * 2 <= spec.vmem_budget and cfg["block_k"] <= w.n \
-            and cfg["block_q"] <= w.n
+    def blocks_within_n(cfg: Config, w: Workload) -> bool:
+        return cfg["block_k"] <= w.n and cfg["block_q"] <= w.n
 
-    return SearchSpace(wl, params, constraints=(blocks_fit,), spec=spec)
+    return SearchSpace(wl, params,
+                       constraints=(blocks_within_n, plan_fits(spec)),
+                       spec=spec)
 
 
 def matmul_space(wl: Workload,

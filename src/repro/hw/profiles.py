@@ -29,16 +29,19 @@ glossary and how to add a device):
 * ``cpu_interpret`` — the pallas interpret-mode host, so the profile
   layer is exercisable in CI without accelerators.
 
-``active_profile()`` resolves ``$REPRO_HW_PROFILE`` (default
-``tpu_v5e``), which is how the CI profile matrix retargets the whole
-stack without touching call sites.
+``active_profile()`` resolves ``$REPRO_HW_PROFILE`` when it is set,
+which is how the CI profile matrix retargets the whole stack without
+touching call sites.  Otherwise a process whose JAX backend is a TPU gets
+the profile registered for its ``device_kind`` (an unknown kind raises),
+and every other process models ``tpu_v5e``.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 import os
-from typing import Dict, Tuple
+import sys
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -66,8 +69,10 @@ class HardwareProfile:
     # --- memory hierarchy ---
     hbm_bytes: int = 16 * 2**30           # device memory per chip
     vmem_bytes: int = 128 * 2**20         # fast on-chip scratch pool
-    vmem_budget: int = 64 * 2**20         # usable budget for kernel
-    #                                       working sets (SearchSpace bound)
+    vmem_budget: int = 32 * 2**20         # scoped VMEM every kernel compiles
+    #                                       under (passed to Mosaic as
+    #                                       vmem_limit_bytes) and the bound
+    #                                       on each launch's planned VMEM
     # --- tiling geometry ---
     lane_count: int = 128                 # trailing vector dim (warp width
     #                                       on GPU, SIMD lanes on CPU)
@@ -108,9 +113,9 @@ GPU_SM = HardwareProfile(
     ici_link_bandwidth=600e9,             # NVLink
     hbm_bytes=40 * 2**30,
     vmem_bytes=40 * 2**20,                # L2 slice + SMEM pool
-    vmem_budget=512 * 2**10,              # per-CTA staging budget (SMEM +
-    #                                       register file the scheduler can
-    #                                       keep resident per program)
+    vmem_budget=8 * 2**20,                # per-program staging budget (SMEM,
+    #                                       register file and the L2 spill
+    #                                       one program keeps resident)
     lane_count=32,                        # warp width
     sublane_count=4,                      # scheduler partitions per SM
     mxu_dim=16,                           # tensor-core tile edge
@@ -178,13 +183,51 @@ def profiles() -> Tuple[str, ...]:
     return tuple(sorted(_PROFILES))
 
 
-def active_profile() -> HardwareProfile:
-    """The process-wide default profile: ``$REPRO_HW_PROFILE`` or tpu_v5e.
+# ``device_kind`` strings JAX reports for a chip -> the profile modeling it
+_DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+}
 
-    Read per call (cheap dict lookups), so tests and the CI matrix can
-    retarget the stack by environment without import-order traps.
+
+def profile_for_device_kind(kind: str) -> HardwareProfile:
+    """The profile of an attached TPU; a kind with no profile raises, since
+    planning it as another chip would compile kernels for the wrong VMEM."""
+    try:
+        return get_profile(_DEVICE_KINDS[kind])
+    except KeyError:
+        raise ValueError(f"no hardware profile for TPU device_kind {kind!r}; "
+                         f"known kinds: {', '.join(sorted(_DEVICE_KINDS))}"
+                         ) from None
+
+
+def _attached_tpu_kind() -> Optional[str]:
+    """``device_kind`` of the TPU this process computes on, else None.
+
+    A process that has not imported JAX runs no kernel, so it is never
+    asked to initialize a backend here.
     """
-    return get_profile(os.environ.get("REPRO_HW_PROFILE", "tpu_v5e"))
+    jax = sys.modules.get("jax")
+    if jax is None or jax.default_backend() != "tpu":
+        return None
+    return jax.devices()[0].device_kind
+
+
+def active_profile() -> HardwareProfile:
+    """The process-wide default profile.
+
+    ``$REPRO_HW_PROFILE`` wins when set; else an attached TPU's
+    ``device_kind`` picks it (unknown kinds raise); else ``tpu_v5e``, the
+    model CPU hosts plan and test against.  Read per call, so tests and the
+    CI matrix can retarget the stack by environment without import-order
+    traps.
+    """
+    name = os.environ.get("REPRO_HW_PROFILE")
+    if name is not None:
+        return get_profile(name)
+    kind = _attached_tpu_kind()
+    if kind is not None:
+        return profile_for_device_kind(kind)
+    return get_profile("tpu_v5e")
 
 
 for _p in (TPU_V5E, GPU_SM, CPU_INTERPRET):

@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.space import Workload
-from repro.kernels._compat import CompilerParams
 from repro.kernels.blocks.plan import Launch, StagePlan
+from repro.kernels.blocks.primitives import compiler_params
 
 _TRACE = threading.local()
 
@@ -140,7 +140,7 @@ def _apply_add(y, entry, *, rows: int, interpret: bool):
                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(y, entry)
 
@@ -156,7 +156,7 @@ def _apply_linrec(h, prod, entry, *, rows: int, interpret: bool):
         in_specs=[row_spec, row_spec, pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(h, prod, entry)
 

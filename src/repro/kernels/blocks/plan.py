@@ -6,8 +6,16 @@ compositions of the *same* tuned CTA-level building blocks (radix-r
 staging, layout shuffles, carry chaining).  The repo analogue: given
 ``(Workload, Config)`` this module produces the exact staged execution —
 the per-stage radix sequence (with the mixed-radix ragged final stage),
-the launch grid / block shapes / scratch, the per-stage VMEM bytes, and
+the launch grid / block shapes / scratch, each launch's VMEM bytes, and
 the HBM pass count (== number of kernel launches the driver performs).
+
+A launch's VMEM is what Mosaic allocates for it: the double-buffered
+pipeline blocks, the scratch, and the temporaries of the in-kernel fold,
+every buffer padded to the (sublane, lane) tile.  The temporaries are
+bounds fitted to compiles for a described TPU v5e (the largest live set
+of each fold, in blocks); ``HardwareProfile.vmem_budget`` is the limit
+the kernels are compiled under, so a plan within it is a kernel the
+compiler accepts.
 
 It is the single source of truth: the kernel drivers execute
 ``plan.launches`` verbatim, ``core.analytical.resources`` reads its
@@ -50,6 +58,27 @@ DEFAULT_SEQ_LIMIT = 64
 # Variants whose in-kernel state is an (a, b) pair: three resident planes
 # (two inputs + output) instead of two.
 _LINREC_VARIANTS = ("linrec",)
+
+# f32 (rows, tile) temporaries alive at the peak of one fold stage of
+# fan-in r, beyond the pipeline buffers: the r - 1 shifted neighbours plus
+# the accumulator (prefix sum), or shifted (a, b) pairs plus both
+# accumulators (linear recurrence).  Fitted with margin to the compiler's
+# scoped allocations on v5e (r = 2, 4, 8; tiles of 128 to 4096 lanes).
+_ADD_FOLD_TEMPS = 1          # + r
+_LINREC_FOLD_TEMPS = 4       # + 2 r
+# a radix-r DIF stage keeps its 2r - 1 shifted (re, im) neighbours and
+# their sublane-broadcast coefficient rows live
+_FFT_FOLD_TEMPS = 10         # + 4 r
+# PCR keeps the four coefficient planes, their eight shifted neighbours
+# and the two elimination factors live in one step
+_PCR_TEMPS = 18
+# the SSD chunk kernels hold a few (Q, Q) f32 score/decay tiles
+_SSD_QQ_TEMPS = 4
+# flash attention holds the (block_q, block_k) scores and probabilities
+_FLASH_QK_TEMPS = 2
+# head / state widths a Workload does not carry: the planner assumes one
+# lane tile (the registered archs' head_dim, ssm head_dim and state <= 128)
+_MODEL_MINOR = 128
 
 
 # ---------------------------------------------------------------------------
@@ -115,14 +144,46 @@ def is_ragged(stages: Tuple[int, ...], nominal: int, span: int) -> bool:
     return bool(stages) and stages[-1] != min(nominal, span)
 
 
+def _round_up(v: int, m: int) -> int:
+    return -(-max(int(v), 1) // m) * m
+
+
+def vmem_tile_bytes(rows: int, cols: int, itemsize: int,
+                    spec: HardwareProfile) -> int:
+    """Bytes one (rows, cols) VMEM buffer occupies: both minor dims pad to
+    the profile's (sublane, lane) tile, so a (rows, 1) carry column costs
+    a full lane tile per row group."""
+    return (_round_up(rows, spec.sublane_count)
+            * _round_up(cols, spec.lane_count) * itemsize)
+
+
+def fold_vmem(rows: int, tile: int, stages: Tuple[int, ...], *, linrec: bool,
+              io_planes: int, io_bytes: int, spec: HardwareProfile) -> int:
+    """VMEM of one staged-fold launch over (rows, tile) blocks.
+
+    Double-buffered pipeline blocks for every operand, the f32 carry
+    column, and the fold's widest stage of f32 temporaries.
+    """
+    fan = max(stages, default=1)
+    temps = (2 * fan + _LINREC_FOLD_TEMPS) if linrec \
+        else (fan + _ADD_FOLD_TEMPS)
+    return (2 * io_planes * vmem_tile_bytes(rows, tile, io_bytes, spec)
+            + vmem_tile_bytes(rows, 1, 4, spec)
+            + temps * vmem_tile_bytes(rows, tile, 4, spec))
+
+
 def resident_tile_cap(wl: Workload,
                       spec: Optional[HardwareProfile] = None) -> int:
-    """Largest power-of-two tile whose double-buffered footprint fits VMEM
-    with at least one problem row per program (paper §IV-C boundary)."""
+    """Largest power-of-two FFT length (from 256, at most ``wl.n``) whose
+    smallest resident launch — one problem row, radix 2 — fits the VMEM
+    the kernels compile under: the paper's §IV-C boundary between the
+    resident kernel and the four-step path."""
     spec = spec if spec is not None else active_profile()
-    eb = dtype_bytes(wl.dtype) * (2 if wl.op in ("fft", "large_fft") else 1)
     tile = 256
-    while tile * 2 * eb * 2 <= spec.vmem_budget and tile * 2 <= wl.n:
+    while tile * 2 <= wl.n and _fft_fused_plan(
+            Workload(op="fft", n=tile * 2, batch=1, dtype=wl.dtype),
+            {"rows_per_program": 1, "radix": 2}, spec).vmem_bytes \
+            <= spec.vmem_budget:
         tile *= 2
     return tile
 
@@ -149,7 +210,8 @@ class Launch:
     grid: Tuple[int, ...]           # pallas grid
     block_shape: Tuple[int, int]    # main operand block (rows, cols)
     stages: Tuple[int, ...]         # in-kernel stage radices
-    vmem_bytes: int                 # resident io + scratch per program
+    vmem_bytes: int                 # pipeline buffers + scratch + fold
+    #                                 temporaries, tile-padded
 
     @property
     def programs(self) -> int:
@@ -182,8 +244,7 @@ class StagePlan:
     passes: int                     # HBM roundtrips == len(launches) +
     #                                 xla_passes when pallas-backed; 1 for
     #                                 fused XLA variants
-    vmem_bytes: int                 # peak resident io+scratch per program
-    stage_vmem_bytes: Tuple[int, ...]   # transient footprint per stage
+    vmem_bytes: int                 # peak launch VMEM (Launch.vmem_bytes)
     block_bytes: int                # DMA block (analytical rank input)
     element_bytes: int              # effective bytes per logical element
     trailing: int                   # trailing-dim extent a VPU issue sees
@@ -217,9 +278,10 @@ class StagePlan:
         valid config of every op x profile: a violation here means the
         planner would hand the drivers an execution that cannot launch
         (non-positive grid/block), mis-reshapes (stage product != tile),
-        overflows the physical VMEM pool, or disagrees with its own pass
-        accounting.  Checks live on the dataclass so plan builders and the
-        analysis pass can never drift apart.
+        disagrees with its own pass accounting, or plans more VMEM for a
+        launch than the limit kernels compile under.  Checks live on the
+        dataclass so plan builders and the analysis pass can never drift
+        apart.
         """
         out: List[str] = []
         if self.tile_n < 1 or self.rows < 1:
@@ -251,19 +313,15 @@ class StagePlan:
                     or any(b < 1 for b in launch.block_shape):
                 out.append(f"launch {launch.name}: non-positive shape "
                            f"grid={launch.grid} block={launch.block_shape}")
-            if launch.vmem_bytes > spec.vmem_bytes:
+            if launch.vmem_bytes > spec.vmem_budget:
                 out.append(f"launch {launch.name}: vmem {launch.vmem_bytes} "
-                           f"exceeds the physical pool {spec.vmem_bytes}")
+                           f"exceeds the compile limit {spec.vmem_budget}")
             block = launch.block_shape[0] * launch.block_shape[1] \
                 * self.element_bytes
             if launch.vmem_bytes < block:
                 out.append(f"launch {launch.name}: scratch {launch.vmem_bytes}"
                            f" cannot hold its own BlockSpec block {block} "
                            f"({launch.block_shape} x {self.element_bytes}B)")
-        if self.stage_vmem_bytes \
-                and max(self.stage_vmem_bytes) > spec.vmem_bytes:
-            out.append(f"stage vmem {max(self.stage_vmem_bytes)} exceeds "
-                       f"the physical pool {spec.vmem_bytes}")
         return out
 
     def resources(self) -> Dict[str, float]:
@@ -320,11 +378,9 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     # unfused, the XLA gate materializes b = sqrt(1-a^2)*u through HBM —
     # one extra pass that never shows up as a pallas launch
     gate_xla = 1 if wl.op == "rglru" and not int(cfg.get("fuse", 0)) else 0
-    planes = 3 if _is_linrec(wl) else 2          # (a, b) in + h out vs in + out
-    carry = rows * 4                             # f32 cross-tile carry scratch
-    io = planes * rows * tile_n * ib
+    linrec = _is_linrec(wl)
+    planes = 3 if linrec else 2                  # (a, b) in + h out vs in + out
     trailing, lane, sub, occ = _occ(tile_n, rows, spec)
-    stage_vmem = tuple(io + carry + r * rows * tile_n * 4 for r in stages)
     ragged = is_ragged(stages, radix, tile_n)
 
     if seq_tiles > seq_limit and tile_n < wl.n:
@@ -333,16 +389,21 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         rows1 = fit_block(rows, batch * p)
         rows2 = fit_block(rows, batch)
         c_stages = stage_radices(p, radix)
-        # linrec's chunk kernel (scan_linrec_prod_pallas) keeps a fourth
-        # plane resident: the per-chunk prefix-products output the carry
-        # scan composes
-        l1_planes = planes + (1 if planes == 3 else 0)
+        # the driver runs every launch in f32.  linrec's chunk kernel
+        # (scan_linrec_prod_pallas) keeps a fourth plane resident: the
+        # per-chunk prefix-products output the carry scan composes; the
+        # apply reads the chunks plus one entry column
         l1 = Launch("chunk-scan", (batch * p // rows1, 1), (rows1, length),
-                    stages, l1_planes * rows1 * length * ib + rows1 * 4)
+                    stages, fold_vmem(rows1, length, stages, linrec=linrec,
+                                      io_planes=planes + (1 if linrec else 0),
+                                      io_bytes=4, spec=spec))
         l2 = Launch("carry-scan", (batch // rows2, 1), (rows2, p),
-                    c_stages, planes * rows2 * p * ib + rows2 * 4)
+                    c_stages, fold_vmem(rows2, p, c_stages, linrec=linrec,
+                                        io_planes=planes, io_bytes=4,
+                                        spec=spec))
         l3 = Launch("apply-entry", (batch * p // rows1,), (rows1, length),
-                    (), (planes + 1) * rows1 * length * ib)
+                    (), 2 * (planes * vmem_tile_bytes(rows1, length, 4, spec)
+                             + vmem_tile_bytes(rows1, 1, 4, spec)))
         launches = (l1, l2, l3)
         return StagePlan(
             op=wl.op, variant=wl.variant, n=wl.n, batch=batch, dtype=wl.dtype,
@@ -351,20 +412,21 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
             launches=launches, passes=len(launches) + gate_xla,
             xla_passes=gate_xla,
             vmem_bytes=max(l.vmem_bytes for l in launches),
-            stage_vmem_bytes=stage_vmem,
             block_bytes=rows * tile_n * eb, element_bytes=eb,
             trailing=trailing, lane_eff=lane, sublane_eff=sub, occupancy=occ,
             ilp=unroll * (2 if cfg.get("in_register") else 1), ragged=ragged,
             steps_per_pass=float(len(stages)))
 
     grid = (batch // rows, seq_tiles)
-    launch = Launch(wl.op, grid, (rows, tile_n), stages, io + carry)
+    launch = Launch(wl.op, grid, (rows, tile_n), stages,
+                    fold_vmem(rows, tile_n, stages, linrec=linrec,
+                              io_planes=planes, io_bytes=ib, spec=spec))
     return StagePlan(
         op=wl.op, variant=wl.variant, n=wl.n, batch=batch, dtype=wl.dtype,
         kind="fused", tile_n=tile_n, rows=rows, radix=radix, stages=stages,
         seq_tiles=seq_tiles, grid=grid, launches=(launch,),
         passes=1 + gate_xla, xla_passes=gate_xla,
-        vmem_bytes=launch.vmem_bytes, stage_vmem_bytes=stage_vmem,
+        vmem_bytes=launch.vmem_bytes,
         block_bytes=rows * tile_n * eb, element_bytes=eb, trailing=trailing,
         lane_eff=lane, sublane_eff=sub, occupancy=occ,
         ilp=unroll * (2 if cfg.get("in_register") else 1), ragged=ragged,
@@ -389,29 +451,35 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     base = _prefix_plan(wl, cfg, spec, seq_limit)
     chunk = base.tile_n
     nc = max(wl.n // max(chunk, 1), 1)
+    # every chunk kernel holds (Q, Q) f32 tiles plus up to seven
+    # double-buffered (Q, width) operand blocks (x, decay column and row,
+    # B, C, y, state)
+    chunk_vmem = (2 * 7 * vmem_tile_bytes(chunk, _MODEL_MINOR, 4, spec)
+                  + _SSD_QQ_TEMPS * vmem_tile_bytes(chunk, chunk, 4, spec))
+    intra = Launch("ssd-intra", (base.batch, nc), (1, chunk), (), chunk_vmem)
     if nc <= 1:
         # single chunk: intra kernel alone already yields the answer
-        return dataclasses.replace(base, kind="fused", seq_tiles=1)
-    intra = Launch("ssd-intra", (base.batch, nc), (1, chunk), (),
-                   base.vmem_bytes)
+        return dataclasses.replace(base, kind="fused", seq_tiles=1,
+                                   launches=(intra,), vmem_bytes=chunk_vmem)
     if int(cfg.get("fuse", 0)):
         state_apply = Launch("ssd-state-apply", (base.batch, nc),
-                             (1, chunk), (), base.vmem_bytes)
+                             (1, chunk), (), chunk_vmem)
         launches = (intra, state_apply)
         return dataclasses.replace(
             base, kind="two-phase", seq_tiles=nc, launches=launches,
-            passes=len(launches), children=())
+            passes=len(launches), vmem_bytes=chunk_vmem, children=())
     child = _prefix_plan(
         Workload(op="scan", n=nc, batch=base.batch, dtype=wl.dtype,
                  variant="linrec"),
         {"tile_n": nc, "rows_per_program": 1,
          "radix": cfg.get("radix", 2)}, spec, seq_limit)
     apply_ = Launch("ssd-apply", (base.batch, nc), (1, chunk), (),
-                    base.vmem_bytes)
+                    chunk_vmem)
     launches = (intra,) + child.launches + (apply_,)
     return dataclasses.replace(
         base, kind="three-phase", seq_tiles=nc, launches=launches,
-        passes=len(launches), children=(child,))
+        passes=len(launches), vmem_bytes=max(l.vmem_bytes for l in launches),
+        children=(child,))
 
 
 def _tridiag_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
@@ -428,16 +496,17 @@ def _tridiag_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
     if wl.variant == "pcr":
         steps = max(1, math.ceil(math.log2(max(n, 2))))
         stages = (2,) * steps
-        io = 5 * rows * n * ib                 # a,b,c,d in + x out
+        # a,b,c,d in + x out, double-buffered, plus one step's temporaries
+        vmem = (2 * 5 * vmem_tile_bytes(rows, n, ib, spec)
+                + _PCR_TEMPS * vmem_tile_bytes(rows, n, 4, spec))
         grid = (batch // rows,)
-        launch = Launch("pcr", grid, (rows, n), stages, io)
+        launch = Launch("pcr", grid, (rows, n), stages, vmem)
         return StagePlan(
             op=wl.op, variant=wl.variant, n=n, batch=batch, dtype=wl.dtype,
             kind="fused", tile_n=n, rows=rows, radix=2, stages=stages,
             seq_tiles=1, grid=grid, launches=(launch,), passes=1,
-            vmem_bytes=io,
-            stage_vmem_bytes=tuple(io + 2 * rows * n * 4 for _ in stages),
-            block_bytes=rows * n * eb, element_bytes=eb, trailing=trailing,
+            vmem_bytes=vmem, block_bytes=rows * n * eb, element_bytes=eb,
+            trailing=trailing,
             lane_eff=lane, sublane_eff=sub, occupancy=occ, ilp=ilp,
             ragged=False, steps_per_pass=float(steps))
 
@@ -452,8 +521,8 @@ def _tridiag_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
         op=wl.op, variant=wl.variant, n=n, batch=batch, dtype=wl.dtype,
         kind="xla", tile_n=n, rows=rows, radix=radix, stages=stages,
         seq_tiles=1, grid=(batch // max(rows, 1),), launches=(), passes=1,
-        vmem_bytes=vmem, stage_vmem_bytes=tuple(vmem for _ in stages),
-        block_bytes=rows * n * eb, element_bytes=eb, trailing=trailing,
+        vmem_bytes=vmem, block_bytes=rows * n * eb, element_bytes=eb,
+        trailing=trailing,
         lane_eff=lane, sublane_eff=sub, occupancy=occ, ilp=ilp,
         ragged=ragged, steps_per_pass=float(max(len(stages), 1)))
 
@@ -466,16 +535,21 @@ def _fft_fused_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
     radix = int(cfg.get("radix", 2))
     n = wl.n
     stages = stage_radices(n, radix)
-    io = 4 * rows * n * 4                      # re/im in + re/im out, f32
+    # re/im in + re/im out double-buffered, the (re, im) DIF coefficient
+    # tables (one row per stage offset), and the widest stage's shifted
+    # neighbours and accumulators
+    coef_rows = sum(2 * r - 1 for r in stages)
+    vmem = (2 * 4 * vmem_tile_bytes(rows, n, 4, spec)
+            + 2 * 2 * vmem_tile_bytes(coef_rows, n, 4, spec)
+            + (_FFT_FOLD_TEMPS + 4 * max(stages, default=1))
+            * vmem_tile_bytes(rows, n, 4, spec))
     trailing, lane, sub, occ = _occ(n, rows, spec)
     grid = (batch // rows,)
-    launch = Launch("fft", grid, (rows, n), stages, io)
+    launch = Launch("fft", grid, (rows, n), stages, vmem)
     return StagePlan(
         op="fft", variant=wl.variant, n=n, batch=batch, dtype=wl.dtype,
         kind="fused", tile_n=n, rows=rows, radix=radix, stages=stages,
-        seq_tiles=1, grid=grid, launches=(launch,), passes=1, vmem_bytes=io,
-        stage_vmem_bytes=tuple(io + 2 * r * rows * (n // max(r, 1)) * 4
-                               for r in stages),
+        seq_tiles=1, grid=grid, launches=(launch,), passes=1, vmem_bytes=vmem,
         block_bytes=rows * n * eb, element_bytes=eb, trailing=trailing,
         lane_eff=lane, sublane_eff=sub, occupancy=occ,
         ilp=int(cfg.get("unroll", 1)), ragged=is_ragged(stages, radix, n),
@@ -511,7 +585,7 @@ def _large_fft_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         kind="multipass", tile_n=n1, rows=row.rows, radix=row.radix,
         stages=row.stages, seq_tiles=1, grid=row.grid, launches=launches,
         passes=len(launches), vmem_bytes=max(p.vmem_bytes for p in (col, row)),
-        stage_vmem_bytes=row.stage_vmem_bytes, block_bytes=row.block_bytes,
+        block_bytes=row.block_bytes,
         element_bytes=row.element_bytes, trailing=row.trailing,
         lane_eff=row.lane_eff, sublane_eff=row.sublane_eff,
         occupancy=row.occupancy, ilp=row.ilp, ragged=row.ragged,
@@ -525,13 +599,21 @@ def _attention_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
     bq = int(cfg.get("block_q", 128))
     bk = int(cfg.get("block_k", 128))
     grid = (batch * max(wl.n // bq, 1),)
-    vmem = (bq + 2 * bk) * 128 * eb * 2
+    d = _MODEL_MINOR
+    blocks = (bq + 2 * bk) * d * eb            # q, k, v blocks (the DMA set)
+    # double-buffered q/k/v/o blocks, the f32 running max / denominator /
+    # accumulator scratch, and the (bq, bk) f32 scores + probabilities
+    vmem = (2 * (2 * vmem_tile_bytes(bq, d, eb, spec)
+                 + 2 * vmem_tile_bytes(bk, d, eb, spec))
+            + 2 * vmem_tile_bytes(bq, 1, 4, spec)
+            + vmem_tile_bytes(bq, d, 4, spec)
+            + _FLASH_QK_TEMPS * vmem_tile_bytes(bq, bk, 4, spec))
     steps = max(wl.n // bk, 1)
     return StagePlan(
         op=wl.op, variant=wl.variant, n=wl.n, batch=batch, dtype=wl.dtype,
         kind="fused", tile_n=bk, rows=bq, radix=2, stages=(),
         seq_tiles=steps, grid=grid, launches=(), passes=1, vmem_bytes=vmem,
-        stage_vmem_bytes=(), block_bytes=vmem // 2, element_bytes=eb,
+        block_bytes=blocks, element_bytes=eb,
         trailing=bk, lane_eff=lane_utilization(bk, spec),
         sublane_eff=sublane_utilization(bq, spec),
         occupancy=lane_utilization(bk, spec),
@@ -556,7 +638,7 @@ def _matmul_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
         op=wl.op, variant=wl.variant, n=wl.n, batch=batch, dtype=wl.dtype,
         kind="fused", tile_n=bn, rows=bm, radix=2, stages=(),
         seq_tiles=steps, grid=grid, launches=(), passes=1, vmem_bytes=vmem,
-        stage_vmem_bytes=(), block_bytes=vmem // 2, element_bytes=eb,
+        block_bytes=vmem // 2, element_bytes=eb,
         trailing=bn, lane_eff=lane_utilization(bn, spec),
         sublane_eff=sublane_utilization(bm, spec), occupancy=occ,
         ilp=bk // 128 or 1, ragged=False, steps_per_pass=float(steps))

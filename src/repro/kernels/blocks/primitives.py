@@ -7,9 +7,9 @@ operating on the trailing (lane) dimension of VMEM-resident tiles:
                        monoid (prefix sum), with balanced-tree unrolling;
   * ``linrec_level`` — the same level for the (a, b) linear-recurrence
                        monoid (composition order fixed by the algebra);
-  * ``butterfly``    — the radix-rr complex DFT fold + twiddles of one
-                       Stockham stage, including the ``stage_view``
-                       reshape-repack (the index-digit layout transform);
+  * ``butterfly``    — the radix-r complex DFT fold + twiddles of one
+                       in-place DIF stage, as lane shifts times per-lane
+                       coefficients (``dif_coefficients``);
   * ``carry chain``  — init/fold/store of the cross-tile VMEM carry that
                        turns a column-tiled grid into one streaming pass.
 
@@ -20,12 +20,25 @@ new kernel family composes them instead of re-rolling its own stage loop
 """
 from __future__ import annotations
 
-import math
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.hw.profiles import active_profile
+
+
+def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
+    """Mosaic parameters of every kernel launch: the grid's dimension
+    semantics and, explicitly, the scoped-VMEM limit the planner bounds
+    each launch by (``HardwareProfile.vmem_budget``) — so a config the
+    plan admits is one the compiler accepts."""
+    return pltpu.CompilerParams(
+        dimension_semantics=dimension_semantics,
+        vmem_limit_bytes=active_profile().vmem_budget)
 
 
 # ---------------------------------------------------------------------------
@@ -99,50 +112,75 @@ def linrec_level(aa: jax.Array, bb: jax.Array, fan_in: int, stride: int
 
 
 # ---------------------------------------------------------------------------
-# Stockham butterfly stage (complex fold on split re/im planes)
+# Radix-r DIF butterfly stage (complex fold on split re/im planes)
 # ---------------------------------------------------------------------------
 
 def cmul(ar, ai, br, bi):
     return ar * br - ai * bi, ar * bi + ai * br
 
 
-def butterfly(re: jax.Array, im: jax.Array, *, n: int, n_cur: int, s: int,
-              rr: int, sign: float) -> Tuple[jax.Array, jax.Array]:
-    """One radix-``rr`` Stockham stage on (rows, n) split planes.
+def dif_coefficients(n: int, stages: Sequence[int], inverse: bool):
+    """Coefficient rows of the in-place DIF stages over an n-point row.
 
-    ``stage_view``: the planes are viewed as (rows, n_cur, s), split into
-    rr parts of m = n_cur // rr, folded through the rr-point DFT matrix
-    with per-part twiddles, and repacked with the radix digit innermost —
-    the self-sorting index-digit layout transform.  ``rr`` must divide
-    ``n_cur``; plans built from ``stage_radices`` guarantee it (the ragged
-    mixed-radix final stage simply arrives with a smaller rr).
+    Stage t of fan-in r and span h (n = blocks * r * h) maps element
+    (block, j, q) to sum_k x(block, k, q) w_r^(jk) w_(rh)^(jq): every
+    output is a sum over the 2r - 1 lane offsets d*h (d = k - j) of a
+    neighbour times a per-lane complex coefficient, zero where k falls
+    outside the block.  Returns (re, im) as (rows, n) float32 arrays, one
+    row per (stage, offset), and the offsets per stage.
     """
-    rows = re.shape[0]
-    assert n_cur % rr == 0, (n_cur, rr)
-    m = n_cur // rr
-    vr = re.reshape(rows, n_cur, s)
-    vi = im.reshape(rows, n_cur, s)
-    parts = [(vr[:, k * m:(k + 1) * m, :], vi[:, k * m:(k + 1) * m, :])
-             for k in range(rr)]
-    p = jax.lax.broadcasted_iota(jnp.float32, (1, m, 1), 1)
-    outs = []
-    for j in range(rr):
-        tr = jnp.zeros((rows, m, s), jnp.float32)
-        ti = jnp.zeros((rows, m, s), jnp.float32)
-        for k in range(rr):
-            ang = sign * 2.0 * math.pi * ((j * k) % rr) / rr
-            wr, wi = math.cos(ang), math.sin(ang)
-            pr, pi_ = parts[k]
-            tr += pr * wr - pi_ * wi
-            ti += pr * wi + pi_ * wr
-        theta = sign * 2.0 * math.pi * j / n_cur
-        twr = jnp.cos(theta * p)
-        twi = jnp.sin(theta * p)
-        tr, ti = cmul(tr, ti, twr, twi)
-        outs.append((tr, ti))
-    re = jnp.stack([o[0] for o in outs], axis=2).reshape(rows, n)
-    im = jnp.stack([o[1] for o in outs], axis=2).reshape(rows, n)
-    return re, im
+    sign = 1.0 if inverse else -1.0
+    lane = np.arange(n)
+    re, im, offsets = [], [], []
+    span = n
+    for r in stages:
+        h = span // r
+        q, j = lane % h, (lane // h) % r
+        stage_offsets = []
+        for d in range(1 - r, r):
+            k = j + d
+            ang = sign * 2.0 * np.pi * (((j * k) % r) / r + (j * q) / span)
+            live = (k >= 0) & (k < r)
+            re.append(np.where(live, np.cos(ang), 0.0))
+            im.append(np.where(live, np.sin(ang), 0.0))
+            stage_offsets.append(d * h)
+        offsets.append(tuple(stage_offsets))
+        span = h
+    return (np.asarray(re, np.float32), np.asarray(im, np.float32),
+            tuple(offsets))
+
+
+def butterfly(re: jax.Array, im: jax.Array, coef_re, coef_im, row: int,
+              offsets: Sequence[int]) -> Tuple[jax.Array, jax.Array]:
+    """One radix-r DIF stage on (rows, n) split planes, in place.
+
+    Each lane folds its 2r - 1 shifted neighbours (``shift_lanes`` at the
+    stage's offsets) through its coefficient rows ``row .. row + 2r - 2``
+    of ``coef_re``/``coef_im`` (refs of ``dif_coefficients``), so a stage
+    is lane shifts and elementwise complex multiply-adds — no lane
+    shuffle or reshape, which Mosaic cannot lower across the lane dim.
+    The outputs land in digit-reversed order; the caller reorders them.
+    """
+    acc_re = jnp.zeros_like(re)
+    acc_im = jnp.zeros_like(im)
+    for i, off in enumerate(offsets):
+        cr = coef_re[row + i:row + i + 1, :]
+        ci = coef_im[row + i:row + i + 1, :]
+        tr, ti = cmul(shift_lanes(re, -off, 0.0), shift_lanes(im, -off, 0.0),
+                      cr, ci)
+        acc_re = acc_re + tr
+        acc_im = acc_im + ti
+    return acc_re, acc_im
+
+
+def digit_reverse(y: jax.Array, stages: Sequence[int]) -> jax.Array:
+    """Reorder (batch, n) DIF outputs into natural order (an XLA
+    reshape/transpose outside the kernel): position (j1, .., jk), j1 most
+    significant, holds frequency j1 + r1 j2 + ..."""
+    batch, n = y.shape
+    k = len(stages)
+    y = y.reshape((batch,) + tuple(int(r) for r in stages))
+    return jnp.transpose(y, (0,) + tuple(range(k, 0, -1))).reshape(batch, n)
 
 
 # ---------------------------------------------------------------------------

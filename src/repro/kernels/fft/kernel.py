@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: batched complex FFT (self-sorting Stockham, radix-r).
+"""Pallas TPU kernel: batched complex FFT (in-place DIF, radix-r).
 
 Complex data is carried as split re/im f32 planes (TPU VREGs are real; the
 paper's BPLG similarly multiplexes real/imaginary shared-memory planes for
@@ -7,10 +7,14 @@ problems resident in VMEM.
 
 The staged loop is driven by the plan's mixed-radix stage sequence
 (``blocks.plan.stage_radices``): stage t applies the shared ``butterfly``
-building block at that stage's fan-in.  Because the sequence factors n
-exactly, the ragged final stage is just a smaller butterfly — the
-historical ``rr = min(radix, n_cur)`` loop crashed at trace time whenever
-an intermediate n_cur stopped dividing by the radix (radix 8 at n = 96).
+building block at that stage's fan-in, as lane shifts times per-lane
+complex coefficients (``primitives.dif_coefficients``, built once per
+(n, stages) on the host and held in VMEM).  A self-sorting Stockham stage
+would repack digits across the lane dim, which Mosaic cannot lower; the
+in-place DIF leaves its output in digit-reversed order instead, and the
+wrapper reorders it with one reshape/transpose in XLA.  Because the stage
+sequence factors n exactly, the ragged final stage is just a smaller
+butterfly.
 
 Tunables: rows_per_program, radix; tile_n = n (whole-problem residency);
 multi-pass large-N handled by the four-step driver in blocks/driver.py.
@@ -21,30 +25,28 @@ import functools
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.blocks import primitives as prim
 from repro.kernels.blocks.plan import stage_radices
 
-import jax.numpy as jnp
 
-
-def _fft_kernel(re_ref, im_ref, ore_ref, oim_ref, *, n: int,
-                stages: Tuple[int, ...], inverse: bool):
-    sign = 1.0 if inverse else -1.0
+def _fft_kernel(re_ref, im_ref, cre_ref, cim_ref, ore_ref, oim_ref, *,
+                offsets: Tuple[Tuple[int, ...], ...], scale: float):
     re = re_ref[...].astype(jnp.float32)
     im = im_ref[...].astype(jnp.float32)
-
-    n_cur, s = n, 1
-    for rr in stages:
-        re, im = prim.butterfly(re, im, n=n, n_cur=n_cur, s=s, rr=rr,
-                                sign=sign)
-        n_cur, s = n_cur // rr, s * rr
-
-    scale = (1.0 / n) if inverse else 1.0
+    row = 0
+    for stage_offsets in offsets:
+        re, im = prim.butterfly(re, im, cre_ref, cim_ref, row, stage_offsets)
+        row += len(stage_offsets)
     ore_ref[...] = (re * scale).astype(ore_ref.dtype)
     oim_ref[...] = (im * scale).astype(oim_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _coefficients(n: int, stages: Tuple[int, ...], inverse: bool):
+    return prim.dif_coefficients(n, stages, inverse)
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_program", "radix",
@@ -56,18 +58,19 @@ def fft_pallas(re: jax.Array, im: jax.Array, *, rows_per_program: int = 4,
     """Row-wise complex FFT on split planes; returns (re, im)."""
     batch, n = re.shape
     rows = rows_per_program
-    grid = (batch // rows,)
-    spec = pl.BlockSpec((rows, n), lambda i: (i, 0))
     stages = prim.as_stages(stages) if stages else stage_radices(n, radix)
-    kernel = functools.partial(_fft_kernel, n=n, stages=stages,
-                               inverse=inverse)
-    return pl.pallas_call(
+    coef_re, coef_im, offsets = _coefficients(n, stages, inverse)
+    spec = pl.BlockSpec((rows, n), lambda i: (i, 0))
+    coef_spec = pl.BlockSpec(coef_re.shape, lambda i: (0, 0))
+    kernel = functools.partial(_fft_kernel, offsets=offsets,
+                               scale=(1.0 / n) if inverse else 1.0)
+    yre, yim = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[spec, spec],
+        grid=(batch // rows,),
+        in_specs=[spec, spec, coef_spec, coef_spec],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct(re.shape, re.dtype)] * 2,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=prim.compiler_params("parallel"),
         interpret=interpret,
-    )(re, im)
+    )(re, im, jnp.asarray(coef_re), jnp.asarray(coef_im))
+    return prim.digit_reverse(yre, stages), prim.digit_reverse(yim, stages)

@@ -21,7 +21,7 @@ from repro.kernels.blocks import driver
 from repro.kernels.blocks.plan import plan_for
 from repro.kernels.fft.kernel import fft_pallas
 from repro.kernels.fft.ref import fft_ref
-from repro.tuning import default_session, on_cpu, tuned_kernel
+from repro.tuning import default_session, plan_execution, tuned_kernel
 
 
 def _normalize(cfg, wl, dims=None):
@@ -37,7 +37,7 @@ def _normalize(cfg, wl, dims=None):
 def fft(x: jax.Array, config: Optional[dict] = None,
         interpret: Optional[bool] = None, inverse: bool = False) -> jax.Array:
     batch, n = x.shape
-    interpret = on_cpu() if interpret is None else interpret
+    _, interpret = plan_execution(True, interpret)
     session = default_session()
     wl_small = Workload(op="fft", n=n, batch=batch, variant="stockham")
     max_tile = max_resident_tile(wl_small)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.kernels.blocks.primitives import compiler_params
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref):
@@ -51,7 +51,6 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *, block_m: int = 256,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(a, b)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.blocks import primitives as prim
 from repro.kernels.blocks.plan import stage_radices, stage_strides
 
@@ -113,8 +112,7 @@ def scan_add_pallas(x: jax.Array, *, rows_per_program: int = 8,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=prim.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(x)
 
@@ -148,8 +146,7 @@ def scan_linrec_pallas(a: jax.Array, b: jax.Array, *, rows_per_program: int = 8,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=prim.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(a, b)
 
@@ -182,7 +179,6 @@ def scan_linrec_prod_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=prim.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(a, b)

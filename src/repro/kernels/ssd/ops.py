@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from repro.core.space import Workload, fit_block, scan_space
 from repro.kernels.blocks import driver
 from repro.kernels.blocks.plan import plan_for_chain
-from repro.kernels.ssd.kernel import (ssd_apply_entry_pallas,
+from repro.kernels.ssd.kernel import (chunk_log_decay,
+                                      ssd_apply_entry_pallas,
                                       ssd_intra_pallas,
                                       ssd_state_apply_pallas)
 from repro.kernels.ssd.ref import ssd_chunked_ref
@@ -71,8 +72,9 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     bbh = jnp.broadcast_to(b[:, None], (B, H, L, S)).reshape(B * H, L, S)
     cbh = jnp.broadcast_to(c[:, None], (B, H, L, S)).reshape(B * H, L, S)
 
-    y_intra, a_chunk, state = driver.launch(
-        ssd_intra_pallas, chain.launches[0], xbh, abh, bbh, cbh,
+    la = chunk_log_decay(abh, chunk)
+    y_intra, state = driver.launch(
+        ssd_intra_pallas, chain.launches[0], xbh, la, bbh, cbh,
         chunk=chunk, interpret=interpret)
     nc = L // chunk
     if nc <= 1:
@@ -85,7 +87,7 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         # the inter-chunk recurrence state — chunk states never round-trip
         # through HBM between the recurrence and the apply
         y = driver.launch(ssd_state_apply_pallas, chain.launches[-1],
-                          y_intra, abh, cbh, a_chunk, state, chunk=chunk,
+                          y_intra, la, cbh, state, chunk=chunk,
                           interpret=interpret)
         return jnp.transpose(y.reshape(B, H, L, P), (0, 2, 1, 3))
 
@@ -95,6 +97,7 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     # the XLA reference otherwise (odd nc).  The enclosing resolution is
     # threaded in: the embedded block runs under the chain's radix, not a
     # fresh ``config=None`` resolution that overrides could never reach.
+    a_chunk = jnp.exp(la.reshape(B * H, nc, chunk)[..., -1])   # (BH, nc)
     a_rows = jnp.broadcast_to(a_chunk[:, None, None, :], (B * H, S, P, nc))
     s_rows = jnp.transpose(state, (0, 2, 3, 1))          # (BH, S, P, nc)
     h = driver.linrec_rows(a_rows.reshape(-1, nc), s_rows.reshape(-1, nc),
@@ -106,6 +109,6 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     entry = jnp.transpose(entry, (0, 3, 1, 2))           # (BH, nc, S, P)
 
     y = driver.launch(ssd_apply_entry_pallas, chain.launches[-1],
-                      y_intra, abh, cbh, entry, chunk=chunk,
+                      y_intra, la, cbh, entry, chunk=chunk,
                       interpret=interpret)
     return jnp.transpose(y.reshape(B, H, L, P), (0, 2, 1, 3))

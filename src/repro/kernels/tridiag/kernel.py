@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.blocks import primitives as prim
 
 
@@ -58,7 +57,6 @@ def pcr_pallas(a: jax.Array, b: jax.Array, c: jax.Array, d: jax.Array, *,
         in_specs=[spec] * 4,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
+        compiler_params=prim.compiler_params("parallel"),
         interpret=interpret,
     )(a, b, c, d)

@@ -27,7 +27,7 @@ from repro.kernels.blocks import driver
 from repro.kernels.blocks.plan import plan_for, wm_chunk
 from repro.kernels.tridiag.kernel import pcr_pallas
 from repro.kernels.tridiag.ref import thomas_ref
-from repro.tuning import default_session, on_cpu, plan_execution, tuned_kernel
+from repro.tuning import default_session, plan_execution, tuned_kernel
 
 # systems longer than this route the LF substitution sweeps through the
 # multi-pass scan driver (paper §IV-C m-kernel path for tridiag)
@@ -243,7 +243,7 @@ def solve(a, b, c, d, variant: str = "pcr", config: Optional[dict] = None,
             config=config)
 
     if variant == "pcr":
-        interpret = on_cpu() if interpret is None else interpret
+        _, interpret = plan_execution(True, interpret)
         c_ = cfg()
         plan = plan_for(Workload(op="tridiag", n=n, batch=batch,
                                  variant="pcr"), c_)

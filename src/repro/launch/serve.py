@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.configs.base import get_arch
 from repro.core.space import Workload
+from repro.launch.compile_cache import place_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import ServeEngine
 from repro.tuning import (OnlineTuner, TraceRecorder, attach,
@@ -76,6 +77,7 @@ def main() -> None:
                     help="record (config, step latency) pairs to this JSONL "
                          "trace for deterministic replay")
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -13,6 +13,7 @@ import argparse
 
 from repro.configs.base import get_arch
 from repro.data.pipeline import Batcher, DataConfig
+from repro.launch.compile_cache import place_compile_cache
 from repro.models.model import build_model
 from repro.train.loop import LoopConfig, run_training
 from repro.train.step import TrainHParams
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -68,6 +68,7 @@ from typing import List, Optional
 
 from repro.configs.paper_ops import PREFIX_OPS, TOTAL_ELEMS
 from repro.core import CostModelObjective, Workload
+from repro.launch.compile_cache import place_compile_cache
 from repro.tuning import TunerSession, default_session, strategies
 
 
@@ -457,6 +458,7 @@ def lint_main(argv: List[str]) -> int:
 # ---------------------------------------------------------------------------
 
 def main(argv: Optional[List[str]] = None) -> int:
+    place_compile_cache()
     argv = list(sys.argv[1:]) if argv is None else list(argv)
     if argv and argv[0] == "lint":
         return lint_main(argv[1:])

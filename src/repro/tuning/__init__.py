@@ -26,7 +26,7 @@ from repro.core.policy import (Policy, PolicyObjective, get_policy,
 from repro.core.space import (Config, Workload, build_space, fit_block,
                               normalize_config)
 from repro.tuning.db import DEFAULT_DB_PATH, SCHEMA_VERSION, TuningDB
-from repro.tuning.dispatch import on_cpu, plan_execution
+from repro.tuning.dispatch import plan_execution
 from repro.tuning.overrides import active_overrides, overrides, overrides_active
 from repro.tuning.registry import (KernelSpec, get_kernel, normalizer_for,
                                    registered_kernels, tuned_kernel)
@@ -79,7 +79,7 @@ __all__ = [
     "TunerSession", "TuningDB", "Workload", "active_overrides", "attach",
     "build_space", "config_key", "default_session", "fit_block", "get_kernel",
     "get_policy", "get_strategy", "journal_path", "normalize_config",
-    "normalizer_for", "on_cpu", "online_search", "overrides",
+    "normalizer_for", "online_search", "overrides",
     "overrides_active", "pareto_front", "plan_execution", "policies",
     "policy_scalar_cols", "prune_candidates",
     "register_strategy", "registered_kernels", "replay",

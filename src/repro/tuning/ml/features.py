@@ -36,7 +36,10 @@ from repro.kernels.blocks.plan import plan_for
 # v5: "fuse" column — the chain-fusion boundary knob (ssd/rglru chains);
 # the plan columns (log2_passes) already see its effect, the raw knob lets
 # the forest separate fusion from blocking at equal pass counts.
-FEATURE_VERSION = 5
+# v6: "log2_vmem" / "vmem_fits" read a launch's full VMEM — double-buffered
+# blocks, scratch and fold temporaries, bounded by the compile limit — where
+# they read the resident io blocks before.
+FEATURE_VERSION = 6
 
 FEATURE_NAMES = (
     # workload (Input Parameters `A`)

@@ -72,8 +72,8 @@ def test_plan_invariants_over_valid_space(wl):
             or plan.op in ("tridiag",)   # pcr/xla stage over n, radix 2
         if plan.op == "tridiag":
             assert math.prod(plan.stages) >= plan.n
-        # valid configs fit the budget the spaces enforce
-        assert plan.vmem_bytes <= V5E.vmem_budget * 2
+        # valid configs fit the VMEM limit the kernels compile under
+        assert plan.vmem_bytes <= V5E.vmem_budget
         # HBM pass count == launch count + the chain's XLA links for
         # pallas-backed plans (rglru's unfused gate is an XLA pass)
         if plan.launches:
@@ -82,6 +82,32 @@ def test_plan_invariants_over_valid_space(wl):
         res = plan.resources()
         assert res["passes"] == plan.passes
         assert res["vmem"] == plan.vmem_bytes
+
+
+@pytest.mark.parametrize("op,variant", [("scan", "ks"), ("scan", "lf"),
+                                        ("scan", "linrec"), ("rglru", "")])
+@pytest.mark.parametrize("n", [128, 256, 512, 1024, 2048, 4096])
+def test_paper_sizes_plan_within_compile_vmem_limit(op, variant, n):
+    """Regression: at the paper's 2^26-element batches every config the
+    tpu_v5e space admits plans each launch within the scoped VMEM the
+    kernels compile under, and the space's bound has teeth (the largest
+    raw blocks are rejected from n = 2048 up, as the compiler rejected
+    them)."""
+    wl = Workload(op=op, n=n, batch=2 ** 26 // n, variant=variant)
+    space = build_space(wl, V5E)
+    valid = space.enumerate_valid()
+    assert valid
+    for cfg in valid:
+        plan = plan_for(wl, cfg, profile=V5E)
+        assert plan.check(V5E) == []
+        assert all(l.vmem_bytes <= V5E.vmem_budget for l in plan.launches)
+    widest = {"tile_n": n, "rows_per_program": 512, "radix": 8, "unroll": 1,
+              "in_register": 0, "fuse": 1}
+    widest = {k: v for k, v in widest.items()
+              if k in {p.name for p in space.params}}
+    over = plan_for(wl, widest, profile=V5E).vmem_bytes > V5E.vmem_budget
+    assert space.is_valid(widest) == (not over)
+    assert over or n < 2048
 
 
 def test_multipass_triggers_past_seq_limit():
@@ -117,8 +143,18 @@ def test_wm_chunk_single_source():
 # Launch conformance: what runs is what the plan promised
 # ---------------------------------------------------------------------------
 
-def _expected_scan_vmem(rows, tile, planes):
-    return planes * rows * tile * 4 + rows * 4      # f32 io + carry scratch
+def _vmem_tile(rows, cols, itemsize=4):
+    """A VMEM buffer padded to the v5e (8, 128) tile."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * itemsize
+
+
+def _expected_scan_vmem(rows, tile, planes, stages):
+    # double-buffered f32 io blocks + the carry column + the widest fold
+    # stage's f32 temporaries (r + 1 for a prefix sum, 2 r + 4 for linrec)
+    fan = max(stages)
+    temps = fan + 1 if planes == 2 else 2 * fan + 4
+    return (2 * planes * _vmem_tile(rows, tile) + _vmem_tile(rows, 1)
+            + temps * _vmem_tile(rows, tile))
 
 
 def test_scan_conformance_every_valid_config():
@@ -143,7 +179,8 @@ def test_scan_conformance_every_valid_config():
         assert launch.grid == (4 // rows, 128 // tile) == plan.launches[0].grid
         assert launch.block_shape == (rows, tile)
         assert math.prod(launch.stages) == tile
-        assert launch.vmem_bytes == _expected_scan_vmem(rows, tile, 2) \
+        assert launch.vmem_bytes \
+            == _expected_scan_vmem(rows, tile, 2, launch.stages) \
             == plan.vmem_bytes
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-4)
@@ -170,7 +207,12 @@ def test_fft_conformance_every_valid_config():
         rows = plan.rows
         assert launch.grid == (4 // rows,) == plan.launches[0].grid
         assert math.prod(launch.stages) == 64
-        assert launch.vmem_bytes == 4 * rows * 64 * 4 == plan.vmem_bytes
+        # re/im in+out double-buffered, the coefficient tables, and the
+        # widest stage's 4 r + 10 temporaries
+        coef_rows = sum(2 * r - 1 for r in launch.stages)
+        assert launch.vmem_bytes == (
+            (8 + 10 + 4 * max(launch.stages)) * _vmem_tile(rows, 64)
+            + 4 * _vmem_tile(coef_rows, 64)) == plan.vmem_bytes
         err = np.max(np.abs(np.asarray(got) - ref)) / np.max(np.abs(ref))
         assert err < 1e-4
 
@@ -194,7 +236,9 @@ def test_pcr_conformance_every_valid_config():
         launch = rec[0]
         rows = norm["rows_per_program"]
         assert launch.grid == (4 // rows,) == plan.launches[0].grid
-        assert launch.vmem_bytes == 5 * rows * 64 * 4 == plan.vmem_bytes
+        # five double-buffered planes + one PCR step's 18 temporaries
+        assert launch.vmem_bytes == (2 * 5 + 18) * _vmem_tile(rows, 64) \
+            == plan.vmem_bytes
         assert len(launch.stages) == math.ceil(math.log2(64))
         np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-3,
                                    atol=1e-3)

@@ -280,3 +280,57 @@ def test_session_is_profile_keyed(tmp_path):
     assert tpu.db.lookup(wl) is None           # other device's winner
     # resolve still answers (analytical fallback on its own profile)
     assert tpu.resolve(wl)
+
+
+# ---------------------------------------------------------------------------
+# 4. On a TPU backend the attached chip picks the profile
+# ---------------------------------------------------------------------------
+
+class _FakeDevice:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def _attach_tpu(monkeypatch, kind):
+    import jax
+    monkeypatch.delenv("REPRO_HW_PROFILE", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeDevice(kind)])
+
+
+def test_tpu_backend_profile_comes_from_device_kind(monkeypatch):
+    _attach_tpu(monkeypatch, "TPU v5 lite")
+    assert active_profile() is TPU_V5E
+
+
+def test_tpu_backend_unknown_device_kind_raises(monkeypatch):
+    _attach_tpu(monkeypatch, "TPU v99 imaginary")
+    with pytest.raises(ValueError, match="TPU v99 imaginary"):
+        active_profile()
+
+
+def test_env_profile_still_wins_on_tpu_backend(monkeypatch):
+    _attach_tpu(monkeypatch, "TPU v99 imaginary")
+    monkeypatch.setenv("REPRO_HW_PROFILE", "gpu_sm")
+    assert active_profile() is GPU_SM
+
+
+def test_tpu_backend_runs_compiled_pallas_unless_asked(monkeypatch):
+    """No silent interpret/reference routing on a TPU: the default is the
+    compiled kernel; interpret mode or the reference only when asked."""
+    from repro.tuning.dispatch import plan_execution
+    _attach_tpu(monkeypatch, "TPU v5 lite")
+    assert plan_execution(None, None) == (True, False)
+    assert plan_execution(None, True) == (True, True)
+    assert plan_execution(False, None) == (False, False)
+
+
+def test_kernels_compile_under_the_profile_vmem_limit():
+    """The scoped-VMEM limit handed to Mosaic is the budget the plans and
+    spaces are bounded by."""
+    from repro.kernels.blocks.primitives import compiler_params
+    params = compiler_params("parallel", "arbitrary")
+    assert params.vmem_limit_bytes == TPU_V5E.vmem_budget
+    assert params.dimension_semantics == ("parallel", "arbitrary")

@@ -78,10 +78,7 @@ def test_error_feedback_accumulates():
 
 def test_psum_compressed_single_device():
     mesh = jax.make_mesh((1,), ("pod",))
-    try:                                     # newer jax exports it top-level
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     x = jnp.asarray([1.0, -2.0, 3.0])
     f = shard_map(lambda v: psum_compressed(v, "pod"), mesh=mesh,

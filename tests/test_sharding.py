@@ -7,10 +7,7 @@ from repro.distributed.sharding import (ShardingDecisions, param_specs,
                                         spec_for_leaf)
 from repro.models.model import build_model
 
-try:  # newer jax: AbstractMesh(axis_sizes, axis_names)
-    MESH = AbstractMesh((16, 16), ("data", "model"))
-except TypeError:  # older jax: AbstractMesh(((name, size), ...))
-    MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_attention_weights_2d_sharded():

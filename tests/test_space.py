@@ -7,6 +7,7 @@ from hypothesis import given, settings
 
 from repro.core import Workload, build_space
 from repro.core.space import pow2_range
+from repro.kernels.blocks.plan import plan_for
 
 
 def test_pow2_range():
@@ -33,8 +34,13 @@ def test_constraints_reject_oversized_vmem():
     space = build_space(wl)
     huge = {"tile_n": 4096, "rows_per_program": 512, "radix": 2,
             "unroll": 1, "in_register": 0}
-    # 512*4096*4*2 = 16 MiB <= budget so this one is fine; push rows
-    assert space.is_valid(huge) == (512 * 4096 * 4 * 2 <= space.spec.vmem_budget)
+    small = dict(huge, rows_per_program=8)
+    # the planned launch (double-buffered blocks + fold temporaries) is
+    # what the compiler is asked to fit into the scoped VMEM limit
+    budget = space.spec.vmem_budget
+    assert plan_for(wl, huge).vmem_bytes > budget
+    assert plan_for(wl, small).vmem_bytes <= budget
+    assert not space.is_valid(huge) and space.is_valid(small)
 
 
 def test_in_register_rule():

@@ -1,0 +1,129 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e chip.
+
+Nothing runs: each test lowers one kernel entry point at the paper's sizes
+(2^26 elements per call) or a model's published widths, with the config
+the tuning session resolves, and compiles it with the TPU compiler for a
+``v5e:2x2`` topology described here without a chip.  What Mosaic refuses
+(an unaligned block, more scoped VMEM than the limit the kernels compile
+under, an op it cannot lower) fails here at no chip time.  Code that asks
+``jax.default_backend()`` still sees the CPU, so the tests ask for the
+compiled Pallas path explicitly (``use_pallas=True, interpret=False``).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+TOTAL = 2 ** 26
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def session_without_db(tmp_path_factory):
+    """Configs come from the analytical model, as on a fresh checkout."""
+    from repro.tuning import TunerSession, set_default_session
+    path = tmp_path_factory.mktemp("tpu_compile") / "absent_db.json"
+    previous = set_default_session(TunerSession(db_path=str(path)))
+    yield
+    set_default_session(previous)
+
+
+@pytest.fixture
+def compile_for(one_chip):
+    """Compile ``fn`` for shapes on the described chip, with JAX's
+    persistent cache off (a described-chip compile cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield run
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+F32 = jnp.float32
+COMPILED = dict(use_pallas=True, interpret=False)
+
+
+@pytest.mark.parametrize("variant", ["ks", "lf"])
+@pytest.mark.parametrize("n", [1024, 4096])
+def test_prefix_sum_compiles(compile_for, variant, n):
+    from repro.kernels.scan.ops import prefix_sum
+    text = compile_for(lambda x: prefix_sum(x, variant=variant, **COMPILED),
+                       ((TOTAL // n, n), F32))
+    assert "tpu_custom_call" in text
+
+
+def test_linear_recurrence_compiles(compile_for):
+    from repro.kernels.scan.ops import linear_recurrence
+    shape = ((TOTAL // 1024, 1024), F32)
+    text = compile_for(lambda a, b: linear_recurrence(a, b, **COMPILED),
+                       shape, shape)
+    assert "tpu_custom_call" in text
+
+
+def test_rglru_compiles(compile_for):
+    from repro.kernels.rglru.ops import rglru
+    shape = ((4, 2048, 2560), F32)          # recurrentgemma-9b lru width
+    text = compile_for(lambda a, u: rglru(a, u, **COMPILED), shape, shape)
+    assert "tpu_custom_call" in text
+
+
+def test_tridiag_pcr_compiles(compile_for):
+    from repro.kernels.tridiag.ops import solve
+    shape = ((TOTAL // 64, 64), F32)
+    text = compile_for(
+        lambda a, b, c, d: solve(a, b, c, d, variant="pcr", interpret=False),
+        shape, shape, shape, shape)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [4096, 8388608])
+def test_fft_compiles(compile_for, n):
+    """n = 4096 runs one resident kernel; 2^23 the four-step path."""
+    from repro.kernels.fft.ops import fft
+    text = compile_for(lambda x: fft(x, interpret=False),
+                       ((TOTAL // n, n), jnp.complex64))
+    assert "tpu_custom_call" in text
+
+
+def test_ssd_compiles(compile_for):
+    from repro.kernels.ssd.ops import ssd
+    B, L, H, P, S = 4, 2048, 24, 64, 128    # mamba2-130m widths
+    text = compile_for(lambda x, a, b, c: ssd(x, a, b, c, **COMPILED),
+                       ((B, L, H, P), F32), ((B, L, H), F32),
+                       ((B, L, S), F32), ((B, L, S), F32))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles(compile_for):
+    from repro.kernels.attention.ops import attention
+    shape = ((64, 2048, 64), jnp.bfloat16)
+    text = compile_for(lambda q, k, v: attention(q, k, v, **COMPILED),
+                       shape, shape, shape)
+    assert "tpu_custom_call" in text
