@@ -129,8 +129,9 @@ def _apply_linrec_kernel(h_ref, p_ref, e_ref, o_ref):
     o_ref[...] = (h + p * e).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def _apply_add(y, entry, *, rows: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("rows", "interpret", "name"))
+def _apply_add(y, entry, *, rows: int, interpret: bool,
+               name: Optional[str] = None):
     batch, n = y.shape
     grid = (batch // rows,)
     return pl.pallas_call(
@@ -142,11 +143,13 @@ def _apply_add(y, entry, *, rows: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
         compiler_params=compiler_params("parallel"),
         interpret=interpret,
+        name=name,
     )(y, entry)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def _apply_linrec(h, prod, entry, *, rows: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("rows", "interpret", "name"))
+def _apply_linrec(h, prod, entry, *, rows: int, interpret: bool,
+                  name: Optional[str] = None):
     batch, n = h.shape
     grid = (batch // rows,)
     row_spec = pl.BlockSpec((rows, n), lambda i: (i, 0))
@@ -158,14 +161,23 @@ def _apply_linrec(h, prod, entry, *, rows: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
         compiler_params=compiler_params("parallel"),
         interpret=interpret,
+        name=name,
     )(h, prod, entry)
 
 
+def _stage_name(name: Optional[str], stage: str) -> Optional[str]:
+    """Launch name of one stage of a named multi-pass op."""
+    return None if name is None else f"{name}_{stage}"
+
+
 def multipass_scan_add(x: jax.Array, plan: StagePlan, *, unroll: int = 1,
-                       interpret: bool = False) -> jax.Array:
+                       interpret: bool = False,
+                       name: Optional[str] = None) -> jax.Array:
     """Prefix sum over (batch, n) as three kernels: per-chunk scans,
     exclusive scan over chunk sums, entry broadcast — HBM roundtrips
-    between launches instead of a serialized carry chain."""
+    between launches instead of a serialized carry chain.  A ``name``
+    names the launches ``<name>_chunk``, ``<name>_carry`` and
+    ``<name>_apply``."""
     from repro.kernels.scan.kernel import scan_add_pallas
     l1, l2, l3 = plan.launches
     batch, n = x.shape
@@ -180,7 +192,8 @@ def multipass_scan_add(x: jax.Array, plan: StagePlan, *, unroll: int = 1,
     record_launch(l1)
     y_local = scan_add_pallas(xc, rows_per_program=l1.block_shape[0],
                               tile_n=length, stages=l1.stages, unroll=unroll,
-                              interpret=interpret)
+                              interpret=interpret,
+                              name=_stage_name(name, "chunk"))
     sums = y_local[:, -1].reshape(batch, p)
     record_launch(l2)
     # the carry scan's tile is the CHUNK COUNT p, not tile_n: the
@@ -190,23 +203,26 @@ def multipass_scan_add(x: jax.Array, plan: StagePlan, *, unroll: int = 1,
     csums = scan_add_pallas(sums, rows_per_program=l2.block_shape[0],
                             tile_n=p, stages=l2.stages,
                             unroll=max(1, min(unroll, l2.block_shape[1])),
-                            interpret=interpret)
+                            interpret=interpret,
+                            name=_stage_name(name, "carry"))
     entry = jnp.pad(csums[:, :-1], ((0, 0), (1, 0))).reshape(batch * p, 1)
     record_launch(l3)
     y = _apply_add(y_local, entry, rows=l3.block_shape[0],
-                   interpret=interpret)
+                   interpret=interpret, name=_stage_name(name, "apply"))
     return y.reshape(batch, n).astype(x.dtype)
 
 
 def multipass_linrec(a: jax.Array, b: jax.Array, plan: StagePlan, *,
                      gate: bool = False,
-                     interpret: bool = False) -> jax.Array:
+                     interpret: bool = False,
+                     name: Optional[str] = None) -> jax.Array:
     """h_t = a_t h_{t-1} + b_t as three kernels: per-chunk linrec (+ the
     chunk transfer operators), carry linrec over operators, apply.
 
     ``gate=True`` is the fused rglru chain: ``b`` carries the raw input u
     and the chunk kernel applies the RG-LRU gate in-tile (the carry and
     apply launches operate on transfer operators, untouched by the gate).
+    ``name`` names the launches as in ``multipass_scan_add``.
     """
     from repro.kernels.scan.kernel import (scan_linrec_pallas,
                                            scan_linrec_prod_pallas)
@@ -221,18 +237,20 @@ def multipass_linrec(a: jax.Array, b: jax.Array, plan: StagePlan, *,
     record_launch(l1)
     h_local, a_cum = scan_linrec_prod_pallas(
         ac, bc, rows_per_program=l1.block_shape[0], stages=l1.stages,
-        gate=gate, interpret=interpret)
+        gate=gate, interpret=interpret, name=_stage_name(name, "chunk"))
     # chunk transfer operator: state_out = A * state_in + B
     A = a_cum[:, -1].reshape(batch, p)
     B = h_local[:, -1].reshape(batch, p)
     record_launch(l2)
     exits = scan_linrec_pallas(A, B, rows_per_program=l2.block_shape[0],
                                tile_n=p, stages=l2.stages,
-                               interpret=interpret)
+                               interpret=interpret,
+                               name=_stage_name(name, "carry"))
     entry = jnp.pad(exits[:, :-1], ((0, 0), (1, 0))).reshape(batch * p, 1)
     record_launch(l3)
     h = _apply_linrec(h_local, a_cum, entry.astype(h_local.dtype),
-                      rows=l3.block_shape[0], interpret=interpret)
+                      rows=l3.block_shape[0], interpret=interpret,
+                      name=_stage_name(name, "apply"))
     return h.reshape(batch, n).astype(a.dtype)
 
 

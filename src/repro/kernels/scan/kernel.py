@@ -92,12 +92,17 @@ def _resolve_stages(stages: Optional[Tuple[int, ...]], tile_n: int,
 
 @functools.partial(jax.jit, static_argnames=("rows_per_program", "tile_n",
                                              "radix", "unroll", "stages",
-                                             "interpret"))
+                                             "interpret", "name"))
 def scan_add_pallas(x: jax.Array, *, rows_per_program: int = 8,
                     tile_n: int = 0, radix: int = 2, unroll: int = 1,
                     stages: Optional[Tuple[int, ...]] = None,
-                    interpret: bool = False) -> jax.Array:
-    """Inclusive prefix sum over the last axis of (batch, n)."""
+                    interpret: bool = False,
+                    name: Optional[str] = None) -> jax.Array:
+    """Inclusive prefix sum over the last axis of (batch, n).
+
+    ``name`` names the launch: it becomes the kernel's HLO instruction
+    name, which a device trace shows (unnamed, the instruction takes
+    this function's name)."""
     batch, n = x.shape
     tile_n = tile_n or n
     grid, in_specs, out_spec, scratch = _grid_and_specs(
@@ -114,22 +119,25 @@ def scan_add_pallas(x: jax.Array, *, rows_per_program: int = 8,
         scratch_shapes=scratch,
         compiler_params=prim.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name=name,
     )(x)
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_program", "tile_n",
                                              "radix", "unroll", "stages",
-                                             "gate", "interpret"))
+                                             "gate", "interpret", "name"))
 def scan_linrec_pallas(a: jax.Array, b: jax.Array, *, rows_per_program: int = 8,
                        tile_n: int = 0, radix: int = 2, unroll: int = 1,
                        stages: Optional[Tuple[int, ...]] = None,
                        gate: bool = False,
-                       interpret: bool = False) -> jax.Array:
+                       interpret: bool = False,
+                       name: Optional[str] = None) -> jax.Array:
     """h_t = a_t * h_{t-1} + b_t along the last axis of (batch, n) pairs.
 
     ``gate=True`` is the fused rglru chain link: ``b`` carries the raw
     input ``u`` and the kernel applies the RG-LRU gate in-tile before the
-    stage loop (one launch for the whole gate→linrec chain).
+    stage loop (one launch for the whole gate→linrec chain).  ``name``
+    names the launch as in ``scan_add_pallas``.
     """
     del unroll  # fold order fixed by composition order for linrec
     batch, n = a.shape
@@ -148,16 +156,19 @@ def scan_linrec_pallas(a: jax.Array, b: jax.Array, *, rows_per_program: int = 8,
         scratch_shapes=scratch,
         compiler_params=prim.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name=name,
     )(a, b)
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_program", "radix",
-                                             "stages", "gate", "interpret"))
+                                             "stages", "gate", "interpret",
+                                             "name"))
 def scan_linrec_prod_pallas(a: jax.Array, b: jax.Array, *,
                             rows_per_program: int = 8, radix: int = 2,
                             stages: Optional[Tuple[int, ...]] = None,
                             gate: bool = False,
-                            interpret: bool = False):
+                            interpret: bool = False,
+                            name: Optional[str] = None):
     """Single-tile linrec returning (h, prefix products of a).
 
     The multi-pass driver's chunk kernel: each program holds whole rows
@@ -181,4 +192,5 @@ def scan_linrec_prod_pallas(a: jax.Array, b: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32)],
         compiler_params=prim.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name=name,
     )(a, b)

@@ -58,6 +58,13 @@ def _plan_workload(wl, linrec: bool):
     return wl if wl.variant == want else dataclasses.replace(wl, variant=want)
 
 
+def _launch_name(plan_wl) -> str:
+    """The kernels' name on a device trace, by the variant that runs:
+    ``scan_ks``, ``scan_lf`` or ``scan_linrec`` (multi-pass launches add
+    a stage suffix)."""
+    return f"scan_{plan_wl.variant}"
+
+
 @tuned_kernel("scan", space=scan_space, pallas=scan_add_pallas,
               reference=scan_add_ref, normalize=_normalize,
               variants=("ks", "lf", "linrec"))
@@ -72,14 +79,16 @@ def prefix_sum(x: jax.Array, variant: str = "ks",
         return scan_add_ref(x)
     wl = Workload(op="scan", n=n, batch=batch, variant=variant)
     cfg = default_session().resolve(wl, config=config)
-    plan = plan_for(_plan_workload(wl, linrec=False), cfg)
+    plan_wl = _plan_workload(wl, linrec=False)
+    plan = plan_for(plan_wl, cfg)
+    name = _launch_name(plan_wl)
     if plan.kind == "multipass":
         return driver.multipass_scan_add(x, plan, unroll=cfg.get("unroll", 1),
-                                         interpret=interpret)
+                                         interpret=interpret, name=name)
     return driver.launch(scan_add_pallas, plan.launches[0], x,
                          rows_per_program=plan.rows, tile_n=plan.tile_n,
                          stages=plan.stages, unroll=cfg.get("unroll", 1),
-                         interpret=interpret)
+                         interpret=interpret, name=name)
 
 
 @tuned_kernel("scan", space=scan_space, pallas=scan_linrec_pallas,
@@ -99,9 +108,12 @@ def linear_recurrence(a: jax.Array, b: jax.Array, variant: str = "linrec",
         return scan_linrec_assoc_ref(a, b)
     wl = Workload(op="scan", n=n, batch=batch, variant=variant)
     cfg = default_session().resolve(wl, config=config)
-    plan = plan_for(_plan_workload(wl, linrec=True), cfg)
+    plan_wl = _plan_workload(wl, linrec=True)
+    plan = plan_for(plan_wl, cfg)
+    name = _launch_name(plan_wl)
     if plan.kind == "multipass":
-        return driver.multipass_linrec(a, b, plan, interpret=interpret)
+        return driver.multipass_linrec(a, b, plan, interpret=interpret,
+                                       name=name)
     return driver.launch(scan_linrec_pallas, plan.launches[0], a, b,
                          rows_per_program=plan.rows, tile_n=plan.tile_n,
-                         stages=plan.stages, interpret=interpret)
+                         stages=plan.stages, interpret=interpret, name=name)

@@ -134,8 +134,10 @@ def main() -> None:
     for r in done:
         reasons[r.finish_reason] = reasons.get(r.finish_reason, 0) + 1
     print(f"[serve] {len(done)} requests, {toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s, prefill_calls={engine.prefill_calls}, "
-          f"host_transfers={engine.host_transfers})")
+          f"({toks/dt:.1f} tok/s)")
+    print("[serve] engine: " + " ".join(
+        f"{k}={v:.4g}" if isinstance(v, float) else f"{k}={v}"
+        for k, v in engine.stats().items()))
     print("[serve] finish reasons: " + ", ".join(
         f"{k}={v}" for k, v in sorted(reasons.items())))
     for r in done[:3]:

@@ -40,6 +40,13 @@ whose config fragments select a per-fragment jitted variant — now held
 in an LRU-capped table (``max_variants``) with the baseline and the
 live variant pinned.  With no listeners the loop takes the exact
 pre-hook path — an untimed engine pays nothing for the hooks.
+
+Operators read the engine through :meth:`ServeEngine.stats` (counters,
+decode and prefill occupancy, queue wait) and, under a profiler trace,
+through four spans that do not overlap: ``serve.admit`` (queue pop,
+lane reset, lane activation), ``serve.prefill`` (one per chunk
+dispatch), ``serve.step`` (one per decode dispatch) and
+``serve.harvest`` (the blocking fetch and its row loop).
 """
 from __future__ import annotations
 
@@ -53,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.model import Model
 from repro.tuning.overrides import overrides as _tuning_overrides
 
@@ -73,6 +81,47 @@ class Request:
     output: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     finish_reason: Optional[str] = None   # "stop" | "length" once done
+    # ServeEngine.stamp_clock stamps: submitted, popped off the queue
+    # into a lane, first output token seen by the host
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+
+
+@dataclasses.dataclass
+class EngineStats:
+    """The engine's counters, all monotone over its life."""
+
+    prefill_calls: int = 0        # prefill device dispatches
+    host_transfers: int = 0       # device->host reads (via _fetch)
+    decode_steps: int = 0         # decode device dispatches
+    lane_steps: int = 0           # sum over harvested decode steps of the
+    #                               lanes that emitted a token
+    prefill_positions: int = 0    # sum of dispatched prefill chunk lengths
+    prefill_writes: int = 0       # prompt tokens written by prefill
+    # t_admit - t_submit of every admitted request that went through submit
+    queue_waits: List[float] = dataclasses.field(default_factory=list)
+
+    def snapshot(self, max_batch: int) -> Dict[str, float]:
+        """The counters, with decode occupancy (``lane_steps`` over
+        ``decode_steps * max_batch``; exact once ``run`` has returned, as
+        it harvests every dispatched step), prefill occupancy (prompt
+        tokens written over ``prefill_positions * max_batch``; the rest
+        is padding) and the p50 / p95 queue wait in seconds."""
+        out: Dict[str, float] = {
+            f.name: getattr(self, f.name)
+            for f in dataclasses.fields(self) if f.name != "queue_waits"}
+        out["decode_occupancy"] = self.lane_steps / max(
+            self.decode_steps * max_batch, 1)
+        out["prefill_occupancy"] = self.prefill_writes / max(
+            self.prefill_positions * max_batch, 1)
+        waits = self.queue_waits
+        out["admitted"] = len(waits)
+        out["queue_wait_p50_s"] = float(np.percentile(waits, 50)) \
+            if waits else 0.0
+        out["queue_wait_p95_s"] = float(np.percentile(waits, 95)) \
+            if waits else 0.0
+        return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -251,15 +300,31 @@ class ServeEngine:
         # slot -> prompt tokens already written (mid-prefill slots)
         self._prefilling: Dict[int, int] = {}
         self._pending_out: List[jax.Array] = []
-        # perf counters (read by benchmarks/tests)
-        self.prefill_calls = 0        # prefill device dispatches
-        self.host_transfers = 0       # device->host reads (via _fetch)
+        self.counters = EngineStats()
+        # clock of the Request stamps and queue waits: the one the serve
+        # driver stamps tokens with.  Not step_timer, which an untimed
+        # engine never reads; tests replace it to fake time.
+        self.stamp_clock: Callable[[], float] = time.perf_counter
+        obs.watch_compiles()
         # -- step hooks (timing is only paid when a listener is registered)
         self.step_timer: Callable[[], float] = step_timer or time.perf_counter
         self._step_listeners: List[Callable[[StepRecord], None]] = []
         self._override_provider: Optional[
             Callable[[], Optional[Mapping[str, Mapping[str, int]]]]] = None
         self._step_index = 0
+
+    @property
+    def prefill_calls(self) -> int:
+        return self.counters.prefill_calls
+
+    @property
+    def host_transfers(self) -> int:
+        return self.counters.host_transfers
+
+    def stats(self) -> Dict[str, float]:
+        """A snapshot of the counters and the ratios derived from them
+        (:meth:`EngineStats.snapshot`)."""
+        return self.counters.snapshot(self.max_batch)
 
     def add_step_listener(self, fn: Callable[[StepRecord], None]) -> None:
         """Register a callback invoked after every timed decode step."""
@@ -314,7 +379,8 @@ class ServeEngine:
             raise ValueError("empty prompt: need at least one token")
         rid = len(self.queue) + len(self.completed) + sum(
             r is not None for r in self.slot_req)
-        self.queue.append(Request(rid, prompt, max_new_tokens))
+        self.queue.append(Request(rid, prompt, max_new_tokens,
+                                  t_submit=self.stamp_clock()))
         return rid
 
     def run(self, max_steps: int = 1000) -> List[Request]:
@@ -392,7 +458,7 @@ class ServeEngine:
 
     def _fetch(self, x: jax.Array) -> np.ndarray:
         """The one device->host chokepoint (counted; fake-able in tests)."""
-        self.host_transfers += 1
+        self.counters.host_transfers += 1
         return np.asarray(x)
 
     # -- harvest: drain emitted tokens back to host ------------------------
@@ -400,22 +466,27 @@ class ServeEngine:
     def _harvest(self) -> None:
         if not self._pending_out:
             return
-        outs, self._pending_out = self._pending_out, []
-        rows = self._fetch(jnp.stack(outs))       # (k, B, 2), ONE transfer
-        for row in rows:
-            for s, req in enumerate(self.slot_req):
-                if req is None:
-                    continue
-                tok, code = int(row[s, 0]), int(row[s, 1])
-                if tok < 0:
-                    continue        # lane was prefilling / already finished
-                req.output.append(tok)
-                self.slot_pos[s] += 1
-                if code:
-                    req.done = True
-                    req.finish_reason = _FINISH_REASONS[code]
-                    self.completed.append(req)
-                    self.slot_req[s] = None
+        with obs.span("serve.harvest"):
+            outs, self._pending_out = self._pending_out, []
+            rows = self._fetch(jnp.stack(outs))   # (k, B, 2), ONE transfer
+            seen = self.stamp_clock()
+            for row in rows:
+                for s, req in enumerate(self.slot_req):
+                    if req is None:
+                        continue
+                    tok, code = int(row[s, 0]), int(row[s, 1])
+                    if tok < 0:
+                        continue    # lane was prefilling / already finished
+                    self.counters.lane_steps += 1
+                    if not req.output:
+                        req.t_first = seen
+                    req.output.append(tok)
+                    self.slot_pos[s] += 1
+                    if code:
+                        req.done = True
+                        req.finish_reason = _FINISH_REASONS[code]
+                        self.completed.append(req)
+                        self.slot_req[s] = None
 
     # -- admission + prefill ----------------------------------------------
 
@@ -435,7 +506,14 @@ class ServeEngine:
         if not self.queue or len(free) < want:
             self._run_prefill()
             return
+        with obs.span("serve.admit"):
+            self._pop_into(free)
+        self._run_prefill()
+
+    def _pop_into(self, free: List[int]) -> None:
+        """Move queued requests into the free slots and reset those lanes."""
         newly: List[int] = []
+        now = self.stamp_clock()
         for slot in free:
             while self.queue:
                 req = self.queue.popleft()
@@ -446,6 +524,9 @@ class ServeEngine:
                     req.finish_reason = FINISH_STOP
                     self.completed.append(req)
                     continue
+                req.t_admit = now
+                if req.t_submit is not None:
+                    self.counters.queue_waits.append(now - req.t_submit)
                 self.slot_req[slot] = req
                 self.slot_pos[slot] = 0
                 self._prefilling[slot] = 0
@@ -459,7 +540,6 @@ class ServeEngine:
             mask[newly] = True
             self.cache = self._lane_reset(self.cache, self._cache_template,
                                           jnp.asarray(mask))
-        self._run_prefill()
 
     def _run_prefill(self) -> None:
         """Advance all mid-prefill slots, chunked and budgeted.
@@ -477,35 +557,46 @@ class ServeEngine:
             ready = [s for s, filled in self._prefilling.items()
                      if filled >= len(self.slot_req[s].prompt) - 1]
             if ready:
-                self._activate_slots(ready)
+                with obs.span("serve.admit"):
+                    self._activate_slots(ready)
             if not self._prefilling:
                 break
             if budget is not None and spent >= budget:
                 break
-            need = {s: len(self.slot_req[s].prompt) - 1 - filled
-                    for s, filled in self._prefilling.items()}
-            c = _pow2_chunk(max(need.values()), self.prefill_chunk)
-            toks = np.zeros((c, self.max_batch), np.int32)
-            poss = np.tile(np.maximum(self.slot_pos, 0).astype(np.int32),
-                           (c, 1))
-            writes = np.zeros((c, self.max_batch), bool)
-            for s, n in need.items():
-                filled = self._prefilling[s]
-                take = min(c, n)
-                prompt = self.slot_req[s].prompt
-                idx = np.arange(take)
-                toks[idx, s] = prompt[filled:filled + take]
-                poss[idx, s] = filled + idx
-                if take < c:
-                    # masked tail steps: hold a valid position, write=False
-                    poss[take:, s] = max(filled + take - 1, 0)
-                writes[:take, s] = True
-                self._prefilling[s] = filled + take
-                spent += take
-            self.cache = self._decode.prefill(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(poss), jnp.asarray(writes))
-            self.prefill_calls += 1
+            with obs.span("serve.prefill"):
+                spent += self._prefill_chunk()
+
+    def _prefill_chunk(self) -> int:
+        """Dispatch one prefill chunk for every mid-prefill slot; returns
+        the prompt tokens it writes."""
+        need = {s: len(self.slot_req[s].prompt) - 1 - filled
+                for s, filled in self._prefilling.items()}
+        c = _pow2_chunk(max(need.values()), self.prefill_chunk)
+        toks = np.zeros((c, self.max_batch), np.int32)
+        poss = np.tile(np.maximum(self.slot_pos, 0).astype(np.int32),
+                       (c, 1))
+        writes = np.zeros((c, self.max_batch), bool)
+        written = 0
+        for s, n in need.items():
+            filled = self._prefilling[s]
+            take = min(c, n)
+            prompt = self.slot_req[s].prompt
+            idx = np.arange(take)
+            toks[idx, s] = prompt[filled:filled + take]
+            poss[idx, s] = filled + idx
+            if take < c:
+                # masked tail steps: hold a valid position, write=False
+                poss[take:, s] = max(filled + take - 1, 0)
+            writes[:take, s] = True
+            self._prefilling[s] = filled + take
+            written += take
+        self.cache = self._decode.prefill(
+            self.params, self.cache, jnp.asarray(toks),
+            jnp.asarray(poss), jnp.asarray(writes))
+        self.counters.prefill_calls += 1
+        self.counters.prefill_positions += c
+        self.counters.prefill_writes += written
+        return written
 
     def _activate_slots(self, slots: List[int]) -> None:
         """Prompts fully written: arm the lanes to decode from their last
@@ -537,6 +628,8 @@ class ServeEngine:
     def _dispatch_step(self) -> None:
         if not self._any_decoding():
             return
-        self.cache, self._state, out = self._decode.step(
-            self.params, self.cache, self._state)
-        self._pending_out.append(out)
+        with obs.span("serve.step"):
+            self.cache, self._state, out = self._decode.step(
+                self.params, self.cache, self._state)
+            self._pending_out.append(out)
+        self.counters.decode_steps += 1

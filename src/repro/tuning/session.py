@@ -33,6 +33,7 @@ import threading
 from collections import OrderedDict
 from typing import Callable, Dict, Mapping, Optional, Tuple, Union
 
+from repro import obs
 from repro.core.analytical import AnalyticalTuner
 from repro.core.bayesian import BayesianTuner, TuneResult
 from repro.core.exhaustive import ExhaustiveSearch, RandomSearch
@@ -333,6 +334,9 @@ def default_session() -> TunerSession:
         with _DEFAULT_LOCK:
             if _DEFAULT is None:
                 _DEFAULT = TunerSession()
+                # every kernel path resolves through here before its
+                # first compile, so the process counts compiles from now
+                obs.watch_compiles()
     return _DEFAULT
 
 

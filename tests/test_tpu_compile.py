@@ -79,6 +79,40 @@ def test_prefix_sum_compiles(compile_for, variant, n):
     assert "tpu_custom_call" in text
 
 
+MULTIPASS = {"tile_n": 128, "rows_per_program": 8, "radix": 4}
+
+
+def _kernel_names(text):
+    """Base HLO instruction names of the Pallas kernels in ``text``."""
+    return sorted(line.split(" = ", 1)[0].split()[-1].lstrip("%")
+                  .split(".", 1)[0]
+                  for line in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line)
+
+
+@pytest.mark.parametrize("variant,shape,config,want", [
+    ("ks", (4096, 1024), None, ["scan_ks"]),
+    ("lf", (4096, 1024), None, ["scan_lf"]),
+    ("linrec", (4096, 1024), None, ["scan_linrec"]),
+    ("ks", (8, 16384), dict(MULTIPASS, unroll=2),
+     ["scan_ks_apply", "scan_ks_carry", "scan_ks_chunk"]),
+    ("linrec", (8, 16384), MULTIPASS,
+     ["scan_linrec_apply", "scan_linrec_carry", "scan_linrec_chunk"]),
+])
+def test_scan_kernels_named_by_variant(compile_for, variant, shape, config,
+                                       want):
+    """A device trace tells the scan kernels apart by their HLO
+    instruction names: the variant, and a stage suffix when multi-pass."""
+    from repro.kernels.scan.ops import linear_recurrence, prefix_sum
+    if variant == "linrec":
+        text = compile_for(lambda a, b: linear_recurrence(
+            a, b, config=config, **COMPILED), (shape, F32), (shape, F32))
+    else:
+        text = compile_for(lambda x: prefix_sum(
+            x, variant=variant, config=config, **COMPILED), (shape, F32))
+    assert _kernel_names(text) == want
+
+
 def test_linear_recurrence_compiles(compile_for):
     from repro.kernels.scan.ops import linear_recurrence
     shape = ((TOTAL // 1024, 1024), F32)
