@@ -11,8 +11,18 @@ TPU guideline (re-derivation of the paper's four rules):
   2. Else maximize grid parallelism while lane utilization stays in
      [0.60, 1.00] (the paper's warp-occupancy band).
   3. Else maximize lane utilization; among ties prefer larger unroll (ILP).
-  4. If the pattern admits a larger radix, prefer it even at reduced grid
-     parallelism (fewer passes/sync points, more ILP).
+  4. Rank the stage circuit, exact (every stage at the nominal fan-in)
+     before ragged, even at reduced grid parallelism.  Where stages pay a
+     barrier (``HardwareProfile.stage_sync_s`` > 0, the paper's GPU case)
+     prefer the larger radix: fewer stages, fewer sync points.  Where they
+     do not (a TPU core runs a Pallas body's stages as straight-line
+     vector code), a stage costs the work in it: a radix-r Kogge-Stone
+     stage folds r - 1 lane-shifted neighbours, and a fold whose offset is
+     below the lane count rotates and selects inside a vector register
+     while a farther one only re-indexes whole registers.  So circuits of
+     ``shift_fold`` / ``linrec_level`` stages rank by fewer in-vreg folds,
+     then fewer cross-vreg folds (which picks radix 2 for power-of-two
+     tiles); FFT butterflies and tridiagonal stages keep the larger radix.
 """
 from __future__ import annotations
 
@@ -21,6 +31,7 @@ import math
 from typing import Dict, List, Optional, Tuple
 
 from repro.core.space import Config, SearchSpace
+from repro.hw.profiles import HardwareProfile
 
 OVERLAP_GRID = 4          # grid programs needed for full DMA/compute overlap
 OCCUPANCY_BAND = (0.60, 1.00)
@@ -31,7 +42,8 @@ OCCUPANCY_BAND = (0.60, 1.00)
 # missing quantity as 0
 RESOURCE_KEYS = ("grid", "vmem", "occupancy", "ilp", "radix", "passes",
                  "block_bytes", "seq_tiles", "stage_count", "steps_per_pass",
-                 "ragged", "lane_eff", "sublane_eff")
+                 "ragged", "lane_eff", "sublane_eff", "shift_circuit",
+                 "lane_folds", "vreg_folds")
 
 
 @dataclasses.dataclass
@@ -48,7 +60,11 @@ class AnalyticalScore:
     seq_rank: float        # TPU twist on the same premise: a fused carry
     #                        chain serializes its column tiles, so fewer
     #                        sequential tiles rank next
-    radix_rank: float      # rule 4
+    circuit_rank: Tuple[float, float, float]   # rule 4: (exact, then the
+    #                        circuit's cost; see ``_circuit_rank``)
+    radix_rank: float      # rule 4 as the GPU guideline states it
+    #                        (exact, then larger radix); kept as an ML
+    #                        feature, the key reads ``circuit_rank``
     block_rank: float      # TPU adaptation of the paper's Ba maximization:
     #                        once >= OVERLAP_GRID programs keep the pipeline
     #                        full, BIGGER DMA blocks win (grid programs are
@@ -58,10 +74,11 @@ class AnalyticalScore:
 
     def key(self) -> Tuple:
         # Lexicographic: tier, then pass count (§IV-C), then carry-chain
-        # depth, then radix (rule 4 overrides block choice), then the
-        # tier-specific objective, then ILP tie-break.
-        return (self.tier, self.pass_rank, self.seq_rank, self.radix_rank,
-                self.block_rank, self.occupancy, self.ilp_rank)
+        # depth, then the stage circuit (rule 4 overrides block choice),
+        # then the tier-specific objective, then ILP tie-break.
+        return (self.tier, self.pass_rank, self.seq_rank,
+                *self.circuit_rank, self.block_rank, self.occupancy,
+                self.ilp_rank)
 
 
 def resources(space: SearchSpace, cfg: Config) -> Dict[str, float]:
@@ -101,12 +118,11 @@ def score(space: SearchSpace, cfg: Config,
     else:
         tier = 0
 
-    # rule 4: larger radix preferred when it cuts passes/steps — but only
-    # stage sequences that stay at the nominal fan-in throughout; a ragged
-    # mixed-radix tail needs an extra odd step and more synchronizations
-    # (the paper's own observation on WM's jagged performance), so the
-    # expert ranks every exact radix above every mixed one.  The raggedness
-    # comes from the plan's actual stage sequence, not a re-derivation.
+    # rule 4: only stage sequences that stay at the nominal fan-in
+    # throughout rank first; a ragged mixed-radix tail needs an extra odd
+    # step and more synchronizations (the paper's own observation on WM's
+    # jagged performance).  The raggedness comes from the plan's actual
+    # stage sequence, not a re-derivation.
     exact = 0 if res.get("ragged") else 1
     radix_rank = exact * 16.0 + math.log2(max(res["radix"], 2))
     # TPU rule 1/2 objective: biggest DMA block that still leaves the
@@ -118,8 +134,18 @@ def score(space: SearchSpace, cfg: Config,
         block_rank = -1.0   # starves the pipeline: strictly worse
     return AnalyticalScore(tier, -res["passes"],
                            -math.log2(max(res.get("seq_tiles", 1), 1)),
-                           radix_rank, block_rank, occ,
-                           math.log2(max(res["ilp"], 1)))
+                           _circuit_rank(res, spec, exact), radix_rank,
+                           block_rank, occ, math.log2(max(res["ilp"], 1)))
+
+
+def _circuit_rank(res: Dict[str, float], spec: HardwareProfile,
+                  exact: int) -> Tuple[float, float, float]:
+    """Rule 4's rank of the stage circuit (module docstring): fewer
+    lane-shifted folds for a shift-fold circuit on a barrier-free profile,
+    else the larger radix."""
+    if res["shift_circuit"] and spec.stage_sync_s == 0:
+        return (float(exact), -res["lane_folds"], -res["vreg_folds"])
+    return (float(exact), math.log2(max(res["radix"], 2)), 0.0)
 
 
 class AnalyticalTuner:

@@ -25,8 +25,9 @@ model retargets across devices by swapping the profile.
     It intentionally models more mechanisms (DMA ramp, issue pipelines,
     pass overheads, mixed-radix penalties) than the analytical guideline
     consumes, so analytical-vs-BO comparisons on it are meaningful.  Under
-    ``tpu_v5e`` its latency arithmetic is bit-identical to the historical
-    ``TPUCostModelObjective`` (pinned by fixture test); the energy model
+    ``tpu_v5e`` its latency arithmetic is pinned bit for bit by a fixture
+    test (``_step_sync_s`` charges the scan family no per-stage barrier
+    there, as measured on the chip); the energy model
     (``idle_w``/``peak_compute_w``/``hbm_pj_per_byte`` profile fields) is
     additional output, never an input to the latency path.
   * ``PolicyObjective`` (``repro.core.policy``) — adapts any vector
@@ -242,6 +243,19 @@ class WallClockObjective(Objective):
             return Measurement(PENALTY_TIME, False)
 
 
+def _step_sync_s(wl: Workload, spec: HardwareProfile) -> float:
+    """Barrier the model charges per in-kernel step.
+
+    A shift-fold circuit's stage pays the profile's ``stage_sync_s`` (0 on
+    a TPU core, where a Pallas body's stages are straight-line vector
+    code); other stage loops keep the per-pass barrier as their step
+    price.
+    """
+    if wl.op in ("scan", "ssd", "rglru"):
+        return spec.stage_sync_s
+    return spec.pass_sync_s
+
+
 def _flops_and_passes(wl: Workload, cfg: Config) -> Dict[str, float]:
     """Operation-specific work model for the cost objective."""
     n = wl.n
@@ -449,8 +463,7 @@ class CostModelObjective(Objective):
     constant comes from the :class:`~repro.hw.profiles.HardwareProfile`, so
     the same model retargets by swapping the profile — the paper's
     portability mechanism. Under ``tpu_v5e`` the latency arithmetic is
-    bit-identical to the historical ``TPUCostModelObjective`` (pinned by
-    fixture test).
+    pinned bit for bit by a fixture test.
 
     Beyond ``time_s`` the model emits two more metric axes from the same
     intermediates:
@@ -545,7 +558,7 @@ class CostModelObjective(Objective):
         overlap = 1.0 if grid >= 4 else (0.85 if grid >= 2 else 0.55)
         t_body = max(t_comp, t_mem) / overlap + (1.0 - overlap) * min(t_comp, t_mem) * 0.1
         passes = work["passes"]
-        t = passes * (spec.kernel_launch_s + t_body / passes + work["steps"] / passes * spec.pass_sync_s)
+        t = passes * (spec.kernel_launch_s + t_body / passes + work["steps"] / passes * _step_sync_s(wl, spec))
         t *= 1.0 + 0.25 * work.get("mixed_radix", 0.0)
         t *= self._jitter(wl, cfg)
         # energy/memory axes, derived from the latency intermediates (the
@@ -649,7 +662,8 @@ class CostModelObjective(Objective):
                 + (1.0 - overlap) * np.minimum(t_comp, t_mem) * 0.1
             passes = work["passes"]
             t = passes * (spec.kernel_launch_s + t_body / passes
-                          + work["steps"] / passes * spec.pass_sync_s)
+                          + work["steps"] / passes
+                          * _step_sync_s(wl, spec))
             t = t * (1.0 + 0.25 * work["mixed_radix"])
             if self.noise:
                 t = t * np.array([self._jitter(wl, c) for c in cfgs])

@@ -21,8 +21,9 @@ Registry
 Three concrete profiles ship (see docs/hardware.md for the field
 glossary and how to add a device):
 
-* ``tpu_v5e``   — the historical constants, **bit-identical** costs to the
-  pre-profile ``TPUCostModelObjective`` (pinned by fixture test);
+* ``tpu_v5e``   — the historical constants; costs pinned bit for bit by a
+  fixture test (its scan-family records recaptured when the per-stage
+  barrier became ``stage_sync_s`` = 0);
 * ``gpu_sm``    — a CUDA-core/SMEM-shaped profile in the spirit of the
   paper's GM20B table, with the Pallas Triton backend's geometry (warp
   lanes, tensor-core tile, kernel-relaunch sync);
@@ -51,8 +52,9 @@ class HardwareProfile:
     """One device's architectural constants (the paper's Table of limits).
 
     Field defaults ARE the TPU v5e machine model — ``HardwareProfile()``
-    is bit-identical to the historical ``TpuSpec()`` so every cost the
-    pre-profile stack computed is reproduced exactly.
+    carries the historical ``TpuSpec()`` constants, plus
+    ``stage_sync_s`` = 0: a TPU core runs a kernel body's stages without
+    a barrier between them.
     """
 
     name: str = "tpu_v5e"
@@ -83,6 +85,10 @@ class HardwareProfile:
     dma_latency_s: float = 2e-6           # per-block DMA issue latency
     kernel_launch_s: float = 5e-6         # fixed kernel-launch overhead
     pass_sync_s: float = 1.5e-6           # per-pass barrier/flush cost
+    stage_sync_s: float = 0.0             # barrier between the stages of
+    #                                       one kernel body (0: a program's
+    #                                       stages are straight-line vector
+    #                                       code, as on a TPU core)
     dma_half_bytes: int = 64 * 2**10      # DMA ramp half-saturation point
     ilp_base: float = 0.55                # issue utilization at unroll=1
     ilp_slope: float = 0.15               # utilization gained per doubling
@@ -122,6 +128,9 @@ GPU_SM = HardwareProfile(
     dma_latency_s=1e-6,
     kernel_launch_s=8e-6,                 # CUDA launch overhead
     pass_sync_s=4e-6,                     # global barrier == kernel relaunch
+    stage_sync_s=4e-6,                    # a stage's lane shifts cross
+    #                                       warps, so stages sync; priced
+    #                                       like the pass barrier
     dma_half_bytes=32 * 2**10,            # coalescing saturates earlier
     ilp_base=0.60,
     ilp_slope=0.10,
@@ -153,6 +162,8 @@ CPU_INTERPRET = HardwareProfile(
     dma_latency_s=1e-7,
     kernel_launch_s=50e-6,                # interpret-mode dispatch is slow
     pass_sync_s=1e-6,
+    stage_sync_s=1e-6,                    # interpret mode dispatches each
+    #                                       stage's ops from the host
     dma_half_bytes=4 * 2**10,             # streaming saturates quickly
     ilp_base=0.70,
     ilp_slope=0.10,

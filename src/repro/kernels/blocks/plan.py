@@ -132,6 +132,26 @@ def stage_strides(stages: Tuple[int, ...]) -> Tuple[int, ...]:
     return tuple(strides)
 
 
+def shift_fold_counts(stages: Tuple[int, ...],
+                      lane_count: int) -> Tuple[int, int]:
+    """Lane-shifted folds per element of a Kogge-Stone stage loop.
+
+    Stage ``i`` of fan-in ``r`` and stride ``s`` folds the neighbours at
+    offsets ``k * s`` for ``k`` in 1 .. r - 1 (``shift_fold`` /
+    ``linrec_level``).  Returns ``(in_vreg, cross_vreg)``: folds whose
+    offset is below ``lane_count`` rotate and select lanes inside a vector
+    register; the others only re-index whole registers.
+    """
+    in_vreg = cross_vreg = 0
+    for r, stride in zip(stages, stage_strides(stages)):
+        for k in range(1, r):
+            if k * stride < lane_count:
+                in_vreg += 1
+            else:
+                cross_vreg += 1
+    return in_vreg, cross_vreg
+
+
 def is_ragged(stages: Tuple[int, ...], nominal: int, span: int) -> bool:
     """Mixed-radix tail check shared by every plan builder.
 
@@ -259,6 +279,10 @@ class StagePlan:
     # read+write roundtrip but never appear in ``launches``
     xla_passes: int = 0
     children: Tuple["StagePlan", ...] = ()
+    # (in-vreg, cross-vreg) lane-shifted folds per element when the stage
+    # loop is a shift_fold / linrec_level circuit (``shift_fold_counts``);
+    # None for butterfly, PCR and stage-less plans
+    shift_folds: Optional[Tuple[int, int]] = None
 
     @property
     def stage_count(self) -> int:
@@ -330,6 +354,7 @@ class StagePlan:
         Every quantity is read off the plan — there is no independent
         re-derivation left in the analytical model or the featurizer.
         """
+        folds = self.shift_folds or (0, 0)
         return {
             "grid": float(self.grid_size),
             "vmem": float(self.vmem_bytes),
@@ -344,6 +369,9 @@ class StagePlan:
             "ragged": 1.0 if self.ragged else 0.0,
             "lane_eff": float(self.lane_eff),
             "sublane_eff": float(self.sublane_eff),
+            "shift_circuit": 0.0 if self.shift_folds is None else 1.0,
+            "lane_folds": float(folds[0]),
+            "vreg_folds": float(folds[1]),
         }
 
 
@@ -382,6 +410,9 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     planes = 3 if linrec else 2                  # (a, b) in + h out vs in + out
     trailing, lane, sub, occ = _occ(tile_n, rows, spec)
     ragged = is_ragged(stages, radix, tile_n)
+    # the resident tile's stage loop; the multi-pass carry scan folds one
+    # element per tile, so its circuit is left out of the per-element count
+    folds = shift_fold_counts(stages, spec.lane_count)
 
     if seq_tiles > seq_limit and tile_n < wl.n:
         # §IV-C m-kernel path: per-chunk scan, chunk-carry scan, apply.
@@ -415,7 +446,7 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
             block_bytes=rows * tile_n * eb, element_bytes=eb,
             trailing=trailing, lane_eff=lane, sublane_eff=sub, occupancy=occ,
             ilp=unroll * (2 if cfg.get("in_register") else 1), ragged=ragged,
-            steps_per_pass=float(len(stages)))
+            steps_per_pass=float(len(stages)), shift_folds=folds)
 
     grid = (batch // rows, seq_tiles)
     launch = Launch(wl.op, grid, (rows, tile_n), stages,
@@ -430,7 +461,7 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         block_bytes=rows * tile_n * eb, element_bytes=eb, trailing=trailing,
         lane_eff=lane, sublane_eff=sub, occupancy=occ,
         ilp=unroll * (2 if cfg.get("in_register") else 1), ragged=ragged,
-        steps_per_pass=float(len(stages)))
+        steps_per_pass=float(len(stages)), shift_folds=folds)
 
 
 def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
@@ -447,7 +478,9 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     the unfused phase-B child models the nc-length transition scan per
     (batch) row, not the S*P row fan-out ``driver.linrec_rows`` resolves
     at launch.  ``plan_for_chain(wl, cfg, dims=(S, P))`` rebuilds the
-    exact embedded launches for the conformance suite."""
+    exact embedded launches for the conformance suite.  Only phase B runs
+    a shift-fold circuit, so only the unfused plan reports fold counts
+    (its child's)."""
     base = _prefix_plan(wl, cfg, spec, seq_limit)
     chunk = base.tile_n
     nc = max(wl.n // max(chunk, 1), 1)
@@ -460,14 +493,16 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     if nc <= 1:
         # single chunk: intra kernel alone already yields the answer
         return dataclasses.replace(base, kind="fused", seq_tiles=1,
-                                   launches=(intra,), vmem_bytes=chunk_vmem)
+                                   launches=(intra,), vmem_bytes=chunk_vmem,
+                                   shift_folds=None)
     if int(cfg.get("fuse", 0)):
         state_apply = Launch("ssd-state-apply", (base.batch, nc),
                              (1, chunk), (), chunk_vmem)
         launches = (intra, state_apply)
         return dataclasses.replace(
             base, kind="two-phase", seq_tiles=nc, launches=launches,
-            passes=len(launches), vmem_bytes=chunk_vmem, children=())
+            passes=len(launches), vmem_bytes=chunk_vmem, children=(),
+            shift_folds=None)
     child = _prefix_plan(
         Workload(op="scan", n=nc, batch=base.batch, dtype=wl.dtype,
                  variant="linrec"),
@@ -479,7 +514,7 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     return dataclasses.replace(
         base, kind="three-phase", seq_tiles=nc, launches=launches,
         passes=len(launches), vmem_bytes=max(l.vmem_bytes for l in launches),
-        children=(child,))
+        children=(child,), shift_folds=child.shift_folds)
 
 
 def _tridiag_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile

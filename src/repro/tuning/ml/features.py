@@ -39,7 +39,10 @@ from repro.kernels.blocks.plan import plan_for
 # v6: "log2_vmem" / "vmem_fits" read a launch's full VMEM — double-buffered
 # blocks, scratch and fold temporaries, bounded by the compile limit — where
 # they read the resident io blocks before.
-FEATURE_VERSION = 6
+# v7: "ana_rank_pct" orders by the analytical key whose rule-4 term ranks a
+# shift-fold circuit by its lane-shifted folds on barrier-free profiles;
+# "radix_rank" still reads rule 4's larger-radix value.
+FEATURE_VERSION = 7
 
 FEATURE_NAMES = (
     # workload (Input Parameters `A`)

@@ -16,8 +16,9 @@ from repro.core.space import Workload, build_space
 from repro.hw.profiles import TPU_V5E as V5E
 from repro.kernels.blocks import driver
 from repro.kernels.blocks.plan import (DEFAULT_SEQ_LIMIT, build_plan,
-                                       plan_for, stage_radices,
-                                       stage_strides, wm_chunk)
+                                       plan_for, shift_fold_counts,
+                                       stage_radices, stage_strides,
+                                       wm_chunk)
 from repro.tuning.registry import normalizer_for
 
 
@@ -42,6 +43,51 @@ def test_stage_radices_prefers_nominal_fan_in():
     assert stage_radices(96, 8) == (8, 6, 2)      # ragged mixed-radix tail
     assert stage_radices(96, 3) == (3, 2, 2, 2, 2, 2)
     assert stage_radices(97, 2) == (97,)          # prime falls through whole
+
+
+@pytest.mark.parametrize("tile,radix,folds", [
+    (512, 8, (15, 6)),      # offsets 1-7, 8-56, 64 | 128-448
+    (4096, 2, (7, 5)),      # offsets 1-64 | 128-2048
+    (256, 4, (10, 2)),      # 1-3, 4-12, 16-48, 64 | 128, 192
+    (1024, 4, (10, 5)),
+    (128, 2, (7, 0)),
+    (96, 8, (13, 0)),       # ragged (8, 6, 2): strides 1, 8, 48
+])
+def test_shift_fold_counts(tile, radix, folds):
+    assert shift_fold_counts(stage_radices(tile, radix), V5E.lane_count) \
+        == folds
+
+
+def test_plans_report_shift_folds_of_their_fold_circuit():
+    scan = Workload(op="scan", n=512, batch=2**17, variant="linrec")
+    plan = plan_for(scan, {"tile_n": 512, "rows_per_program": 8,
+                           "radix": 8}, profile=V5E)
+    assert plan.shift_folds == (15, 6)
+    res = plan.resources()
+    assert (res["shift_circuit"], res["lane_folds"], res["vreg_folds"]) \
+        == (1.0, 15.0, 6.0)
+    # multi-pass: the resident chunk's circuit, not the carry scan's
+    multi = plan_for(Workload(op="scan", n=2**16, batch=4, variant="ks"),
+                     {"tile_n": 256, "rows_per_program": 2, "radix": 4},
+                     profile=V5E)
+    assert multi.kind == "multipass"
+    assert multi.shift_folds == shift_fold_counts((4, 4, 4, 4),
+                                                  V5E.lane_count)
+    # SSD: only the unfused chain runs a fold circuit (phase B, the child)
+    ssd = Workload(op="ssd", n=512, batch=16, variant="")
+    unfused = plan_for(ssd, {"tile_n": 128, "radix": 2, "fuse": 0},
+                       profile=V5E)
+    assert unfused.shift_folds == unfused.children[0].shift_folds == (2, 0)
+    assert plan_for(ssd, {"tile_n": 128, "radix": 2, "fuse": 1},
+                    profile=V5E).shift_folds is None
+    # butterflies, PCR and stage-less plans carry no fold circuit
+    for wl in (Workload(op="fft", n=128, batch=8, variant="stockham"),
+               Workload(op="tridiag", n=128, batch=8, variant="pcr"),
+               Workload(op="attention", n=256, batch=4, variant="flash")):
+        cfg = build_space(wl, V5E).enumerate_valid()[0]
+        plan = plan_for(wl, cfg, profile=V5E)
+        assert plan.shift_folds is None
+        assert plan.resources()["shift_circuit"] == 0.0
 
 
 # ---------------------------------------------------------------------------

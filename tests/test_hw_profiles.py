@@ -4,7 +4,9 @@ Three contracts:
 
   1. **tpu_v5e is bit-identical to the pre-profile stack** — every scalar
      and batched cost reproduces the fixture captured before the
-     refactor, down to the float bit pattern (``float.hex``).
+     refactor, down to the float bit pattern (``float.hex``); the scan,
+     ssd and rglru records were recaptured when the profile's per-stage
+     barrier (``stage_sync_s``) went to 0.
   2. **Every registered profile is usable end to end** — for each op the
      registry knows, the profile-bounded space is non-empty and every
      sampled StagePlan / cost-model quantity is finite.

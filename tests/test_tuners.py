@@ -93,3 +93,96 @@ def test_session_tune_populates_db(tmp_path):
     res = TunerSession(db=db).tune(wl, method="bayesian")
     assert db.lookup(wl) == res.best_config
     assert res.evaluations > 0
+
+
+# ---------------------------------------------------------------------------
+# Rule 4 on a barrier-free profile: the stage circuit with the fewest
+# lane-shifted folds (the paper's 2^26-element scan sizes)
+# ---------------------------------------------------------------------------
+
+_PAPER_SCAN_NS = (128, 256, 512, 1024, 2048, 4096)
+
+
+def _paper_suggestion(profile, op, variant, n):
+    from repro.hw.profiles import get_profile
+    wl = Workload(op=op, n=n, batch=2**26 // n, variant=variant)
+    space = build_space(wl, get_profile(profile))
+    return space, AnalyticalTuner().suggest(space)
+
+
+@pytest.mark.parametrize("n", _PAPER_SCAN_NS)
+@pytest.mark.parametrize("variant", ["ks", "lf", "linrec"])
+def test_tpu_scan_suggestion_has_fewest_in_vreg_folds(variant, n):
+    from repro.kernels.blocks.plan import plan_for
+    space, cfg = _paper_suggestion("tpu_v5e", "scan", variant, n)
+    wl, spec = space.workload, space.spec
+    folds = {c["radix"]: plan_for(wl, c, profile=spec).shift_folds[0]
+             for c in space.enumerate_valid() if c["tile_n"] == n}
+    assert plan_for(wl, cfg, profile=spec).shift_folds[0] \
+        == min(folds.values())
+    assert cfg["radix"] == 2 and folds[2] < min(folds[4], folds[8])
+
+
+@pytest.mark.parametrize("n", _PAPER_SCAN_NS)
+def test_tpu_ks_and_lf_resolve_one_config(n, tmp_path):
+    """ks and lf run the same kernel, so one config compiles one program."""
+    from repro.hw.profiles import get_profile
+    from repro.tuning import TunerSession
+    session = TunerSession(db=TuningDB(path=str(tmp_path / "db.json")),
+                           spec=get_profile("tpu_v5e"))
+    ks, lf = (session.resolve(
+        Workload(op="scan", n=n, batch=2**26 // n, variant=v))
+        for v in ("ks", "lf"))
+    assert ks == lf
+
+
+# (profile, op, variant, n) -> (tile_n, rows_per_program, radix, unroll,
+# in_register): the suggestions rule 4 gave before it ranked fold
+# circuits.  The GPU profile pays a barrier per stage and keeps the larger
+# radix; FFT and tridiagonal stages keep it on every profile.
+_RULE4_PINNED = {
+    ("gpu_sm", "scan", "ks", 128): (128, 512, 2, 8, 1),
+    ("gpu_sm", "scan", "ks", 256): (256, 512, 4, 8, 0),
+    ("gpu_sm", "scan", "ks", 512): (512, 256, 8, 8, 0),
+    ("gpu_sm", "scan", "ks", 1024): (1024, 128, 4, 8, 0),
+    ("gpu_sm", "scan", "ks", 2048): (2048, 128, 2, 8, 0),
+    ("gpu_sm", "scan", "ks", 4096): (4096, 32, 8, 8, 0),
+    ("gpu_sm", "scan", "lf", 128): (128, 512, 2, 8, 1),
+    ("gpu_sm", "scan", "lf", 256): (256, 512, 4, 8, 0),
+    ("gpu_sm", "scan", "lf", 512): (512, 256, 8, 8, 0),
+    ("gpu_sm", "scan", "lf", 1024): (1024, 128, 4, 8, 0),
+    ("gpu_sm", "scan", "lf", 2048): (2048, 128, 2, 8, 0),
+    ("gpu_sm", "scan", "lf", 4096): (4096, 32, 8, 8, 0),
+    ("gpu_sm", "scan", "linrec", 128): (128, 512, 2, 1, 1),
+    ("gpu_sm", "scan", "linrec", 256): (256, 256, 4, 1, 0),
+    ("gpu_sm", "scan", "linrec", 512): (512, 128, 8, 1, 0),
+    ("gpu_sm", "scan", "linrec", 1024): (1024, 64, 4, 1, 0),
+    ("gpu_sm", "scan", "linrec", 2048): (2048, 64, 2, 1, 0),
+    ("gpu_sm", "scan", "linrec", 4096): (4096, 16, 8, 1, 0),
+    ("tpu_v5e", "fft", "stockham", 64): (64, 256, 8, 4, 0),
+    ("tpu_v5e", "fft", "stockham", 128): (128, 256, 2, 4, 0),
+    ("tpu_v5e", "fft", "stockham", 256): (256, 256, 16, 4, 0),
+    ("tpu_v5e", "fft", "stockham", 512): (512, 256, 8, 4, 0),
+    ("tpu_v5e", "fft", "stockham", 1024): (1024, 128, 4, 4, 0),
+    ("tpu_v5e", "fft", "stockham", 2048): (2048, 128, 2, 4, 0),
+    ("tpu_v5e", "fft", "stockham", 4096): (4096, 16, 16, 4, 0),
+    ("tpu_v5e", "tridiag", "pcr", 64): (64, 256, 2, 4, 1),
+    ("tpu_v5e", "tridiag", "pcr", 128): (128, 256, 2, 4, 1),
+    ("tpu_v5e", "tridiag", "pcr", 256): (256, 256, 2, 4, 1),
+    ("tpu_v5e", "tridiag", "pcr", 512): (512, 256, 2, 4, 1),
+    ("tpu_v5e", "tridiag", "pcr", 1024): (1024, 256, 2, 4, 1),
+    ("tpu_v5e", "tridiag", "wm", 64): (64, 1, 8, 1, 0),
+    ("tpu_v5e", "tridiag", "wm", 128): (128, 1, 2, 1, 0),
+    ("tpu_v5e", "tridiag", "wm", 256): (256, 1, 4, 1, 0),
+    ("tpu_v5e", "tridiag", "wm", 512): (512, 1, 8, 1, 0),
+    ("tpu_v5e", "tridiag", "wm", 1024): (1024, 1, 4, 1, 0),
+}
+
+
+@pytest.mark.parametrize("profile,op,variant,n", sorted(_RULE4_PINNED))
+def test_rule4_suggestions_unchanged_where_stages_sync_or_do_not_fold(
+        profile, op, variant, n):
+    _, cfg = _paper_suggestion(profile, op, variant, n)
+    knobs = ("tile_n", "rows_per_program", "radix", "unroll", "in_register")
+    assert tuple(cfg[k] for k in knobs) == _RULE4_PINNED[profile, op,
+                                                         variant, n]
