@@ -8,12 +8,15 @@ Poisson schedule at the cell's fixed rate, and a request's latency
 counts from when it was due.  Closed loop: each client sends its next
 request when it has seen the previous one finish.
 
-After the window, the first token of every request due in it is waited
-for (at most ``drain_s``; one that never comes has failed).  Then the
-program's state is freed, and a seeded sample of the finished requests,
-the longest among them, is run through the plain reference: the number
-compared is the widest gap by which a served token's logit lies below
-the reference's best logit at that position.
+A request that fell due while the engine's last call of the window ran
+is sent when the window closes.  After the window, the first token of
+every request due in it is waited for (at most ``drain_s``; one that
+never comes has failed).  Then the program's state is freed, and a
+seeded sample of the finished requests, the longest among them, is run
+through the plain reference.  At each position it reads the gap by which
+the served token's logit lies below the reference's best logit; the
+cell's settings name the summaries compared (the widest or the mean gap)
+and their limits.
 """
 from __future__ import annotations
 
@@ -172,6 +175,10 @@ def _window(ctx: RunContext, loop: _Loop) -> Dict[str, float]:
                 time.sleep(max(min(nxt, deadline) - time.perf_counter(), 0))
     marks["end"] = time.perf_counter()
     marks["queued"] = len(loop.engine.queue)
+    # requests that fell due while the last engine call ran are sent now,
+    # late; their latency counts from when they were due
+    for spec in pending[i:]:
+        loop.submit(spec, t0 + spec.arrival)
     if "trace_start" in marks and "trace_end" not in marks:
         marks["trace_end"] = marks["end"]
         ctx.tracer.stop()
@@ -223,8 +230,8 @@ def _reference_check(ctx: RunContext, loop: _Loop) -> Dict[str, object]:
             "control": {} if ctl is None else _summarise(ctl, mask, count)}
 
 
-# the numbers compared: the widest gap catches a single wrong token; the
-# mean separates a lower precision, whose near-ties flip far more often
+# the gaps read: the widest gap catches a single wrong token; the mean
+# separates a lower precision, whose near-ties flip far more often
 GAPS = ("max_logit_gap", "mean_logit_gap")
 
 
@@ -262,11 +269,15 @@ def run(ctx: RunContext) -> Outcome:
     failed = sum(1 for t in due if not t.stamps)
 
     window = end - t0
-    tokens = sum(sum(1 for s in t.stamps if s <= end) for t in loop.tracks)
+    # tokens/s: output tokens the host saw by the deadline over the window's
+    # set length, so that where the deadline falls in the last engine call
+    # (a decode step or a long prefill) does not move the rate
+    tokens = sum(sum(1 for s in t.stamps if s <= deadline)
+                 for t in loop.tracks)
     ttft = [t.stamps[0] - t.due if t.stamps else float("inf") for t in due]
     itl = [b - a for t in loop.tracks
            for a, b in zip(t.stamps, t.stamps[1:]) if b <= end]
-    e2e = {"tokens_per_s": rate(tokens, window),
+    e2e = {"tokens_per_s": rate(tokens, ctx.seconds),
            "ttft_p95_ms": percentile(ttft, 95) * 1e3 if ttft else float("inf"),
            "itl_p95_ms": percentile(itl, 95) * 1e3 if itl else float("inf")}
     ctx.log(f"[serve] window={window:.3f}s due={len(due)} "
@@ -281,7 +292,10 @@ def run(ctx: RunContext) -> Outcome:
                 f"{max(lag) * 1e3:.3f} ms, p95 {percentile(lag, 95) * 1e3:.3f}"
                 " ms")
 
-    readings = {}
+    # queue time of each request due in the window: from when it was due
+    # (not when the client got round to submitting it) to its admission
+    readings = {"queue_wait_s": [t.obj.t_admit - t.due for t in due
+                                 if t.obj.t_admit is not None]}
     if "trace_start" in marks:
         a, b = marks["trace_start"], marks["trace_end"]
         out_tokens = sum(1 for t in loop.tracks for s in t.stamps
@@ -295,11 +309,14 @@ def run(ctx: RunContext) -> Outcome:
     del engine, params
     gc.collect()
     result = _reference_check(ctx, loop)
-    ctx.log(f"[check] compared {result['tokens']} served tokens")
-    limits = settings["limits"]
-    check = {name: {"value": value, "limit": float(limits[name])}
-             for name, value in result["numbers"].items()}
-    controls = result["control"]
+    numbers = result["numbers"]
+    ctx.log(f"[check] compared {result['tokens']} served tokens; gaps: "
+            + ", ".join(f"{k} {v!r}" for k, v in numbers.items()))
+    # the cell's settings name the numbers compared, each with its limit
+    check = {name: {"value": numbers[name], "limit": float(limit)}
+             for name, limit in settings["limits"].items()}
+    controls = {name: v for name, v in result["control"].items()
+                if name in check}
     return Outcome(attempted=len(due), failed=failed, setup_s=setup_s,
                    end_to_end=e2e, check=check, memory_peak_bytes=memory,
                    readings=readings, controls=controls)

@@ -47,13 +47,11 @@ SETTINGS = {
     "serve.tiny.chat": dict(SERVE_COMMON, rate=40.0,
                             engine={"max_batch": 4, "max_len": 64},
                             check={"sample": 12, "bucket": 48},
-                            limits={"max_logit_gap": 0.06,
-                                    "mean_logit_gap": 0.002}),
+                            limits={"mean_logit_gap": 0.002}),
     "serve.tiny.gen": dict(SERVE_COMMON, clients=4, rounds=8,
                            engine={"max_batch": 4, "max_len": 64},
                            check={"sample": 2, "bucket": 32},
-                           limits={"max_logit_gap": 0.06,
-                                    "mean_logit_gap": 0.002}),
+                           limits={"mean_logit_gap": 0.002}),
 }
 CELLS = [("ops.scan", "tiny-ops", "scan"), ("ops.fft_pcr", "tiny-ops", "fft_pcr"),
          ("serve.tiny.chat", "tiny-mamba", "chat"),

@@ -14,7 +14,7 @@ def root(tmp_path_factory):
 
 
 @pytest.mark.parametrize("cell", ["ops.scan", "ops.fft_pcr",
-                                  "serve.tiny.chat"])
+                                  "serve.tiny.chat", "serve.tiny.gen"])
 def test_control_fails_where_the_program_passes(root, cell):
     result, outcome = kit.run_cell(root, cell, seed=2 ** 31 + 99,
                                    seconds=0.3, control=True)

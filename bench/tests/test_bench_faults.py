@@ -20,7 +20,7 @@ def root(tmp_path_factory):
     ("ops.scan", "answer"), ("ops.fft_pcr", "answer"),
     ("ops.scan", "half_batch"), ("ops.fft_pcr", "half_batch"),
     ("serve.tiny.chat", "token"), ("serve.tiny.chat", "state"),
-    ("serve.tiny.gen", "state")])
+    ("serve.tiny.gen", "token"), ("serve.tiny.gen", "state")])
 def test_planted_fault_is_not_correct(root, cell, fault):
     with planted(fault):
         result, _ = kit.run_cell(root, cell, seconds=0.3)

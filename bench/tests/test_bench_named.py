@@ -27,7 +27,7 @@ DATA = os.path.join(BENCH, "tests", "data")
 sys.path.insert(0, BENCH)
 
 import trace_reduce as tr  # noqa: E402
-from harness import named  # noqa: E402
+from harness import counts, named  # noqa: E402
 from harness.cells import load_module  # noqa: E402
 from harness.device import PEAKS  # noqa: E402
 from harness.run_context import MetricContext  # noqa: E402
@@ -36,8 +36,9 @@ V5E = PEAKS["TPU v5 lite"]
 SERVE_SPANS = ("serve.admit", "serve.prefill", "serve.step", "serve.harvest")
 
 
-def read(metric, trace, readings=None, max_batch=32):
-    cell = types.SimpleNamespace(settings={"engine": {"max_batch": max_batch}})
+def read(metric, trace, readings=None, max_batch=32, config=None):
+    cell = types.SimpleNamespace(settings={"engine": {"max_batch": max_batch}},
+                                 config=config)
     ctx = MetricContext(trace=trace, readings=readings or {}, peaks=V5E,
                         cell=cell)
     return load_module(BENCH, "metrics", metric).read(ctx)
@@ -155,6 +156,25 @@ def test_serving_readers_on_engine_trace(spans_trace, spans_readings):
         assert wait == pytest.approx(
             100 * named.span_seconds(spans_trace, "serve.harvest")
             / spans_trace.window_s)
+
+
+@pytest.mark.parametrize("cell", ["gen"])
+def test_serving_mfu_on_engine_trace(spans_trace, spans_readings, cell):
+    """Traced prompt and output tokens times the model's flops a token,
+    over the window and the bf16 peak.  The recorded run's prompt tokens
+    are the ones its engine wrote (``prefill_writes``)."""
+    with open(os.path.join(BENCH, "configs", "mamba2-130m.json")) as f:
+        cfg = json.load(f)
+    tokens = {"prompt": spans_readings["stats"]["prefill_writes"],
+              "output": spans_readings["traced_tokens"]["output"]}
+    value = read(f"mfu_pct.{cell}", spans_trace, {"traced_tokens": tokens},
+                 config=cfg)
+    flops = (tokens["prompt"] * counts.mamba2_flops_per_token(cfg, False)
+             + tokens["output"] * counts.mamba2_flops_per_token(cfg, True))
+    assert value == pytest.approx(
+        100 * flops / spans_trace.window_s / V5E["bf16_flops_per_s"])
+    assert 0 < value < 100
+    assert read(f"mfu_pct.{cell}", spans_trace, {}, config=cfg) is None
 
 
 def test_readers_silent_without_the_program_marks(monkeypatch):
