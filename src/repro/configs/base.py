@@ -21,11 +21,13 @@ class ModelConfig:
     activation: str = "swiglu"        # swiglu | geglu | gelu
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    use_rope: bool = True             # False: no position encoding (NoPE)
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     # attention
     attn_window: Optional[int] = None     # local sliding window (recurrentgemma)
     sub_quadratic: bool = False           # supports 500k-token decode
+    attn_scale: float = 0.0               # softmax scale; 0 = 1/sqrt(head_dim)
     # MoE
     n_experts: int = 0
     n_shared_experts: int = 0
@@ -38,7 +40,13 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_width: int = 4
-    # hybrid (recurrentgemma): layer pattern period, e.g. ("rec","rec","attn")
+    # the published Mamba-2 mixer (arXiv:2405.21060): input x * dt into the
+    # state, a per-head skip y += D x, and a bias on the depthwise conv
+    ssm_dt_input: bool = False
+    ssm_d_skip: bool = False
+    ssm_conv_bias: bool = False
+    # hybrid: layer pattern period of "rec" (RG-LRU), "ssd" (Mamba-2) and
+    # "attn" layers, each followed by its MLP, e.g. ("rec","rec","attn")
     block_pattern: Tuple[str, ...] = ()
     lru_width: int = 0
     # enc-dec (whisper)
@@ -57,6 +65,12 @@ class ModelConfig:
     #                                       sp shrinks per-layer remat saves
     #                                       by the model-axis size)
     logits_softcap: float = 0.0
+    # muP multipliers (granite): embedding x embed_scale (0 = sqrt(d_model)),
+    # each mixer and MLP output x residual_scale before the residual add,
+    # logits / logits_scaling
+    embed_scale: float = 0.0
+    residual_scale: float = 1.0
+    logits_scaling: float = 1.0
     # distribution hints (set by the launcher; 0/() = no explicit
     # constraints, e.g. host smoke tests without a mesh context)
     model_axis_size: int = 0
@@ -148,7 +162,8 @@ def _load_all() -> None:
     import importlib
     for mod in ("gemma_2b", "minitron_4b", "qwen15_05b", "granite_34b",
                 "whisper_large_v3", "llama32_vision_90b", "qwen2_moe_a27b",
-                "qwen3_moe_30b_a3b", "recurrentgemma_9b", "mamba2_130m"):
+                "qwen3_moe_30b_a3b", "recurrentgemma_9b", "mamba2_130m",
+                "granite_4_0_h_micro"):
         importlib.import_module(f"repro.configs.{mod}")
 
 

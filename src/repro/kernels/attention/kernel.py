@@ -83,19 +83,22 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "causal",
-                                             "window", "interpret"))
+                                             "window", "scale", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            block_q: int = 256, block_k: int = 256,
                            causal: bool = True,
                            window: Optional[int] = None,
+                           scale: Optional[float] = None,
                            interpret: bool = False) -> jax.Array:
-    """q: (BH, Lq, D), k/v: (BH, Lk, D) -> (BH, Lq, D)."""
+    """q: (BH, Lq, D), k/v: (BH, Lk, D) -> (BH, Lq, D); ``scale`` is the
+    softmax scale (None: 1/sqrt(D))."""
     BH, lq, d = q.shape
     lk = k.shape[1]
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
     grid = (BH, lq // block_q, lk // block_k)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, lq=lq, lk=lk)

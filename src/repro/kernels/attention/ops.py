@@ -26,6 +26,7 @@ def _normalize(cfg, wl, dims=None):
               normalize=_normalize, variants=("flash",))
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
+              scale: Optional[float] = None,
               config: Optional[dict] = None,
               interpret: Optional[bool] = None,
               use_pallas: Optional[bool] = None) -> jax.Array:
@@ -33,15 +34,17 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     GQA callers repeat KV heads before the call. Decode (Lq == 1) always
     takes the XLA path — it is a GEMV-shaped, memory-bound op where flash
-    tiling has nothing to add.
+    tiling has nothing to add.  ``scale`` is the softmax scale (None:
+    1/sqrt(D)).
     """
     BH, lq, d = q.shape
     lk = k.shape[1]
     use_pallas, interpret = plan_execution(use_pallas, interpret, gate=lq > 1)
     if not use_pallas or lq == 1:
-        return attention_ref(q, k, v, causal=causal, window=window)
+        return attention_ref(q, k, v, causal=causal, window=window,
+                             scale=scale)
     cfg = default_session().resolve(
         Workload(op="attention", n=lk, batch=BH, variant="flash"),
         config=config, dims={"lq": lq, "lk": lk})
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  interpret=interpret, **cfg)
+                                  scale=scale, interpret=interpret, **cfg)

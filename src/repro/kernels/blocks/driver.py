@@ -265,13 +265,15 @@ def _linrec_space_valid(n: int) -> bool:
 
 
 def linrec_rows(a: jax.Array, b: jax.Array, *, use_pallas: bool,
-                interpret: bool, config: Optional[dict] = None) -> jax.Array:
+                interpret: bool, config: Optional[dict] = None,
+                name: Optional[str] = None) -> jax.Array:
     """Tuned linear recurrence over (rows, n) — the shared carry-chain
     block composite kernels (SSD phase-B, tridiag LF sweeps) call.
 
     Resolves the (op="scan", variant="linrec") workload through the
     session, builds its StagePlan, and dispatches fused or multi-pass
-    exactly like the public ``linear_recurrence`` entry point.
+    exactly like the public ``linear_recurrence`` entry point.  ``name``
+    names the launches (multi-pass: with stage suffixes).
     """
     from repro.kernels.scan.ref import scan_linrec_assoc_ref
     rows, n = a.shape
@@ -286,7 +288,7 @@ def linrec_rows(a: jax.Array, b: jax.Array, *, use_pallas: bool,
     cfg = default_session().resolve(wl, config=config)
     plan = plan_for(wl, cfg)
     if plan.kind == "multipass":
-        return multipass_linrec(a, b, plan, interpret=interpret)
+        return multipass_linrec(a, b, plan, interpret=interpret, name=name)
     return launch(scan_linrec_pallas, plan.launches[0], a, b,
                   rows_per_program=plan.rows, tile_n=plan.tile_n,
-                  stages=plan.stages, interpret=interpret)
+                  stages=plan.stages, interpret=interpret, name=name)

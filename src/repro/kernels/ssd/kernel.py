@@ -18,6 +18,12 @@ folded into the same launch.
 Tunables: chunk length Q (the VMEM tile; tile_n in the tuning space),
 rows via the grid, and the chain-fusion boundary (``fuse``). Q is
 hardware-aligned to the 128-lane MXU edge.
+
+Each launch carries a name, which becomes its HLO instruction name on a
+device trace: ``ssd_chunk`` (phase A), ``ssd_carry`` (phase B: the fused
+B + C launch, or unfused the embedded linear-recurrence scan) and
+``ssd_apply`` (unfused phase C) — the stage names of the multi-pass scan
+driver, so the three read as one kernel family ``ssd``.
 """
 from __future__ import annotations
 
@@ -96,9 +102,9 @@ def _vector_specs(chunk: int):
             pl.BlockSpec((1, 1, chunk), lambda i, j: (i, 0, j)))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
 def ssd_intra_pallas(x, la, b, c, *, chunk: int = 128,
-                     interpret: bool = False):
+                     interpret: bool = False, name: str = "ssd_chunk"):
     """x: (BH, L, P); la: (BH, L) chunk-cumulative log decay
     (``chunk_log_decay``); b, c: (BH, L, S) — b/c pre-broadcast.
 
@@ -127,6 +133,7 @@ def ssd_intra_pallas(x, la, b, c, *, chunk: int = 128,
         ],
         compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
+        name=name,
     )(x, la[:, :, None], la[:, None, :], b, c)
     return y, st
 
@@ -156,9 +163,9 @@ def _state_apply_kernel(y_ref, lac_ref, c_ref, ac_ref, st_ref, o_ref,
     carry_ref[...] = ac * ent + st
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
 def ssd_state_apply_pallas(y_intra, la, c, state, *, chunk: int = 128,
-                           interpret: bool = False):
+                           interpret: bool = False, name: str = "ssd_carry"):
     """Fused inter-chunk recurrence + apply (chain ``fuse=1``): one launch.
 
     y_intra: (BH, L, P); la: (BH, L) chunk-cumulative log decay;
@@ -190,12 +197,13 @@ def ssd_state_apply_pallas(y_intra, la, c, state, *, chunk: int = 128,
         scratch_shapes=[pltpu.VMEM((S, P), jnp.float32)],
         compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name=name,
     )(y_intra, la[:, :, None], c, a_chunk, state)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
 def ssd_apply_entry_pallas(y_intra, la, c, entry, *, chunk: int = 128,
-                           interpret: bool = False):
+                           interpret: bool = False, name: str = "ssd_apply"):
     """Adds the inter-chunk contribution. entry: (BH, nc, S, P)."""
     BH, L, P = y_intra.shape
     S = c.shape[-1]
@@ -214,4 +222,5 @@ def ssd_apply_entry_pallas(y_intra, la, c, entry, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((BH, L, P), y_intra.dtype),
         compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
+        name=name,
     )(y_intra, la[:, :, None], c, entry)

@@ -102,7 +102,8 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     s_rows = jnp.transpose(state, (0, 2, 3, 1))          # (BH, S, P, nc)
     h = driver.linrec_rows(a_rows.reshape(-1, nc), s_rows.reshape(-1, nc),
                            use_pallas=True, interpret=interpret,
-                           config={"tile_n": nc, "radix": radix})
+                           config={"tile_n": nc, "radix": radix},
+                           name="ssd_carry")
     h = h.reshape(B * H, S, P, nc)
     entry = jnp.concatenate(
         [jnp.zeros_like(h[..., :1]), h[..., :-1]], axis=-1)

@@ -15,6 +15,14 @@ def _mlp_params(d: int, f: int) -> int:
     return 3 * d * f                       # gate, up, down
 
 
+def _ssd_params(cfg: ModelConfig) -> int:
+    d = cfg.d_model
+    d_inner = cfg.ssm_expand * d
+    n_heads = d_inner // cfg.ssm_head_dim
+    return (d * (2 * d_inner + 2 * cfg.ssm_state + n_heads)   # in_proj
+            + d_inner * d)                                     # out_proj
+
+
 def active_param_count(cfg: ModelConfig) -> int:
     """Matmul-active parameters per token (MoE: only routed-in experts)."""
     d = cfg.d_model
@@ -39,22 +47,19 @@ def active_param_count(cfg: ModelConfig) -> int:
         per_layer += d * cfg.n_experts     # router
         return per_layer * cfg.n_layers
     if cfg.family == "ssm":
-        d_inner = cfg.ssm_expand * d
-        n_heads = d_inner // cfg.ssm_head_dim
-        per_layer = d * (2 * d_inner + 2 * cfg.ssm_state + n_heads)  # in_proj
-        per_layer += d_inner * d           # out_proj
+        per_layer = _ssd_params(cfg)
         if cfg.d_ff:
             per_layer += _mlp_params(d, cfg.d_ff)
         return per_layer * cfg.n_layers
     if cfg.family == "hybrid":
         w = cfg.lru_width or d
-        rec_layer = 2 * d * w + 2 * w * w + w * d + _mlp_params(d, cfg.d_ff)
-        attn_layer = _attn_params(cfg) + _mlp_params(d, cfg.d_ff)
+        mixer = {"rec": 2 * d * w + 2 * w * w + w * d,
+                 "ssd": _ssd_params(cfg), "attn": _attn_params(cfg)}
         period = cfg.block_pattern
-        n_rec = sum(1 for k in period if k == "rec")
-        n_att = len(period) - n_rec
         groups = cfg.n_layers // max(len(period), 1)
-        return groups * (n_rec * rec_layer + n_att * attn_layer)
+        # every layer of the period has its MLP
+        return groups * sum(mixer[k] + _mlp_params(d, cfg.d_ff)
+                            for k in period)
     raise ValueError(cfg.family)
 
 

@@ -58,14 +58,22 @@ def _score_constraint(h: int, lq: int, model_axis: int) -> Optional[P]:
     return None
 
 
+def softmax_scale(cfg: ModelConfig, head_dim: int):
+    """The configured softmax scale, else 1/sqrt(head_dim)."""
+    if cfg.attn_scale:
+        return jnp.asarray(cfg.attn_scale, jnp.float32)
+    return 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
+
+
 def _attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool, window: Optional[int], compute_dtype,
-                    model_axis: int, q_offset) -> jax.Array:
+                    model_axis: int, q_offset, scale=None) -> jax.Array:
     """One (B, Lq, H, D) x (B, Lk, H, D) attention tile; q_offset is the
     global position of q[0] minus kpos[0] (supports q-chunking)."""
     bq, lq, h, d = q.shape
     lk = k.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     cons = _score_constraint(h, lq, model_axis)
     if cons is not None:
@@ -93,7 +101,8 @@ _Q_CHUNK = 1024
 
 def _attention_4d(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool, window: Optional[int],
-                  compute_dtype, model_axis: int = 0) -> jax.Array:
+                  compute_dtype, model_axis: int = 0,
+                  scale=None) -> jax.Array:
     """XLA-path attention keeping (B, L, H, D) layout end-to-end.
 
     Never merges the data-sharded batch dim with the model-sharded head dim
@@ -108,7 +117,8 @@ def _attention_4d(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if lq * lk <= _SCORE_ELEMS_LIMIT or lq % _Q_CHUNK or lq == lk == 0:
         return _attention_core(q, k, v, causal=causal, window=window,
                                compute_dtype=compute_dtype,
-                               model_axis=model_axis, q_offset=base_offset)
+                               model_axis=model_axis, q_offset=base_offset,
+                               scale=scale)
 
     nc = lq // _Q_CHUNK
     qr = jnp.moveaxis(q.reshape(bq, nc, _Q_CHUNK, h, d), 1, 0)
@@ -120,7 +130,7 @@ def _attention_4d(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return _attention_core(
                 qb, k, v, causal=causal, window=window,
                 compute_dtype=compute_dtype, model_axis=model_axis,
-                q_offset=idx * _Q_CHUNK + base_offset)
+                q_offset=idx * _Q_CHUNK + base_offset, scale=scale)
 
         return None, jax.checkpoint(run)(qb)
 
@@ -149,9 +159,11 @@ def self_attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
     q = _split_heads(dense(p["wq"], x, compute_dtype), hq, hd)
     k = _split_heads(dense(p["wk"], x, compute_dtype), hkv, hd)
     v = _split_heads(dense(p["wv"], x, compute_dtype), hkv, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     n_rep = hq // max(hkv, 1)
+    scale = softmax_scale(cfg, hd)
 
     if cache is not None and l == 1:
         pos = positions[:, 0]                                    # (B,)
@@ -172,7 +184,6 @@ def self_attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
 
         kk = _repeat_kv(ck.astype(compute_dtype), n_rep)         # (B,S,H,hd)
         vv = _repeat_kv(cv.astype(compute_dtype), n_rep)
-        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
         s = jnp.einsum("bhd,bshd->bhs", q[:, 0], kk).astype(jnp.float32)
         s = s * scale
         mask = slot_pos <= pos[:, None]                          # causal/valid
@@ -194,12 +205,12 @@ def self_attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
         kf = k.transpose(0, 2, 1, 3).reshape(b * hq, -1, hd)
         vf = v.transpose(0, 2, 1, 3).reshape(b * hq, -1, hd)
         of = attention_op(qf, kf, vf, causal=True, window=window,
-                          use_pallas=True)
+                          scale=cfg.attn_scale or None, use_pallas=True)
         o = of.reshape(b, hq, l, hd).transpose(0, 2, 1, 3)
     else:
         o = _attention_4d(q, k, v, causal=True, window=window,
                           compute_dtype=compute_dtype,
-                          model_axis=cfg.model_axis_size)
+                          model_axis=cfg.model_axis_size, scale=scale)
     o = o.reshape(b, l, hq * hd)
     return dense(p["wo"], o, compute_dtype), None
 
@@ -220,11 +231,13 @@ def cross_attention(p: Dict, x: jax.Array, memory: jax.Array,
         qf = q.transpose(0, 2, 1, 3).reshape(b * hq, l, hd)
         kf = k.transpose(0, 2, 1, 3).reshape(b * hq, m, hd)
         vf = v.transpose(0, 2, 1, 3).reshape(b * hq, m, hd)
-        of = attention_op(qf, kf, vf, causal=False, use_pallas=True)
+        of = attention_op(qf, kf, vf, causal=False,
+                          scale=cfg.attn_scale or None, use_pallas=True)
         o = of.reshape(b, hq, l, hd).transpose(0, 2, 1, 3)
     else:
         o = _attention_4d(q, k, v, causal=False, window=None,
                           compute_dtype=compute_dtype,
-                          model_axis=cfg.model_axis_size)
+                          model_axis=cfg.model_axis_size,
+                          scale=softmax_scale(cfg, hd))
     o = o.reshape(b, l, hq * hd)
     return dense(p["wo"], o, compute_dtype)

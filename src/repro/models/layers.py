@@ -107,9 +107,10 @@ def unembed(p: Dict, x: jax.Array, softcap: float = 0.0) -> jax.Array:
     return logits
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, cache: Optional[jax.Array] = None
+def causal_conv1d(x: jax.Array, w: jax.Array, cache: Optional[jax.Array] = None,
+                  bias: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv. x: (B, L, D); w: (K, D).
+    """Depthwise causal conv. x: (B, L, D); w: (K, D); bias: (D,) or None.
 
     Returns (y, new_cache) with cache = last K-1 inputs (for decode)."""
     K = w.shape[0]
@@ -119,5 +120,7 @@ def causal_conv1d(x: jax.Array, w: jax.Array, cache: Optional[jax.Array] = None
         ctx = jnp.concatenate([cache.astype(x.dtype), x], axis=1)
     y = sum(ctx[:, i:i + x.shape[1], :] * w[i][None, None, :]
             for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(y.dtype)
     new_cache = ctx[:, -(K - 1):, :] if K > 1 else ctx[:, :0, :]
     return y.astype(x.dtype), new_cache

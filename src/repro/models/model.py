@@ -3,7 +3,8 @@
 Layer stacks are `lax.scan`ned over parameter groups so HLO size is O(1) in
 depth (critical for 88–100-layer archs in the 512-device dry-run). A "group"
 is the architecture's repeating pattern:
-  dense/moe: 1 block;  hybrid: (rec, rec, local-attn);  vlm: 4 standard +
+  dense/moe: 1 block;  hybrid: its block_pattern, e.g. (rec, rec,
+  local-attn) or (ssd x5, attn, ssd x4);  vlm: 4 standard +
   1 cross-attn block;  ssm: 1 SSD block;  audio: enc stack + dec stack.
 
 Caches are pytrees with a leading group dimension threaded through the same
@@ -69,6 +70,28 @@ def _constrain_residual(x: jax.Array, cfg: ModelConfig) -> jax.Array:
         x, P(b, "model", P.UNCONSTRAINED))
 
 
+def _residual(x: jax.Array, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """x + h, with h scaled by the residual multiplier where one is set."""
+    if cfg.residual_scale != 1.0:
+        h = h * jnp.asarray(cfg.residual_scale, h.dtype)
+    return x + h
+
+
+def _embed_scale(cfg: ModelConfig, dtype) -> jax.Array:
+    if cfg.embed_scale:
+        return jnp.asarray(cfg.embed_scale, dtype)
+    return jnp.sqrt(jnp.asarray(cfg.d_model, dtype))
+
+
+def _logits(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Final norm, tied unembedding, softcap and logits divisor."""
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = L.unembed(params["embed"], x, cfg.logits_softcap)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
 # ---------------------------------------------------------------------------
 # blocks
 # ---------------------------------------------------------------------------
@@ -97,16 +120,17 @@ def _std_block(p: Dict, x, cfg: ModelConfig, *, positions, cache=None,
     h, new_cache = attn_mod.self_attention(
         p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps), cfg,
         positions=positions, cache=cache, window=window, compute_dtype=cd)
-    x = x + h
+    x = _residual(x, h, cfg)
     if "xattn" in p and memory is not None:
-        x = x + attn_mod.cross_attention(
-            p["xattn"], L.rms_norm(x, p["lnx"], cfg.norm_eps), memory, cfg, cd)
+        x = _residual(x, attn_mod.cross_attention(
+            p["xattn"], L.rms_norm(x, p["lnx"], cfg.norm_eps), memory, cfg,
+            cd), cfg)
     y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.family == "moe":
         h, aux = moe_block(p["moe"], y, cfg, cd)
     else:
         h = L.mlp(p["mlp"], y, cfg.activation, cd)
-    return x + h, new_cache, aux
+    return _residual(x, h, cfg), new_cache, aux
 
 
 def _init_ssd_group(key, cfg, dtype):
@@ -124,10 +148,10 @@ def _ssd_group(p, x, cfg, *, cache=None, compute_dtype=None):
     h, new_cache = ssm_mod.ssd_block(
         p["ssd"], L.rms_norm(x, p["ln"], cfg.norm_eps), cfg, cache=cache,
         compute_dtype=cd)
-    x = x + h
+    x = _residual(x, h, cfg)
     if p.get("mlp") is not None:
-        x = x + L.mlp(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps),
-                      cfg.activation, cd)
+        x = _residual(x, L.mlp(p["mlp"], L.rms_norm(x, p["ln2"], cfg.norm_eps),
+                               cfg.activation, cd), cfg)
     return x, new_cache, jnp.zeros((), jnp.float32)
 
 
@@ -135,7 +159,10 @@ def _init_hybrid_group(key, cfg, dtype):
     ks = jax.random.split(key, len(cfg.block_pattern))
     group = []
     for k, kind in zip(ks, cfg.block_pattern):
-        if kind == "rec":
+        if kind == "ssd":
+            # a Mamba-2 mixer, each with its own MLP
+            group.append(_init_ssd_group(k, cfg, dtype))
+        elif kind == "rec":
             k1, k2 = jax.random.split(k)
             group.append({"ln1": jnp.zeros((cfg.d_model,), dtype),
                           "rec": rec_mod.init_recurrent_block(k1, cfg, dtype),
@@ -152,15 +179,23 @@ def _hybrid_group(p, x, cfg, *, positions, cache=None, compute_dtype=None):
     new_caches = []
     for i, blk in enumerate(p["blocks"]):
         sub_cache = None if cache is None else cache[i]
-        if "rec" in blk:
+        if "ssd" in blk:
+            def run_ssd(xx, blk=blk):
+                y, nc, _ = _ssd_group(blk, xx, cfg, cache=sub_cache,
+                                      compute_dtype=cd)
+                return y, nc
+            if cache is None and cfg.remat == "full":
+                run_ssd = jax.checkpoint(run_ssd)
+            x, nc = run_ssd(x)
+        elif "rec" in blk:
             def run_rec(xx, blk=blk):
                 h, nc = rec_mod.recurrent_block(
                     blk["rec"], L.rms_norm(xx, blk["ln1"], cfg.norm_eps), cfg,
                     cache=sub_cache, compute_dtype=cd)
-                xx = xx + h
-                xx = xx + L.mlp(blk["mlp"],
-                                L.rms_norm(xx, blk["ln2"], cfg.norm_eps),
-                                cfg.activation, cd)
+                xx = _residual(xx, h, cfg)
+                xx = _residual(xx, L.mlp(
+                    blk["mlp"], L.rms_norm(xx, blk["ln2"], cfg.norm_eps),
+                    cfg.activation, cd), cfg)
                 return xx, nc
             if cache is None and cfg.remat == "full":
                 # per-layer remat: without it the whole group's forward
@@ -290,8 +325,7 @@ class Model:
         cd = _cdtype(cfg)
         b, l = tokens.shape
         x = L.embed(params["embed"], tokens, cd,
-                    one_hot=bool(cfg.batch_axes)) * jnp.sqrt(
-            jnp.asarray(cfg.d_model, cd))
+                    one_hot=bool(cfg.batch_axes)) * _embed_scale(cfg, cd)
         x = _constrain_batch(x, cfg)
         positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
         if cfg.is_enc_dec and memory is not None:
@@ -329,9 +363,7 @@ class Model:
         dec_blocks = params["blocks"]
         (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                    dec_blocks)
-        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = L.unembed(params["embed"], x, cfg.logits_softcap)
-        return logits, aux / max(n_dec, 1)
+        return _logits(params, x, cfg), aux / max(n_dec, 1)
 
     # --- KV / state caches ---
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16
@@ -354,7 +386,9 @@ class Model:
                 out = []
                 ring = cfg.attn_window is not None and cfg.attn_window < max_len
                 for kind in cfg.block_pattern:
-                    if kind == "rec":
+                    if kind == "ssd":
+                        out.append(ssm_mod.init_ssd_cache(cfg, batch, dtype))
+                    elif kind == "rec":
                         out.append(rec_mod.init_recurrent_cache(cfg, batch,
                                                                 dtype))
                     else:
@@ -378,8 +412,7 @@ class Model:
         """token: (B, 1); pos: (B, 1) absolute positions."""
         cfg = self.cfg
         cd = _cdtype(cfg)
-        x = L.embed(params["embed"], token, cd) * jnp.sqrt(
-            jnp.asarray(cfg.d_model, cd))
+        x = L.embed(params["embed"], token, cd) * _embed_scale(cfg, cd)
         x = _constrain_batch(x, cfg)
         if memory is not None:
             memory = memory.astype(cd)
@@ -407,9 +440,7 @@ class Model:
             return y, nc
 
         x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = L.unembed(params["embed"], x, cfg.logits_softcap)
-        return logits, new_cache
+        return _logits(params, x, cfg), new_cache
 
     # --- bulk prompt ingestion (single-dispatch prefill) ---
     def prefill(self, params, tokens: jax.Array, cache: PyTree,

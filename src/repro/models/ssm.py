@@ -24,7 +24,7 @@ def init_ssd_block(key, cfg: ModelConfig, dtype) -> Dict:
     ks = jax.random.split(key, 5)
     # fused input projection: [x (d_inner), z (d_inner), B (s), C (s), dt (H)]
     d_proj = 2 * d_inner + 2 * s + n_heads
-    return {
+    p = {
         "in_proj": init_dense(ks[0], d, d_proj, dtype),
         "conv_w": (jax.random.normal(ks[1], (cfg.conv_width,
                                              d_inner + 2 * s), jnp.float32)
@@ -34,6 +34,11 @@ def init_ssd_block(key, cfg: ModelConfig, dtype) -> Dict:
         "norm_scale": jnp.zeros((d_inner,), dtype),
         "out_proj": init_dense(ks[2], d_inner, d, dtype),
     }
+    if cfg.ssm_d_skip:
+        p["d_skip"] = jnp.ones((n_heads,), jnp.float32)
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = jnp.zeros((d_inner + 2 * s,), dtype)
+    return p
 
 
 def ssd_block(p: Dict, x: jax.Array, cfg: ModelConfig, *,
@@ -50,32 +55,36 @@ def ssd_block(p: Dict, x: jax.Array, cfg: ModelConfig, *,
     conv_in = jnp.concatenate([xz, bc], axis=-1)
     conv_out, conv_cache = causal_conv1d(
         conv_in, p["conv_w"].astype(compute_dtype),
-        cache=None if cache is None else cache["conv"])
+        cache=None if cache is None else cache["conv"], bias=p.get("conv_b"))
     conv_out = jax.nn.silu(conv_out)
     xs, b_in, c_in = jnp.split(conv_out, [d_inner, d_inner + s], axis=-1)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + p["dt_bias"][None, None, :])          # (B, L, H)
     a = jnp.exp(-jnp.exp(p["a_log"])[None, None, :] * dt)        # decay in (0,1)
-    xh = xs.reshape(bsz, L, n_heads, P)
+    xh = xs.reshape(bsz, L, n_heads, P).astype(jnp.float32)
+    # the state takes x * dt where the mixer discretizes its input
+    x_in = xh * dt[..., None] if cfg.ssm_dt_input else xh
 
     if cache is None or L > 1:
-        y = ssd_op(xh.astype(jnp.float32), a, b_in.astype(jnp.float32),
+        y = ssd_op(x_in, a, b_in.astype(jnp.float32),
                    c_in.astype(jnp.float32),
                    use_pallas=cfg.use_pallas or None)
         new_state = None  # prefill state capture handled by decode-from-scratch
     else:
         # O(1) decode step: h = a h + b x^T ; y = c . h
         h = cache["state"]
-        x_t = xh[:, 0]                                           # (B, H, P)
+        x_t = x_in[:, 0]                                         # (B, H, P)
         a_t = a[:, 0]                                            # (B, H)
         b_t = b_in[:, 0].astype(jnp.float32)                     # (B, S)
         c_t = c_in[:, 0].astype(jnp.float32)
         h = (a_t[..., None, None] * h
-             + jnp.einsum("bs,bhp->bhsp", b_t, x_t.astype(jnp.float32)))
+             + jnp.einsum("bs,bhp->bhsp", b_t, x_t))
         y = jnp.einsum("bs,bhsp->bhp", c_t, h)[:, None]          # (B,1,H,P)
         new_state = h
 
+    if "d_skip" in p:
+        y = y + p["d_skip"][:, None] * xh
     y = y.reshape(bsz, L, d_inner).astype(compute_dtype)
     y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
     out = dense(p["out_proj"], y, compute_dtype)

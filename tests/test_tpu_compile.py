@@ -155,6 +155,26 @@ def test_ssd_compiles(compile_for):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("fuse,want", [
+    (1, ["ssd_carry", "ssd_chunk"]),
+    (0, ["ssd_apply", "ssd_carry", "ssd_chunk"]),
+])
+def test_ssd_kernels_named(compile_for, fuse, want):
+    """A device trace tells the SSD launches apart by their HLO
+    instruction names: ``ssd_chunk`` (intra-chunk), ``ssd_carry`` (the
+    fused state-and-apply launch, or unfused the embedded linear
+    recurrence) and ``ssd_apply`` (unfused apply); granite-4.0-h-micro's
+    widths at 8192 tokens."""
+    from repro.kernels.ssd.ops import ssd
+    B, L, H, P, S = 1, 8192, 64, 64, 128
+    config = {"tile_n": 256, "radix": 2, "fuse": fuse}
+    text = compile_for(
+        lambda x, a, b, c: ssd(x, a, b, c, config=config, **COMPILED),
+        ((B, L, H, P), F32), ((B, L, H), F32), ((B, L, S), F32),
+        ((B, L, S), F32))
+    assert _kernel_names(text) == want
+
+
 def test_flash_attention_compiles(compile_for):
     from repro.kernels.attention.ops import attention
     shape = ((64, 2048, 64), jnp.bfloat16)
