@@ -23,6 +23,14 @@ TPU guideline (re-derivation of the paper's four rules):
      ``shift_fold`` / ``linrec_level`` stages rank by fewer in-vreg folds,
      then fewer cross-vreg folds (which picks radix 2 for power-of-two
      tiles); FFT butterflies and tridiagonal stages keep the larger radix.
+
+Ahead of rule 4, after the tier and the pass count, a carry chain ranks
+by fewer sequential tiles.  An SSD chain is the exception: its phase A
+runs quadratic contractions over each chunk, so its work per element
+grows with the chunk length Q, while a chunk's own cost (its state's HBM
+roundtrip, a grid step per launch) shrinks per element as Q grows.  The
+plan models both (``intra_s`` and ``chunk_s``, seconds per element) and
+the chain ranks by their sum, which picks the chunk that balances them.
 """
 from __future__ import annotations
 
@@ -43,7 +51,7 @@ OCCUPANCY_BAND = (0.60, 1.00)
 RESOURCE_KEYS = ("grid", "vmem", "occupancy", "ilp", "radix", "passes",
                  "block_bytes", "seq_tiles", "stage_count", "steps_per_pass",
                  "ragged", "lane_eff", "sublane_eff", "shift_circuit",
-                 "lane_folds", "vreg_folds")
+                 "lane_folds", "vreg_folds", "intra_s", "chunk_s")
 
 
 @dataclasses.dataclass
@@ -59,7 +67,9 @@ class AnalyticalScore:
     #                        blocking preference
     seq_rank: float        # TPU twist on the same premise: a fused carry
     #                        chain serializes its column tiles, so fewer
-    #                        sequential tiles rank next
+    #                        sequential tiles rank next; an SSD chain,
+    #                        whose intra-chunk work grows with the chunk,
+    #                        ranks by its modelled time (``_seq_rank``)
     circuit_rank: Tuple[float, float, float]   # rule 4: (exact, then the
     #                        circuit's cost; see ``_circuit_rank``)
     radix_rank: float      # rule 4 as the GPU guideline states it
@@ -132,10 +142,18 @@ def score(space: SearchSpace, cfg: Config,
         block_rank = math.log2(min(max(res["block_bytes"], 1), 4 * 2**20))
     else:
         block_rank = -1.0   # starves the pipeline: strictly worse
-    return AnalyticalScore(tier, -res["passes"],
-                           -math.log2(max(res.get("seq_tiles", 1), 1)),
+    return AnalyticalScore(tier, -res["passes"], _seq_rank(res),
                            _circuit_rank(res, spec, exact), radix_rank,
                            block_rank, occ, math.log2(max(res["ilp"], 1)))
+
+
+def _seq_rank(res: Dict[str, float]) -> float:
+    """The carry-chain term: fewer sequential tiles, or, where the plan
+    models an SSD chain's time (module docstring), less of it."""
+    chain_s = res["intra_s"] + res["chunk_s"]
+    if chain_s > 0:
+        return -chain_s
+    return -math.log2(max(res.get("seq_tiles", 1), 1))
 
 
 def _circuit_rank(res: Dict[str, float], spec: HardwareProfile,

@@ -89,6 +89,12 @@ class HardwareProfile:
     #                                       one kernel body (0: a program's
     #                                       stages are straight-line vector
     #                                       code, as on a TPU core)
+    grid_step_s: float = 0.35e-6          # one step of a launch's grid
+    #                                       pipeline on a TPU v5e: the
+    #                                       ssd_carry launch at 64 x 8,192
+    #                                       rows took 0.35-0.39 us more per
+    #                                       added (row, chunk) step going
+    #                                       from chunk 512 to 128 (PERF.md)
     dma_half_bytes: int = 64 * 2**10      # DMA ramp half-saturation point
     ilp_base: float = 0.55                # issue utilization at unroll=1
     ilp_slope: float = 0.15               # utilization gained per doubling
@@ -131,6 +137,9 @@ GPU_SM = HardwareProfile(
     stage_sync_s=4e-6,                    # a stage's lane shifts cross
     #                                       warps, so stages sync; priced
     #                                       like the pass barrier
+    grid_step_s=0.0,                      # a grid's programs are CTAs
+    #                                       spread over the SMs, not steps
+    #                                       of one core's pipeline
     dma_half_bytes=32 * 2**10,            # coalescing saturates earlier
     ilp_base=0.60,
     ilp_slope=0.10,
@@ -164,6 +173,8 @@ CPU_INTERPRET = HardwareProfile(
     pass_sync_s=1e-6,
     stage_sync_s=1e-6,                    # interpret mode dispatches each
     #                                       stage's ops from the host
+    grid_step_s=1e-6,                     # interpret mode walks the grid
+    #                                       as a host loop
     dma_half_bytes=4 * 2**10,             # streaming saturates quickly
     ilp_base=0.70,
     ilp_slope=0.10,

@@ -74,6 +74,8 @@ _FFT_FOLD_TEMPS = 10         # + 4 r
 _PCR_TEMPS = 18
 # the SSD chunk kernels hold a few (Q, Q) f32 score/decay tiles
 _SSD_QQ_TEMPS = 4
+# bf16 matrix-unit passes of one f32 contraction at Precision.HIGHEST
+_SSD_MXU_PASSES = 6
 # flash attention holds the (block_q, block_k) scores and probabilities
 _FLASH_QK_TEMPS = 2
 # head / state widths a Workload does not carry: the planner assumes one
@@ -283,6 +285,10 @@ class StagePlan:
     # loop is a shift_fold / linrec_level circuit (``shift_fold_counts``);
     # None for butterfly, PCR and stage-less plans
     shift_folds: Optional[Tuple[int, int]] = None
+    # modelled seconds per element of an SSD chain (``_ssd_chunk_cost``):
+    # (intra-chunk matrix-unit work, the chunk's own costs spread over its
+    # elements); None for every other op
+    chunk_cost: Optional[Tuple[float, float]] = None
 
     @property
     def stage_count(self) -> int:
@@ -355,6 +361,7 @@ class StagePlan:
         re-derivation left in the analytical model or the featurizer.
         """
         folds = self.shift_folds or (0, 0)
+        intra_s, chunk_s = self.chunk_cost or (0.0, 0.0)
         return {
             "grid": float(self.grid_size),
             "vmem": float(self.vmem_bytes),
@@ -372,6 +379,8 @@ class StagePlan:
             "shift_circuit": 0.0 if self.shift_folds is None else 1.0,
             "lane_folds": float(folds[0]),
             "vreg_folds": float(folds[1]),
+            "intra_s": float(intra_s),
+            "chunk_s": float(chunk_s),
         }
 
 
@@ -464,6 +473,27 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         steps_per_pass=float(len(stages)), shift_folds=folds)
 
 
+def _ssd_chunk_cost(chunk: int, chunk_launches: int, spec: HardwareProfile
+                   ) -> Tuple[float, float]:
+    """Modelled seconds per element of the SSD chain at chunk length Q:
+    (intra-chunk matrix-unit work, the chunk's own costs ÷ Q).
+
+    Phase A's three f32 contractions a chunk, C·Bᵀ (Q×S×Q), scores·x
+    (Q×Q×P) and the chunk state (S×Q×P), run as ``_SSD_MXU_PASSES`` bf16
+    passes, each width rounded up to the unit's edge: 2·(Q·S + Q·P + S·P)
+    flops an element, the first two growing with Q.  Each chunk adds its
+    (S, P) f32 state, written by phase A and read by the carry, and one
+    grid step of each of the ``chunk_launches`` launches that walk the
+    chunks.  S and P are the model width ``_MODEL_MINOR``.
+    """
+    q, w = _round_up(chunk, spec.mxu_dim), _round_up(_MODEL_MINOR,
+                                                     spec.mxu_dim)
+    intra = (_SSD_MXU_PASSES * 2 * (2 * q * w + w * w)
+             / spec.peak_bf16_flops)
+    state = 2 * _MODEL_MINOR * _MODEL_MINOR * 4 / spec.hbm_bandwidth
+    return intra, (state + chunk_launches * spec.grid_step_s) / chunk
+
+
 def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
               seq_limit: int) -> StagePlan:
     """SSD chain: intra-chunk kernel → linrec over chunk transitions →
@@ -480,7 +510,8 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     at launch.  ``plan_for_chain(wl, cfg, dims=(S, P))`` rebuilds the
     exact embedded launches for the conformance suite.  Only phase B runs
     a shift-fold circuit, so only the unfused plan reports fold counts
-    (its child's)."""
+    (its child's).  Every SSD plan reports ``_ssd_chunk_cost``: phase A
+    and the apply (fused or not) each walk the chunks."""
     base = _prefix_plan(wl, cfg, spec, seq_limit)
     chunk = base.tile_n
     nc = max(wl.n // max(chunk, 1), 1)
@@ -494,7 +525,9 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         # single chunk: intra kernel alone already yields the answer
         return dataclasses.replace(base, kind="fused", seq_tiles=1,
                                    launches=(intra,), vmem_bytes=chunk_vmem,
-                                   shift_folds=None)
+                                   shift_folds=None,
+                                   chunk_cost=_ssd_chunk_cost(chunk, 1, spec))
+    cost = _ssd_chunk_cost(chunk, 2, spec)
     if int(cfg.get("fuse", 0)):
         state_apply = Launch("ssd-state-apply", (base.batch, nc),
                              (1, chunk), (), chunk_vmem)
@@ -502,7 +535,7 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         return dataclasses.replace(
             base, kind="two-phase", seq_tiles=nc, launches=launches,
             passes=len(launches), vmem_bytes=chunk_vmem, children=(),
-            shift_folds=None)
+            shift_folds=None, chunk_cost=cost)
     child = _prefix_plan(
         Workload(op="scan", n=nc, batch=base.batch, dtype=wl.dtype,
                  variant="linrec"),
@@ -514,7 +547,7 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     return dataclasses.replace(
         base, kind="three-phase", seq_tiles=nc, launches=launches,
         passes=len(launches), vmem_bytes=max(l.vmem_bytes for l in launches),
-        children=(child,), shift_folds=child.shift_folds)
+        children=(child,), shift_folds=child.shift_folds, chunk_cost=cost)
 
 
 def _tridiag_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile

@@ -42,7 +42,9 @@ from repro.kernels.blocks.plan import plan_for
 # v7: "ana_rank_pct" orders by the analytical key whose rule-4 term ranks a
 # shift-fold circuit by its lane-shifted folds on barrier-free profiles;
 # "radix_rank" still reads rule 4's larger-radix value.
-FEATURE_VERSION = 7
+# v8: "ana_rank_pct" orders an SSD chain's chunk by its modelled
+# intra-chunk and per-chunk time instead of by the fewest chunks.
+FEATURE_VERSION = 8
 
 FEATURE_NAMES = (
     # workload (Input Parameters `A`)

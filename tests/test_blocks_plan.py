@@ -445,6 +445,26 @@ def test_ssd_chain_odd_chunk_count_models_xla_fallback():
     assert len(fused.launches) == 2
 
 
+def test_ssd_plan_models_intra_work_against_per_chunk_cost():
+    """The SSD chain's two modelled terms: the intra-chunk matrix work
+    grows with the chunk (six bf16 passes of 2·(2·Q·128 + 128²) flops an
+    element), the chunk's own costs shrink per element; other ops report
+    neither."""
+    wl = Workload(op="ssd", n=8192, batch=64, variant="chunked")
+    res = [plan_for(wl, {"tile_n": q, "fuse": 1}, profile=V5E).resources()
+           for q in (128, 256, 512, 1024)]
+    intra = [r["intra_s"] for r in res]
+    chunk = [r["chunk_s"] for r in res]
+    assert intra == sorted(intra) and chunk == sorted(chunk, reverse=True)
+    assert intra[-1] == pytest.approx(
+        6 * 2 * (2 * 1024 * 128 + 128 ** 2) / V5E.peak_bf16_flops)
+    assert chunk[0] == pytest.approx(
+        (2 * 128 * 128 * 4 / V5E.hbm_bandwidth + 2 * V5E.grid_step_s) / 128)
+    scan = plan_for(Workload(op="scan", n=8192, batch=64, variant="linrec"),
+                    {"tile_n": 256}, profile=V5E).resources()
+    assert scan["intra_s"] == scan["chunk_s"] == 0.0
+
+
 def test_multipass_carry_unroll_clamped_at_extreme_seq_tiles():
     """Satellite fix: the workload-tuned unroll rides into the carry scan
     (l2) whose tile length is seq_tiles, not tile_n — at extreme

@@ -29,9 +29,13 @@ def test_ssd_chunked_ref_matches_sequential(chunk):
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("chunk", [64, 128])
+# chunk 256 at the model widths (S 128, P 64) over four chunks
+_PIPELINE_SHAPES = {256: dict(L=1024, P=64, S=128)}
+
+
+@pytest.mark.parametrize("chunk", [64, 128, 256])
 def test_ssd_pallas_pipeline(chunk):
-    x, a, b, c = _ssd_inputs()
+    x, a, b, c = _ssd_inputs(**_PIPELINE_SHAPES.get(chunk, {}))
     ref = ssd_ref(x, a, b, c)
     got = ssd(x, a, b, c, config={"tile_n": chunk}, interpret=True)
     np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-3)

@@ -176,6 +176,12 @@ _RULE4_PINNED = {
     ("tpu_v5e", "tridiag", "wm", 256): (256, 1, 4, 1, 0),
     ("tpu_v5e", "tridiag", "wm", 512): (512, 1, 8, 1, 0),
     ("tpu_v5e", "tridiag", "wm", 1024): (1024, 1, 4, 1, 0),
+    ("tpu_v5e", "rglru", "", 128): (128, 512, 2, 1, 1),
+    ("tpu_v5e", "rglru", "", 256): (256, 512, 2, 1, 1),
+    ("tpu_v5e", "rglru", "", 512): (512, 512, 2, 1, 1),
+    ("tpu_v5e", "rglru", "", 1024): (1024, 512, 2, 1, 1),
+    ("tpu_v5e", "rglru", "", 2048): (2048, 256, 2, 1, 0),
+    ("tpu_v5e", "rglru", "", 4096): (4096, 128, 2, 1, 0),
 }
 
 
@@ -186,3 +192,14 @@ def test_rule4_suggestions_unchanged_where_stages_sync_or_do_not_fold(
     knobs = ("tile_n", "rows_per_program", "radix", "unroll", "in_register")
     assert tuple(cfg[k] for k in knobs) == _RULE4_PINNED[profile, op,
                                                          variant, n]
+
+
+def test_tpu_ssd_chunk_ranks_by_intra_chunk_work():
+    """granite-4.0-h-micro's SSD at 8,192 tokens (64 heads): chunk 256,
+    the fastest of 128 ... 1024 on a TPU v5e (PERF.md), with phases B + C
+    fused.  Fewest chunks (1024) would quadruple the intra-chunk matmuls."""
+    from repro.hw.profiles import get_profile
+    space = build_space(Workload("ssd", n=8192, batch=64, variant="chunked"),
+                        get_profile("tpu_v5e"))
+    cfg = AnalyticalTuner().suggest(space)
+    assert (cfg["tile_n"], cfg["fuse"]) == (256, 1)
