@@ -20,10 +20,6 @@ Prints ``name,...`` CSV rows:
       (the BENCH_analysis gate: the full zero-execution lint — AST rules,
       fingerprints, op x profile invariants — must finish under 10 s and
       come back clean);
-  fusion              — fused vs unfused chain execution per chain
-      (the BENCH_fusion gate: the fused arm must save a planned HBM pass
-      on both chains, conform to its chain plan's launch list, and beat
-      unfused wall clock on rglru);
   serving             — multi-tenant trace through the optimized serving
       engine vs the per-token replay baseline (the BENCH_serving gates:
       >= 3x tokens/sec on full runs, prefill dispatches and host
@@ -50,7 +46,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma list: prefix_ops,convergence,roofline,"
                          "resolve,blocks,sweep,ml_predict,online,transfer,"
-                         "pareto,analysis,fusion,serving")
+                         "pareto,analysis,serving")
     ap.add_argument("--no-host-wallclock", action="store_true")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed for the stochastic sections (reproducible CI)")
@@ -113,9 +109,6 @@ def main() -> None:
         from benchmarks.bench_analysis import run as run_analysis
         gate_failures += run_analysis(emit, seed=args.seed,
                                       smoke=args.smoke)
-    if begin("fusion"):
-        from benchmarks.bench_fusion import run as run_fusion
-        gate_failures += run_fusion(emit, seed=args.seed, smoke=args.smoke)
     if begin("serving"):
         from benchmarks.bench_serving import run as run_serving
         gate_failures += run_serving(emit, seed=args.seed, smoke=args.smoke)

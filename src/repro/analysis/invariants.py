@@ -10,7 +10,7 @@ every ``known_ops()`` op under every registered
   * **plan soundness** — :meth:`StagePlan.check` per valid config (stage
     radix product == tile, positive grids/blocks, per-launch VMEM within
     the physical pool, scratch holds its BlockSpec block, pass count ==
-    launch count + the chain's XLA passes);
+    launch count);
   * **model agreement** — ``core.analytical.resources()`` reports the
     same pass count / VMEM / grid the plan carries, and every
     ``RESOURCE_KEYS`` quantity is present and finite;
@@ -105,12 +105,9 @@ def check_space(space: SearchSpace) -> List[Finding]:
 def _signatures(space: SearchSpace) -> List[Tuple]:
     """Per-candidate decision signature: everything any tuner can see.
 
-    (launch list, chain pass accounting, noise-free modeled cost,
-    analytical guideline key) — a knob that never moves any component can
-    never change any methodology's decision, online or offline.  The pass
-    accounting (``passes``/``xla_passes``) covers chain knobs like
-    ``fuse`` whose effect can be to *relabel* a launch list (fold an XLA
-    link into a kernel) without changing the Pallas launches themselves.
+    (launch list, pass count, noise-free modeled cost, analytical
+    guideline key) — a knob that never moves any component can never
+    change any methodology's decision, online or offline.
     """
     spec = space.spec
     obj = CostModelObjective(spec, noise=0.0)
@@ -120,8 +117,7 @@ def _signatures(space: SearchSpace) -> List[Tuple]:
     for cfg, cost in zip(cands, costs):
         plan = plan_for(space.workload, cfg, profile=spec)
         key = score(space, cfg, res=plan.resources()).key()
-        sigs.append((tuple(plan.launches), plan.passes, plan.xla_passes,
-                     float(cost), key))
+        sigs.append((tuple(plan.launches), plan.passes, float(cost), key))
     return sigs
 
 

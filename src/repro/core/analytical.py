@@ -59,12 +59,7 @@ class AnalyticalScore:
     tier: int              # 3 = rule-1 configs, 2 = rule-2, 1 = rule-3 (higher better)
     pass_rank: float       # paper §IV-C premise: minimize the number of
     #                        passes/kernels FIRST (each extra pass is a full
-    #                        HBM roundtrip) — ranks above the radix choice.
-    #                        Chain-aware: StagePlan.passes counts XLA chain
-    #                        links too (``xla_passes``), so the chain-fusion
-    #                        knob (``fuse``) is rewarded here — a fused
-    #                        chain's saved HBM pass ranks before any
-    #                        blocking preference
+    #                        HBM roundtrip) — ranks above the radix choice
     seq_rank: float        # TPU twist on the same premise: a fused carry
     #                        chain serializes its column tiles, so fewer
     #                        sequential tiles rank next; an SSD chain,

@@ -24,9 +24,10 @@ model retargets across devices by swapping the profile.
     model for one hardware profile, used as the offline-tuning "device".
     It intentionally models more mechanisms (DMA ramp, issue pipelines,
     pass overheads, mixed-radix penalties) than the analytical guideline
-    consumes, so analytical-vs-BO comparisons on it are meaningful.  Under
-    ``tpu_v5e`` its latency arithmetic is pinned bit for bit by a fixture
-    test (``_step_sync_s`` charges the scan family no per-stage barrier
+    consumes, so analytical-vs-BO comparisons on it are meaningful.  The
+    model is written once, over arrays of candidates; a single config is
+    a batch of one.  Under ``tpu_v5e`` its latency arithmetic is pinned
+    bit for bit by a fixture test (``_step_sync_s`` charges the scan family no per-stage barrier
     there, as measured on the chip); the energy model
     (``idle_w``/``peak_compute_w``/``hbm_pj_per_byte`` profile fields) is
     additional output, never an input to the latency path.
@@ -48,14 +49,10 @@ from repro.core.space import Config, SearchSpace, Workload
 from repro.hw.profiles import (
     HardwareProfile,
     active_profile,
-    dma_efficiency,
     dma_efficiency_arr,
     effective_element_bytes,
-    ilp_factor,
     ilp_factor_arr,
-    lane_utilization,
     lane_utilization_arr,
-    sublane_utilization,
     sublane_utilization_arr,
 )
 
@@ -256,73 +253,6 @@ def _step_sync_s(wl: Workload, spec: HardwareProfile) -> float:
     return spec.pass_sync_s
 
 
-def _flops_and_passes(wl: Workload, cfg: Config) -> Dict[str, float]:
-    """Operation-specific work model for the cost objective."""
-    n = wl.n
-    tile_n = cfg.get("tile_n", n)
-    r = cfg.get("radix", 2)
-    out: Dict[str, float] = {}
-    def mixed(tile: int, radix: int) -> float:
-        # ragged final circuit level when radix^k != tile: extra low-radix
-        # step + sync (paper's WM jagged-performance observation)
-        k = round(math.log(max(tile, 2), radix)) if radix > 1 else 1
-        return 0.0 if radix**k == tile else 1.0
-
-    if wl.op in ("scan", "ssd", "rglru"):
-        steps = math.ceil(math.log(max(tile_n, 2), r))
-        # Kogge-Stone does N work per step; Ladner-Fischer ~2N total but more
-        # steps of structure; model KS-like: n ops/step, radix-r node = r-1 adds
-        out["flops"] = steps * n * (r - 1) / max(r / 2, 1)
-        base_passes = math.ceil(math.log(max(n, 2), r) / math.log(max(tile_n, 2), r)) if tile_n < n else 1
-        fuse = cfg.get("fuse", 0)
-        if wl.op == "ssd":
-            # chain passes: intra + (linrec + apply, or the fused
-            # state-apply launch) — fuse=1 saves one HBM pass
-            out["passes"] = (3.0 - fuse) if tile_n < n else 1.0
-        elif wl.op == "rglru":
-            # gate link: a separate XLA pass unless folded into the scan
-            # kernel's first stage (fuse=1)
-            out["passes"] = base_passes + (1.0 - fuse)
-        else:
-            out["passes"] = base_passes
-        out["steps"] = steps
-        out["mixed_radix"] = mixed(tile_n, r)
-    elif wl.op == "tridiag":
-        steps = math.ceil(math.log2(max(n, 2))) if wl.variant in ("cr", "pcr") else math.ceil(math.log(max(n, 2), r))
-        per_step = 14 if wl.variant == "pcr" else 9  # PCR full-width; CR halves
-        work_n = n if wl.variant == "pcr" else 2 * n
-        out["flops"] = steps * work_n * per_step / max(math.log2(r), 1)
-        out["passes"] = 1
-        out["steps"] = steps
-        out["mixed_radix"] = mixed(tile_n, r) if wl.variant == "wm" else 0.0
-    elif wl.op in ("fft", "large_fft"):
-        # radix-r Stockham: log_r(N) stages, each stage ~5N flops equivalent
-        stages_total = math.log(max(n, 2), r)
-        out["flops"] = 5.0 * n * math.log2(max(n, 2))  # canonical 5NlogN
-        s = math.log(max(tile_n, 2), r)
-        out["passes"] = max(1, math.ceil(stages_total / max(s, 1)))
-        out["steps"] = math.ceil(stages_total)
-        # mixed-radix penalty (paper Fig 5 jagged line): if r^k != tile_n an
-        # extra lower-radix step is required
-        k = round(math.log(tile_n, r))
-        out["mixed_radix"] = 0.0 if r ** k == tile_n else 1.0
-    elif wl.op == "attention":
-        head_dim = 128
-        out["flops"] = 4.0 * n * head_dim  # per q-row, per kv token: 2 matmuls
-        out["passes"] = 1
-        out["steps"] = max(n // cfg.get("block_k", 128), 1)
-    elif wl.op == "matmul":
-        out["flops"] = 2.0 * n * n  # per row of M
-        out["passes"] = 1
-        out["steps"] = max(n // cfg.get("block_k", 128), 1)
-    else:
-        out["flops"] = float(n)
-        out["passes"] = 1
-        out["steps"] = 1
-    out.setdefault("mixed_radix", 0.0)
-    return out
-
-
 def _knob(cfgs: Sequence[Config], name: str, default) -> np.ndarray:
     return np.array([c.get(name, default) for c in cfgs], dtype=np.float64)
 
@@ -374,7 +304,9 @@ class _KnobCols:
 
 
 def _mixed_radix_arr(tile: np.ndarray, r: np.ndarray) -> np.ndarray:
-    """Vectorized mixed() from _flops_and_passes: 1.0 when radix^k != tile."""
+    """Ragged final circuit level when radix^k != tile (1.0): an extra
+    low-radix step and sync (the paper's WM jagged-performance
+    observation)."""
     k = np.where(r > 1,
                  np.rint(np.log(np.maximum(tile, 2)) / np.log(np.maximum(r, 2))),
                  1.0)
@@ -383,12 +315,7 @@ def _mixed_radix_arr(tile: np.ndarray, r: np.ndarray) -> np.ndarray:
 
 def _batch_work(wl: Workload, cfgs: Sequence[Config],
                 cols: Optional[_KnobCols] = None) -> Dict[str, np.ndarray]:
-    """Vectorized `_flops_and_passes`: arrays over the candidate axis.
-
-    Element-for-element identical to the scalar model (same formulas, same
-    double-precision ops), so batched sweeps and per-config evaluation
-    produce the same times.
-    """
+    """Operation-specific work model: arrays over the candidate axis."""
     cols = cols or _KnobCols(cfgs)
     n = wl.n
     tile_n = cols.get("tile_n", n)
@@ -400,17 +327,19 @@ def _batch_work(wl: Workload, cfgs: Sequence[Config],
         log_r = np.log(np.maximum(r, 2))
         log_tile = np.log(np.maximum(tile_n, 2))
         steps = np.ceil(log_tile / log_r)
+        # Kogge-Stone does N work per step; Ladner-Fischer ~2N total but
+        # more steps of structure; model KS-like: n ops/step, radix-r node
+        # = r-1 adds
         out["flops"] = steps * n * (r - 1) / np.maximum(r / 2, 1)
-        base_passes = np.where(
-            tile_n < n,
-            np.ceil(np.log(max(n, 2)) / log_r / (log_tile / log_r)), 1.0)
-        fuse = cols.get("fuse", 0)
         if wl.op == "ssd":
-            out["passes"] = np.where(tile_n < n, 3.0 - fuse, 1.0)
-        elif wl.op == "rglru":
-            out["passes"] = base_passes + (1.0 - fuse)
+            # the intra-chunk launch, plus the recurrence-and-apply launch
+            # when there is more than one chunk
+            out["passes"] = np.where(tile_n < n, 2.0, 1.0)
         else:
-            out["passes"] = base_passes
+            out["passes"] = np.where(
+                tile_n < n,
+                np.ceil(np.log(max(n, 2)) / log_r / (log_tile / log_r)),
+                1.0)
         out["steps"] = steps
         out["mixed_radix"] = _mixed_radix_arr(tile_n, r)
     elif wl.op == "tridiag":
@@ -418,7 +347,7 @@ def _batch_work(wl: Workload, cfgs: Sequence[Config],
             steps = float(math.ceil(math.log2(max(n, 2)))) * ones
         else:
             steps = np.ceil(np.log(max(n, 2)) / np.log(np.maximum(r, 2)))
-        per_step = 14 if wl.variant == "pcr" else 9
+        per_step = 14 if wl.variant == "pcr" else 9   # PCR full-width; CR halves
         work_n = n if wl.variant == "pcr" else 2 * n
         out["flops"] = steps * work_n * per_step / np.maximum(np.log2(r), 1)
         out["passes"] = ones.copy()
@@ -428,6 +357,7 @@ def _batch_work(wl: Workload, cfgs: Sequence[Config],
     elif wl.op in ("fft", "large_fft"):
         log_r = np.log(np.maximum(r, 2))
         stages_total = np.log(max(n, 2)) / log_r
+        # radix-r Stockham: log_r(N) stages, canonical 5 N log2 N flops
         out["flops"] = 5.0 * n * math.log2(max(n, 2)) * ones
         s = np.log(np.maximum(tile_n, 2)) / log_r
         out["passes"] = np.maximum(1, np.ceil(stages_total / np.maximum(s, 1)))
@@ -436,11 +366,11 @@ def _batch_work(wl: Workload, cfgs: Sequence[Config],
         out["mixed_radix"] = np.where(np.power(r, k) == tile_n, 0.0, 1.0)
     elif wl.op == "attention":
         head_dim = 128
-        out["flops"] = 4.0 * n * head_dim * ones
+        out["flops"] = 4.0 * n * head_dim * ones   # per q row: 2 matmuls
         out["passes"] = ones.copy()
         out["steps"] = np.maximum(np.floor(n / cols.get("block_k", 128)), 1)
     elif wl.op == "matmul":
-        out["flops"] = 2.0 * n * n * ones
+        out["flops"] = 2.0 * n * n * ones   # per row of M
         out["passes"] = ones.copy()
         out["steps"] = np.maximum(np.floor(n / cols.get("block_k", 128)), 1)
     else:
@@ -506,71 +436,13 @@ class CostModelObjective(Objective):
     def __call__(self, space: SearchSpace, cfg: Config) -> Measurement:
         if not space.is_valid(cfg):
             return Measurement(PENALTY_TIME, False)
-        wl, spec = space.workload, self.spec
-        # tridiag: 4 coefficients per equation; fft: interleaved complex
-        eb = effective_element_bytes(wl.op, wl.dtype)
-
-        work = _flops_and_passes(wl, cfg)
-        batch = max(wl.batch, 1)
-        rows = cfg.get("rows_per_program", 1)
-        tile_n = cfg.get("tile_n", wl.n)
-
-        if wl.op == "attention":
-            block_q, block_k = cfg["block_q"], cfg["block_k"]
-            grid = max(batch, 1) * max(wl.n // block_q, 1)
-            block_bytes = (block_q + 2 * block_k) * 128 * eb
-            total_bytes = batch * wl.n * 128 * eb * 3
-            total_flops = batch * wl.n * work["flops"]
-            trailing = block_k
-        elif wl.op == "matmul":
-            bm, bn, bk = cfg["block_m"], cfg["block_n"], cfg["block_k"]
-            grid = max(batch // bm, 1) * max(wl.n // bn, 1)
-            block_bytes = (bm * bk + bk * bn) * eb
-            total_bytes = (batch * wl.n + wl.n * wl.n) * eb
-            total_flops = batch * work["flops"]
-            trailing = bn
-        else:
-            grid = max(batch // rows, 1) * max(wl.n // tile_n, 1)
-            block_bytes = rows * tile_n * eb
-            total_bytes = 2.0 * batch * wl.n * eb * work["passes"]
-            total_flops = batch * work["flops"]
-            trailing = min(tile_n, spec.lane_count * 8) if not cfg.get("in_register") else tile_n
-
-        # --- memory term ---
-        t_mem = total_bytes / (spec.hbm_bandwidth * dma_efficiency(int(block_bytes), spec))
-        # --- compute term (VPU for prefix ops; MXU for matmul/attention) ---
-        if wl.op in ("matmul", "attention"):
-            peak = spec.peak_bf16_flops if wl.dtype == "bfloat16" else spec.peak_f32_flops
-            mxu_util = min(trailing / spec.mxu_dim, 1.0)
-            t_comp = total_flops / (peak * max(mxu_util, 1e-3))
-        else:
-            util = lane_utilization(trailing, spec)
-            sub = sublane_utilization(rows * max(tile_n // spec.lane_count, 1), spec)
-            eff = max(util * max(sub, 0.25)
-                      * ilp_factor(cfg.get("unroll", 1), spec), 1e-3)
-            t_comp = total_flops / (spec.peak_vpu_flops * eff)
-            if cfg.get("in_register"):
-                t_comp *= 0.8   # no scratch roundtrip between steps
-            else:
-                t_comp *= 1.0 + 0.05 * work["steps"]  # scratch traffic per step
-
-        # --- overlap: need >=2 programs in flight (occupancy premise) ---
-        overlap = 1.0 if grid >= 4 else (0.85 if grid >= 2 else 0.55)
-        t_body = max(t_comp, t_mem) / overlap + (1.0 - overlap) * min(t_comp, t_mem) * 0.1
-        passes = work["passes"]
-        t = passes * (spec.kernel_launch_s + t_body / passes + work["steps"] / passes * _step_sync_s(wl, spec))
-        t *= 1.0 + 0.25 * work.get("mixed_radix", 0.0)
-        t *= self._jitter(wl, cfg)
-        # energy/memory axes, derived from the latency intermediates (the
-        # latency value above is already final — nothing below feeds back)
-        energy = (spec.idle_w * t + spec.peak_compute_w * t_comp
-                  + spec.hbm_pj_per_byte * 1e-12 * total_bytes)
-        peak_vmem = 2.0 * block_bytes   # double-buffered working set
+        col = {k: float(v[0]) for k, v in self._columns(space, [cfg]).items()}
         return Measurement(
-            t, True,
-            meta={"t_comp": t_comp, "t_mem": t_mem, "grid": grid,
-                  "passes": passes, "flops": total_flops, "bytes": total_bytes},
-            metrics={METRIC_ENERGY: energy, METRIC_PEAK_VMEM: peak_vmem},
+            col["t"], True,
+            meta={k: col[k] for k in ("t_comp", "t_mem", "grid", "passes",
+                                      "flops", "bytes")},
+            metrics={METRIC_ENERGY: col["energy"],
+                     METRIC_PEAK_VMEM: col["peak_vmem"]},
         )
 
     def signature(self) -> str:
@@ -587,21 +459,18 @@ class CostModelObjective(Objective):
         return self.batch_eval_metrics(space, cfgs,
                                        assume_valid=assume_valid)[METRIC_TIME]
 
-    def batch_eval_metrics(self, space: SearchSpace, cfgs: Sequence[Config],
-                           *, assume_valid: bool = False
-                           ) -> Dict[str, np.ndarray]:
-        """Vectorized fast path: the whole candidate set in array ops.
+    def _columns(self, space: SearchSpace, cfgs: Sequence[Config]
+                 ) -> Dict[str, np.ndarray]:
+        """The model over a candidate set in array ops: every intermediate
+        column (``t``, ``t_comp``, ``t_mem``, ``grid``, ``passes``,
+        ``flops``, ``bytes``, ``energy``, ``peak_vmem``), unclamped.
 
-        Mirrors ``__call__`` branch for branch; the only per-config Python
-        left is knob extraction (and the sha256 jitter when noise is on).
-        The time column is computed first and independently — the energy
-        and memory columns are derived afterwards, so the latency numbers
-        are bit-identical to the pre-vector implementation.
+        The only per-config Python is knob extraction (and the sha256
+        jitter when noise is on).  The time column is final before the
+        energy and memory columns are derived from it.
         """
-        if not len(cfgs):
-            return {n: np.empty(0, dtype=np.float64)
-                    for n in self.metric_names()}
         wl, spec = space.workload, self.spec
+        # tridiag: 4 coefficients per equation; fft: interleaved complex
         eb = effective_element_bytes(wl.op, wl.dtype)
         cols = _KnobCols(cfgs)
         work = _batch_work(wl, cfgs, cols)
@@ -654,9 +523,12 @@ class CostModelObjective(Objective):
                                  * ilp_factor_arr(cols.get("unroll", 1), spec),
                                  1e-3)
                 t_comp = total_flops / (spec.peak_vpu_flops * eff)
+                # in registers: no scratch roundtrip between steps; else
+                # scratch traffic per step
                 t_comp = np.where(in_reg, t_comp * 0.8,
                                   t_comp * (1.0 + 0.05 * work["steps"]))
 
+            # overlap needs >= 2 programs in flight (occupancy premise)
             overlap = np.where(grid >= 4, 1.0, np.where(grid >= 2, 0.85, 0.55))
             t_body = np.maximum(t_comp, t_mem) / overlap \
                 + (1.0 - overlap) * np.minimum(t_comp, t_mem) * 0.1
@@ -667,13 +539,24 @@ class CostModelObjective(Objective):
             t = t * (1.0 + 0.25 * work["mixed_radix"])
             if self.noise:
                 t = t * np.array([self._jitter(wl, c) for c in cfgs])
-            # derived metric columns — same expressions as the scalar path,
-            # computed after (and never feeding into) the time column
+            # derived metric columns, computed after (and never feeding
+            # into) the time column
             energy = (spec.idle_w * t + spec.peak_compute_w * t_comp
                       + spec.hbm_pj_per_byte * 1e-12 * total_bytes)
             peak_vmem = 2.0 * block_bytes * np.ones_like(t)
+        return {"t": t, "t_comp": t_comp, "t_mem": t_mem, "grid": grid,
+                "passes": passes, "flops": total_flops, "bytes": total_bytes,
+                "energy": energy, "peak_vmem": peak_vmem}
 
-        t = np.nan_to_num(t, nan=PENALTY_TIME, posinf=PENALTY_TIME,
+    def batch_eval_metrics(self, space: SearchSpace, cfgs: Sequence[Config],
+                           *, assume_valid: bool = False
+                           ) -> Dict[str, np.ndarray]:
+        """The whole candidate set in array ops, penalty-clamped."""
+        if not len(cfgs):
+            return {n: np.empty(0, dtype=np.float64)
+                    for n in self.metric_names()}
+        col = self._columns(space, cfgs)
+        t = np.nan_to_num(col["t"], nan=PENALTY_TIME, posinf=PENALTY_TIME,
                           neginf=PENALTY_TIME)
         if not assume_valid:
             valid = np.fromiter((space.is_valid(c) for c in cfgs),
@@ -683,10 +566,11 @@ class CostModelObjective(Objective):
         # protocol's convention); such rows lose on every metric axis
         pen_e, pen_v = metric_penalty(METRIC_ENERGY), metric_penalty(METRIC_PEAK_VMEM)
         bad = t == PENALTY_TIME
-        energy = np.nan_to_num(energy, nan=pen_e, posinf=pen_e, neginf=pen_e)
+        energy = np.nan_to_num(col["energy"], nan=pen_e, posinf=pen_e,
+                               neginf=pen_e)
         return {METRIC_TIME: t,
                 METRIC_ENERGY: np.where(bad, pen_e, energy),
-                METRIC_PEAK_VMEM: np.where(bad, pen_v, peak_vmem)}
+                METRIC_PEAK_VMEM: np.where(bad, pen_v, col["peak_vmem"])}
 
 
 # Backwards-compatible name: the objective predates the profile layer and

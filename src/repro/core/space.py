@@ -239,14 +239,6 @@ def scan_space(wl: Workload,
         ParamSpec("unroll", unroll_dom),        # node-ops per VPU step
         ParamSpec("in_register", (0, 1)),
     ]
-    if wl.op in ("ssd", "rglru"):
-        # chain-fusion boundary knob: 1 folds the op's neighbouring chain
-        # links into a shared launch (rglru's gate into the scan kernel's
-        # first stage, SSD's phase B + apply into one sequential launch),
-        # 0 breaks at the historical kernel boundaries — each break is a
-        # full HBM roundtrip the analytical model charges as a pass.
-        # Plain scans have no chain, so the knob would be dead there.
-        params.append(ParamSpec("fuse", (0, 1)))
     return SearchSpace(
         wl,
         params,

@@ -2,7 +2,7 @@
 
 Generalizes the FFT four-step decomposition (paper §IV-C) into a driver
 any prefix-family kernel can use, so large-N scan — and through the scan,
-tridiag substitution sweeps, SSD phase-B and RG-LRU — also get the
+tridiag substitution sweeps and RG-LRU — also get the
 m-kernel multi-pass path instead of only FFT:
 
   * ``four_step_fft``     — N = n1*n2 column/row decomposition, recursing
@@ -11,8 +11,8 @@ m-kernel multi-pass path instead of only FFT:
                             block-scan decomposition (chunk scan, carry
                             scan over chunk transfer operators, apply);
   * ``linrec_rows``       — the tuned linear-recurrence building block as
-                            a library call for composite kernels (SSD
-                            phase-B, tridiag LF sweeps), with the XLA
+                            a library call for composite kernels (the
+                            tridiag LF sweeps), with the XLA
                             reference as fallback where the radix spaces
                             have no valid config (odd lengths).
 
@@ -219,7 +219,7 @@ def multipass_linrec(a: jax.Array, b: jax.Array, plan: StagePlan, *,
     """h_t = a_t h_{t-1} + b_t as three kernels: per-chunk linrec (+ the
     chunk transfer operators), carry linrec over operators, apply.
 
-    ``gate=True`` is the fused rglru chain: ``b`` carries the raw input u
+    ``gate=True`` is the rglru path: ``b`` carries the raw input u
     and the chunk kernel applies the RG-LRU gate in-tile (the carry and
     apply launches operate on transfer operators, untouched by the gate).
     ``name`` names the launches as in ``multipass_scan_add``.
@@ -265,15 +265,13 @@ def _linrec_space_valid(n: int) -> bool:
 
 
 def linrec_rows(a: jax.Array, b: jax.Array, *, use_pallas: bool,
-                interpret: bool, config: Optional[dict] = None,
-                name: Optional[str] = None) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """Tuned linear recurrence over (rows, n) — the shared carry-chain
-    block composite kernels (SSD phase-B, tridiag LF sweeps) call.
+    block composite kernels (the tridiag LF sweeps) call.
 
     Resolves the (op="scan", variant="linrec") workload through the
     session, builds its StagePlan, and dispatches fused or multi-pass
-    exactly like the public ``linear_recurrence`` entry point.  ``name``
-    names the launches (multi-pass: with stage suffixes).
+    exactly like the public ``linear_recurrence`` entry point.
     """
     from repro.kernels.scan.ref import scan_linrec_assoc_ref
     rows, n = a.shape
@@ -285,10 +283,9 @@ def linrec_rows(a: jax.Array, b: jax.Array, *, use_pallas: bool,
     from repro.kernels.blocks.plan import plan_for
     from repro.tuning import default_session
     wl = Workload(op="scan", n=n, batch=rows, variant="linrec")
-    cfg = default_session().resolve(wl, config=config)
-    plan = plan_for(wl, cfg)
+    plan = plan_for(wl, default_session().resolve(wl))
     if plan.kind == "multipass":
-        return multipass_linrec(a, b, plan, interpret=interpret, name=name)
+        return multipass_linrec(a, b, plan, interpret=interpret)
     return launch(scan_linrec_pallas, plan.launches[0], a, b,
                   rows_per_program=plan.rows, tile_n=plan.tile_n,
-                  stages=plan.stages, interpret=interpret, name=name)
+                  stages=plan.stages, interpret=interpret)

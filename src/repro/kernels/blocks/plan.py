@@ -23,16 +23,11 @@ fields instead of re-deriving pass counts from knobs, and
 ``tuning.ml.features`` featurizes the same fields — so model and kernel
 cannot silently disagree (tests/test_blocks_plan.py pins the agreement).
 
-Composite ops (rglru's gate→linrec, SSD's intra→linrec→apply) are
-*chains* of links: the ``fuse`` knob decides whether neighbouring links
-share a launch (gate folded into the scan kernel's first stage, SSD
-phase B + apply collapsed into one sequential-grid launch) or break at
-the historical boundaries, each break costing a full HBM roundtrip.
-``plan_for_chain`` exposes the per-link view (and, given the runtime
-state dims a ``Workload`` cannot carry, the *exact* embedded launches);
-``plan_for`` already folds the chain's pass accounting into the regular
-``StagePlan``, so the analytical model and the featurizer price fusion
-with no extra plumbing.
+Composite ops run one chain each: rglru's gate is the scan kernel's
+first stage, and the SSD is an intra-chunk launch followed, when there is
+more than one chunk, by one sequential launch for the inter-chunk
+recurrence and its apply.  Their ``launches`` and ``passes`` are the
+whole contract: a ``capture_launches`` trace equals ``plan.launches``.
 
 Deliberately pure Python (no jax import): the analytical tuner and the
 numpy-only ML stack consume plans without pulling in the kernel runtime.
@@ -252,10 +247,9 @@ class StagePlan:
     n: int
     batch: int
     dtype: str
-    kind: str                       # "fused" | "multipass" | "three-phase"
-    #                                 (ssd unfused) | "two-phase" (ssd
-    #                                 fused) | "xla"; dispatchers branch
-    #                                 on == "multipass" only
+    kind: str                       # "fused" | "multipass" | "two-phase"
+    #                                 (ssd over several chunks) | "xla";
+    #                                 dispatchers branch on == "multipass"
     tile_n: int                     # elements resident per program
     rows: int                       # problem rows per program
     radix: int                      # nominal (tuned) fan-in
@@ -263,9 +257,9 @@ class StagePlan:
     seq_tiles: int                  # sequential carry tiles per program
     grid: Tuple[int, ...]           # main-launch grid
     launches: Tuple[Launch, ...]    # every kernel launch, driver order
-    passes: int                     # HBM roundtrips == len(launches) +
-    #                                 xla_passes when pallas-backed; 1 for
-    #                                 fused XLA variants
+    passes: int                     # HBM roundtrips == len(launches)
+    #                                 when pallas-backed; 1 for fused XLA
+    #                                 variants
     vmem_bytes: int                 # peak launch VMEM (Launch.vmem_bytes)
     block_bytes: int                # DMA block (analytical rank input)
     element_bytes: int              # effective bytes per logical element
@@ -276,16 +270,12 @@ class StagePlan:
     ilp: float
     ragged: bool                    # mixed-radix tail (last stage < radix)
     steps_per_pass: float
-    # HBM passes performed by XLA-level chain links that are not pallas
-    # launches (e.g. rglru's unfused elementwise gate): they cost a full
-    # read+write roundtrip but never appear in ``launches``
-    xla_passes: int = 0
     children: Tuple["StagePlan", ...] = ()
     # (in-vreg, cross-vreg) lane-shifted folds per element when the stage
     # loop is a shift_fold / linrec_level circuit (``shift_fold_counts``);
     # None for butterfly, PCR and stage-less plans
     shift_folds: Optional[Tuple[int, int]] = None
-    # modelled seconds per element of an SSD chain (``_ssd_chunk_cost``):
+    # modelled seconds per element of an SSD plan (``_ssd_chunk_cost``):
     # (intra-chunk matrix-unit work, the chunk's own costs spread over its
     # elements); None for every other op
     chunk_cost: Optional[Tuple[float, float]] = None
@@ -331,13 +321,9 @@ class StagePlan:
                            f"{self.tile_n} (stages={self.stages})")
         if any(g < 1 for g in self.grid):
             out.append(f"non-positive grid dim: {self.grid}")
-        if self.xla_passes < 0:
-            out.append(f"negative xla_passes: {self.xla_passes}")
-        if self.launches \
-                and self.passes != len(self.launches) + self.xla_passes:
+        if self.launches and self.passes != len(self.launches):
             out.append(f"passes={self.passes} disagrees with "
-                       f"{len(self.launches)} launches "
-                       f"+ {self.xla_passes} xla passes")
+                       f"{len(self.launches)} launches")
         for launch in self.launches:
             if any(g < 1 for g in launch.grid) \
                     or any(b < 1 for b in launch.block_shape):
@@ -410,11 +396,6 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
     unroll = int(cfg.get("unroll", 1))
     stages = stage_radices(tile_n, radix)
     seq_tiles = max(wl.n // max(tile_n, 1), 1)
-    # rglru is a gate→linrec chain: fused, the elementwise gate runs inside
-    # the scan kernel's first stage (same launches, one fewer HBM pass);
-    # unfused, the XLA gate materializes b = sqrt(1-a^2)*u through HBM —
-    # one extra pass that never shows up as a pallas launch
-    gate_xla = 1 if wl.op == "rglru" and not int(cfg.get("fuse", 0)) else 0
     linrec = _is_linrec(wl)
     planes = 3 if linrec else 2                  # (a, b) in + h out vs in + out
     trailing, lane, sub, occ = _occ(tile_n, rows, spec)
@@ -449,8 +430,7 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
             op=wl.op, variant=wl.variant, n=wl.n, batch=batch, dtype=wl.dtype,
             kind="multipass", tile_n=tile_n, rows=rows, radix=radix,
             stages=stages, seq_tiles=seq_tiles, grid=l1.grid,
-            launches=launches, passes=len(launches) + gate_xla,
-            xla_passes=gate_xla,
+            launches=launches, passes=len(launches),
             vmem_bytes=max(l.vmem_bytes for l in launches),
             block_bytes=rows * tile_n * eb, element_bytes=eb,
             trailing=trailing, lane_eff=lane, sublane_eff=sub, occupancy=occ,
@@ -465,8 +445,7 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         op=wl.op, variant=wl.variant, n=wl.n, batch=batch, dtype=wl.dtype,
         kind="fused", tile_n=tile_n, rows=rows, radix=radix, stages=stages,
         seq_tiles=seq_tiles, grid=grid, launches=(launch,),
-        passes=1 + gate_xla, xla_passes=gate_xla,
-        vmem_bytes=launch.vmem_bytes,
+        passes=1, vmem_bytes=launch.vmem_bytes,
         block_bytes=rows * tile_n * eb, element_bytes=eb, trailing=trailing,
         lane_eff=lane, sublane_eff=sub, occupancy=occ,
         ilp=unroll * (2 if cfg.get("in_register") else 1), ragged=ragged,
@@ -475,16 +454,17 @@ def _prefix_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
 
 def _ssd_chunk_cost(chunk: int, chunk_launches: int, spec: HardwareProfile
                    ) -> Tuple[float, float]:
-    """Modelled seconds per element of the SSD chain at chunk length Q:
+    """Modelled seconds per element of the SSD at chunk length Q:
     (intra-chunk matrix-unit work, the chunk's own costs ÷ Q).
 
-    Phase A's three f32 contractions a chunk, C·Bᵀ (Q×S×Q), scores·x
-    (Q×Q×P) and the chunk state (S×Q×P), run as ``_SSD_MXU_PASSES`` bf16
-    passes, each width rounded up to the unit's edge: 2·(Q·S + Q·P + S·P)
-    flops an element, the first two growing with Q.  Each chunk adds its
-    (S, P) f32 state, written by phase A and read by the carry, and one
-    grid step of each of the ``chunk_launches`` launches that walk the
-    chunks.  S and P are the model width ``_MODEL_MINOR``.
+    The intra launch's three f32 contractions a chunk, C·Bᵀ (Q×S×Q),
+    scores·x (Q×Q×P) and the chunk state (S×Q×P), run as
+    ``_SSD_MXU_PASSES`` bf16 passes, each width rounded up to the unit's
+    edge: 2·(Q·S + Q·P + S·P) flops an element, the first two growing with
+    Q.  Each chunk adds its (S, P) f32 state, written by the intra launch
+    and read by the carry, and one grid step of each of the
+    ``chunk_launches`` launches that walk the chunks.  S and P are the
+    model width ``_MODEL_MINOR``.
     """
     q, w = _round_up(chunk, spec.mxu_dim), _round_up(_MODEL_MINOR,
                                                      spec.mxu_dim)
@@ -496,22 +476,12 @@ def _ssd_chunk_cost(chunk: int, chunk_launches: int, spec: HardwareProfile
 
 def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
               seq_limit: int) -> StagePlan:
-    """SSD chain: intra-chunk kernel → linrec over chunk transitions →
-    apply.  Unfused, phase B is a child prefix plan on the shared blocks
-    and the chain runs as three launches with HBM roundtrips between;
-    ``fuse=1`` collapses phase B + apply into one sequential-grid launch
-    whose VMEM carry holds the running (S, P) entry state — the chunk
-    states feed the recurrence without ever leaving the core (two-phase).
-
-    Model-level plan: the phase count and chunk staging are exact, but the
-    state dims (S, P) are runtime shapes a ``Workload`` does not carry, so
-    the unfused phase-B child models the nc-length transition scan per
-    (batch) row, not the S*P row fan-out ``driver.linrec_rows`` resolves
-    at launch.  ``plan_for_chain(wl, cfg, dims=(S, P))`` rebuilds the
-    exact embedded launches for the conformance suite.  Only phase B runs
-    a shift-fold circuit, so only the unfused plan reports fold counts
-    (its child's).  Every SSD plan reports ``_ssd_chunk_cost``: phase A
-    and the apply (fused or not) each walk the chunks."""
+    """SSD: the intra-chunk launch, then — over several chunks — one
+    sequential-grid launch whose VMEM carry holds the running (S, P) entry
+    state, so the chunk states feed the recurrence without leaving the
+    core and the apply shares its launch (two-phase).  A single chunk
+    needs the intra launch alone.  Neither runs a shift-fold circuit;
+    both report ``_ssd_chunk_cost``."""
     base = _prefix_plan(wl, cfg, spec, seq_limit)
     chunk = base.tile_n
     nc = max(wl.n // max(chunk, 1), 1)
@@ -522,32 +492,17 @@ def _ssd_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
                   + _SSD_QQ_TEMPS * vmem_tile_bytes(chunk, chunk, 4, spec))
     intra = Launch("ssd-intra", (base.batch, nc), (1, chunk), (), chunk_vmem)
     if nc <= 1:
-        # single chunk: intra kernel alone already yields the answer
         return dataclasses.replace(base, kind="fused", seq_tiles=1,
                                    launches=(intra,), vmem_bytes=chunk_vmem,
                                    shift_folds=None,
                                    chunk_cost=_ssd_chunk_cost(chunk, 1, spec))
-    cost = _ssd_chunk_cost(chunk, 2, spec)
-    if int(cfg.get("fuse", 0)):
-        state_apply = Launch("ssd-state-apply", (base.batch, nc),
-                             (1, chunk), (), chunk_vmem)
-        launches = (intra, state_apply)
-        return dataclasses.replace(
-            base, kind="two-phase", seq_tiles=nc, launches=launches,
-            passes=len(launches), vmem_bytes=chunk_vmem, children=(),
-            shift_folds=None, chunk_cost=cost)
-    child = _prefix_plan(
-        Workload(op="scan", n=nc, batch=base.batch, dtype=wl.dtype,
-                 variant="linrec"),
-        {"tile_n": nc, "rows_per_program": 1,
-         "radix": cfg.get("radix", 2)}, spec, seq_limit)
-    apply_ = Launch("ssd-apply", (base.batch, nc), (1, chunk), (),
-                    chunk_vmem)
-    launches = (intra,) + child.launches + (apply_,)
+    state_apply = Launch("ssd-state-apply", (base.batch, nc), (1, chunk), (),
+                         chunk_vmem)
+    launches = (intra, state_apply)
     return dataclasses.replace(
-        base, kind="three-phase", seq_tiles=nc, launches=launches,
-        passes=len(launches), vmem_bytes=max(l.vmem_bytes for l in launches),
-        children=(child,), shift_folds=child.shift_folds, chunk_cost=cost)
+        base, kind="two-phase", seq_tiles=nc, launches=launches,
+        passes=len(launches), vmem_bytes=chunk_vmem, shift_folds=None,
+        chunk_cost=_ssd_chunk_cost(chunk, 2, spec))
 
 
 def _tridiag_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
@@ -756,165 +711,6 @@ def build_plan(wl: Workload, cfg: Mapping[str, int], *,
     # unknown op: a degenerate single-launch plan keeps generic consumers
     # (featurizer, analytical tiering) total rather than raising
     return _prefix_plan(wl, cfg, spec, seq_limit)
-
-
-# ---------------------------------------------------------------------------
-# Chain planning: sequences of ops as one staged execution
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ChainLink:
-    """One op of a chain and what executing it costs.
-
-    ``kind`` records where the link's work happens: ``"pallas"`` links own
-    the launches in ``launches``; ``"xla"`` links run as XLA ops costing
-    ``passes`` HBM roundtrips with no pallas launch; ``"fused"`` links are
-    folded into a neighbouring link's launch (zero launches, zero passes
-    of their own — the whole point of the ``fuse`` knob).
-    """
-
-    name: str                       # link tag ("gate", "linrec", "intra"...)
-    kind: str                       # "pallas" | "xla" | "fused"
-    launches: Tuple[Launch, ...]    # launches this link issues itself
-    passes: int                     # HBM roundtrips this link costs
-    plan: Optional[StagePlan] = None   # the link's own plan when it has one
-
-
-@dataclasses.dataclass(frozen=True)
-class ChainPlan:
-    """A sequence of ops planned as one staged execution.
-
-    ``plan`` is the flattened :class:`StagePlan` (what ``resources()``,
-    the analytical model and the featurizer consume — built by
-    ``plan_for`` with the same config); ``links`` is the per-op view the
-    drivers dispatch from.  ``launches`` concatenates the links' launch
-    lists in driver order — the conformance contract is that a
-    ``capture_launches`` trace of the chain's execution equals it.
-    """
-
-    op: str
-    links: Tuple[ChainLink, ...]
-    plan: StagePlan
-
-    @property
-    def launches(self) -> Tuple[Launch, ...]:
-        return tuple(l for link in self.links for l in link.launches)
-
-    @property
-    def passes(self) -> int:
-        return sum(link.passes for link in self.links)
-
-    def check(self, spec: HardwareProfile) -> List[str]:
-        """Chain-level violations on top of the flattened plan's own."""
-        out = self.plan.check(spec)
-        if self.passes != self.plan.passes:
-            out.append(f"chain passes {self.passes} disagree with the "
-                       f"flattened plan's {self.plan.passes}")
-        for link in self.links:
-            if link.kind == "fused" and (link.launches or link.passes):
-                out.append(f"link {link.name}: fused links own no launches "
-                           f"or passes")
-            if link.kind == "pallas" and link.passes != len(link.launches):
-                out.append(f"link {link.name}: {link.passes} passes vs "
-                           f"{len(link.launches)} launches")
-        return out
-
-
-def _rglru_chain(wl: Workload, cfg: Mapping[str, int], plan: StagePlan
-                 ) -> ChainPlan:
-    fused = bool(int(cfg.get("fuse", 0)))
-    gate = ChainLink("gate", "fused" if fused else "xla", (), 0 if fused
-                     else 1)
-    linrec = ChainLink("linrec", "pallas", plan.launches,
-                       len(plan.launches), plan=plan)
-    return ChainPlan(op=wl.op, links=(gate, linrec), plan=plan)
-
-
-def _ssd_chain(wl: Workload, cfg: Mapping[str, int], plan: StagePlan,
-               spec: HardwareProfile, seq_limit: int,
-               dims: Optional[Tuple[int, int]]) -> ChainPlan:
-    if plan.kind == "fused":            # nc <= 1: intra kernel alone
-        intra = ChainLink("intra", "pallas", plan.launches,
-                          len(plan.launches), plan=plan)
-        return ChainPlan(op=wl.op, links=(intra,), plan=plan)
-    nc = plan.seq_tiles
-    intra = ChainLink("intra", "pallas", plan.launches[:1], 1)
-    if plan.kind == "two-phase":
-        # phase B + apply share the sequential state-apply launch: the
-        # linrec link's carry lives in that launch's VMEM scratch
-        linrec = ChainLink("linrec", "fused", (), 0)
-        apply_ = ChainLink("apply", "pallas", plan.launches[1:], 1)
-        return ChainPlan(op=wl.op, links=(intra, linrec, apply_), plan=plan)
-    # unfused: phase B is the embedded linrec block.  With the runtime
-    # state dims the embedded plan is exact — the (S, P) fan-out
-    # ``driver.linrec_rows`` resolves at launch; without them, fall back
-    # to the flattened plan's model-level child.
-    if dims is not None and _linrec_space_valid_model(nc):
-        s, p = dims
-        embed_batch = plan.batch * s * p
-        embed_wl = Workload(op="scan", n=nc, batch=embed_batch,
-                            dtype="float32", variant="linrec")
-        # mirror the scan normalizer's defaults for the threaded config
-        # ({"tile_n": nc, "radix": cfg radix}): rows fit from the default 8
-        embed_cfg = {"tile_n": nc,
-                     "rows_per_program": fit_block(8, embed_batch),
-                     "radix": int(cfg.get("radix", 2))}
-        child = build_plan(embed_wl, embed_cfg, profile=spec,
-                           seq_limit=seq_limit)
-        linrec = ChainLink("linrec", "pallas", child.launches,
-                           len(child.launches), plan=child)
-    elif dims is not None:
-        # odd nc: the embedded block falls back to the XLA reference
-        linrec = ChainLink("linrec", "xla", (), 1)
-    else:
-        child = plan.children[0] if plan.children else None
-        launches = child.launches if child is not None else ()
-        linrec = ChainLink("linrec", "pallas", launches, len(launches),
-                           plan=child)
-    apply_ = ChainLink("apply", "pallas", plan.launches[-1:], 1)
-    chain_plan = plan
-    if dims is not None:
-        # re-flatten around the exact embedded launches so chain-level
-        # pass accounting stays consistent (launch count can only match)
-        launches = plan.launches[:1] + linrec.launches + plan.launches[-1:]
-        chain_plan = dataclasses.replace(
-            plan, launches=launches, passes=len(launches) + plan.xla_passes
-            + (1 if linrec.kind == "xla" else 0),
-            xla_passes=plan.xla_passes + (1 if linrec.kind == "xla" else 0),
-            children=(linrec.plan,) if linrec.plan is not None else ())
-    return ChainPlan(op=wl.op, links=(intra, linrec, apply_),
-                     plan=chain_plan)
-
-
-def _linrec_space_valid_model(n: int) -> bool:
-    """Planner-side mirror of ``driver._linrec_space_valid`` (kept here so
-    the pure-Python planner never imports the jax-backed driver)."""
-    return n >= 2 and n % 2 == 0
-
-
-def plan_for_chain(wl: Workload, cfg: Mapping[str, int], *,
-                   dims: Optional[Tuple[int, int]] = None,
-                   profile: Optional[HardwareProfile] = None,
-                   seq_limit: int = DEFAULT_SEQ_LIMIT) -> ChainPlan:
-    """Plan ``wl``'s op — a chain for composite ops — as one staged
-    execution.
-
-    For ``rglru`` the chain is gate→linrec; for ``ssd`` it is
-    intra→linrec→apply, and passing the runtime state dims ``dims=(S, P)``
-    makes the embedded phase-B launches exact (a ``capture_launches``
-    trace of the executed chain equals ``chain.launches``).  Every other
-    op is a single-link chain around its regular ``plan_for`` plan.
-    """
-    wl = wl.canonical()
-    spec = _resolve_profile(profile, None)
-    plan = plan_for(wl, cfg, profile=spec, seq_limit=seq_limit)
-    if wl.op == "rglru":
-        return _rglru_chain(wl, cfg, plan)
-    if wl.op == "ssd":
-        return _ssd_chain(wl, cfg, plan, spec, seq_limit, dims)
-    link = ChainLink(wl.op or "op", "pallas" if plan.launches else "xla",
-                     plan.launches, plan.passes, plan=plan)
-    return ChainPlan(op=wl.op, links=(link,), plan=plan)
 
 
 @functools.lru_cache(maxsize=65536)

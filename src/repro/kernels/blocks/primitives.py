@@ -229,15 +229,14 @@ def carry_fold_linrec(aa: jax.Array, bb: jax.Array, carry_ref) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Chain-fusion links (elementwise ops folded into a stage loop's prologue)
+# Elementwise ops folded into a stage loop's prologue
 # ---------------------------------------------------------------------------
 
 def rglru_gate(aa: jax.Array, uu: jax.Array) -> jax.Array:
     """RG-LRU input gate b = sqrt(max(1 - a^2, 0)) * u, in-tile.
 
-    The fused rglru chain runs this as the scan kernel's first stage
-    (``gate=True``) instead of a separate XLA pass — the ``fuse=1`` arm of
-    the chain planner, saving one full HBM roundtrip over the rows.
+    The rglru op runs this as the scan kernel's first stage
+    (``gate=True``) instead of a separate XLA pass over the rows.
     """
     return jnp.sqrt(jnp.maximum(1.0 - aa * aa, 0.0)) * uu
 

@@ -9,11 +9,9 @@ space), builds its StagePlan, and dispatches fused or multi-pass through
 the shared blocks driver, so per-op DB entries and ``overrides(rglru=...)``
 apply.
 
-rglru is a gate→linrec *chain*: the tuned ``fuse`` knob decides whether
-the elementwise gate runs inside the scan kernel's first stage
-(``fuse=1`` — one launch, one fewer HBM roundtrip; the plan's
-``xla_passes`` drops to 0) or as a separate XLA pass at the historical
-op boundary (``fuse=0``).
+The Pallas path hands the scan kernel the raw input u: the elementwise
+gate sqrt(1-a^2) * u runs inside the kernel's first stage (``gate=True``),
+so the gated operand never makes an HBM roundtrip of its own.
 """
 from __future__ import annotations
 
@@ -49,21 +47,14 @@ def rglru(a: jax.Array, u: jax.Array, config: Optional[dict] = None,
     wl = Workload(op="rglru", n=L, batch=B * D)
     cfg = default_session().resolve(wl, config=config)
     plan = plan_for(wl, cfg)
-    fused = bool(cfg.get("fuse", 0))
     a_rows = jnp.transpose(a, (0, 2, 1)).reshape(B * D, L)
-    if fused:
-        # fused chain: the second operand is the raw input u; the kernel
-        # computes the gate in-tile (gate=True), saving the XLA gate pass
-        b_rows = jnp.transpose(u, (0, 2, 1)).reshape(B * D, L)
-    else:
-        b = jnp.sqrt(jnp.maximum(1.0 - a * a, 0.0)) * u
-        b_rows = jnp.transpose(b, (0, 2, 1)).reshape(B * D, L)
+    u_rows = jnp.transpose(u, (0, 2, 1)).reshape(B * D, L)
     if plan.kind == "multipass":
-        h = driver.multipass_linrec(a_rows, b_rows, plan, gate=fused,
+        h = driver.multipass_linrec(a_rows, u_rows, plan, gate=True,
                                     interpret=interpret_eff)
     else:
         h = driver.launch(scan_linrec_pallas, plan.launches[0],
-                          a_rows, b_rows, rows_per_program=plan.rows,
+                          a_rows, u_rows, rows_per_program=plan.rows,
                           tile_n=plan.tile_n, stages=plan.stages,
-                          gate=fused, interpret=interpret_eff)
+                          gate=True, interpret=interpret_eff)
     return jnp.transpose(h.reshape(B, D, L), (0, 2, 1))

@@ -52,7 +52,7 @@ def _scan_linrec_kernel(a_ref, b_ref, h_ref, carry_ref, *,
     aa = a_ref[...].astype(jnp.float32)
     bb = b_ref[...].astype(jnp.float32)
     if gate:
-        # fused rglru chain: b_ref holds u; the elementwise gate runs as
+        # rglru: b_ref holds u; the elementwise gate runs as
         # the stage loop's prologue instead of a separate XLA HBM pass
         bb = prim.rglru_gate(aa, bb)
     for fan_in, stride in zip(stages, stage_strides(stages)):
@@ -134,9 +134,9 @@ def scan_linrec_pallas(a: jax.Array, b: jax.Array, *, rows_per_program: int = 8,
                        name: Optional[str] = None) -> jax.Array:
     """h_t = a_t * h_{t-1} + b_t along the last axis of (batch, n) pairs.
 
-    ``gate=True`` is the fused rglru chain link: ``b`` carries the raw
-    input ``u`` and the kernel applies the RG-LRU gate in-tile before the
-    stage loop (one launch for the whole gate→linrec chain).  ``name``
+    ``gate=True`` is the rglru path: ``b`` carries the raw input ``u``
+    and the kernel applies the RG-LRU gate in-tile before the stage loop
+    (one launch for the gate and the recurrence).  ``name``
     names the launch as in ``scan_add_pallas``.
     """
     del unroll  # fold order fixed by composition order for linrec

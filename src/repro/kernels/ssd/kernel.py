@@ -1,29 +1,22 @@
 """Pallas TPU kernels for the Mamba-2 SSD chunked algorithm.
 
-Three-phase parallel form (Dao & Gu 2024, adapted to TPU tiling):
-  phase A (kernel): per (batch*head, chunk) block, compute the intra-chunk
-    output via the quadratic dual form — Q x Q attention-like matmuls that
-    map straight onto the MXU — plus the chunk's state-space transition
-    (a_chunk scalar, (S, P) state injection);
-  phase B (tuned scan): linear-recurrence scan over chunk transitions
-    (reuses the paper-tuned scan kernel / monoid);
-  phase C (kernel): broadcast scanned entry states back into each chunk.
+Two launches (Dao & Gu 2024, adapted to TPU tiling):
+  intra (``ssd_intra_pallas``): per (batch*head, chunk) block, the
+    intra-chunk output via the quadratic dual form — Q x Q attention-like
+    matmuls that map straight onto the MXU — plus the chunk's (S, P) state
+    injection;
+  carry (``ssd_state_apply_pallas``): the inter-chunk recurrence and its
+    apply in one launch whose chunk axis is sequential and whose (S, P)
+    VMEM carry *is* the recurrence state — the intra launch's chunk states
+    feed it without an HBM roundtrip of the scanned entry states.
 
-The chain planner's ``fuse=1`` arm collapses phases B + C into
-``ssd_state_apply_pallas``: one launch whose chunk axis is sequential and
-whose (S, P) VMEM carry *is* the inter-chunk recurrence state — phase A's
-chunk states feed phase B without the HBM roundtrip, and the apply is
-folded into the same launch.
-
-Tunables: chunk length Q (the VMEM tile; tile_n in the tuning space),
-rows via the grid, and the chain-fusion boundary (``fuse``). Q is
-hardware-aligned to the 128-lane MXU edge.
+Tunables: chunk length Q (the VMEM tile; tile_n in the tuning space) and
+rows via the grid. Q is hardware-aligned to the 128-lane MXU edge.
 
 Each launch carries a name, which becomes its HLO instruction name on a
-device trace: ``ssd_chunk`` (phase A), ``ssd_carry`` (phase B: the fused
-B + C launch, or unfused the embedded linear-recurrence scan) and
-``ssd_apply`` (unfused phase C) — the stage names of the multi-pass scan
-driver, so the three read as one kernel family ``ssd``.
+device trace: ``ssd_chunk`` (intra) and ``ssd_carry`` (recurrence +
+apply) — the stage names of the multi-pass scan driver, so the two read
+as one kernel family ``ssd``.
 """
 from __future__ import annotations
 
@@ -85,17 +78,6 @@ def _intra_kernel(x_ref, lac_ref, lar_ref, b_ref, c_ref, y_ref, st_ref):
     st_ref[0, 0] = state.astype(st_ref.dtype)
 
 
-def _inter_kernel(y_ref, lac_ref, c_ref, ent_ref, o_ref):
-    y = y_ref[0].astype(jnp.float32)      # (Q, P)
-    la_col = lac_ref[0]                   # (Q, 1)
-    c = c_ref[0].astype(jnp.float32)      # (Q, S)
-    ent = ent_ref[0, 0].astype(jnp.float32)  # (S, P)
-    y_in = jax.lax.dot_general(c, ent, (((1,), (0,)), ((), ())),
-                               precision=_EXACT,
-                               preferred_element_type=jnp.float32)  # (Q, P)
-    o_ref[0] = (y + y_in * jnp.exp(la_col)).astype(o_ref.dtype)
-
-
 def _vector_specs(chunk: int):
     """Column and row BlockSpecs of the (Q,)-vector decay inputs."""
     return (pl.BlockSpec((1, chunk, 1), lambda i, j: (i, j, 0)),
@@ -140,7 +122,7 @@ def ssd_intra_pallas(x, la, b, c, *, chunk: int = 128,
 
 def _state_apply_kernel(y_ref, lac_ref, c_ref, ac_ref, st_ref, o_ref,
                         carry_ref):
-    """Fused phases B + C: the (S, P) VMEM carry is the recurrence state.
+    """Recurrence + apply: the (S, P) VMEM carry is the recurrence state.
 
     The chunk axis is the grid's sequential dimension, so the carry
     entering program (i, j) is exactly h_{j-1} = the scanned entry state
@@ -166,12 +148,11 @@ def _state_apply_kernel(y_ref, lac_ref, c_ref, ac_ref, st_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
 def ssd_state_apply_pallas(y_intra, la, c, state, *, chunk: int = 128,
                            interpret: bool = False, name: str = "ssd_carry"):
-    """Fused inter-chunk recurrence + apply (chain ``fuse=1``): one launch.
+    """Inter-chunk recurrence + apply in one launch.
 
     y_intra: (BH, L, P); la: (BH, L) chunk-cumulative log decay;
     c: (BH, L, S); state: (BH, nc, S, P) chunk state injections straight
-    out of ``ssd_intra_pallas``.  Unlike the unfused phase B, odd chunk
-    counts need no radix-space fallback: the sequential carry walks any nc.
+    out of ``ssd_intra_pallas``.  The sequential carry walks any nc.
     The chunk transitions a_chunk = exp(la[chunk end]) enter as (1, P)
     rows, which Mosaic broadcasts over the state's sublanes.
     """
@@ -199,28 +180,3 @@ def ssd_state_apply_pallas(y_intra, la, c, state, *, chunk: int = 128,
         interpret=interpret,
         name=name,
     )(y_intra, la[:, :, None], c, a_chunk, state)
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
-def ssd_apply_entry_pallas(y_intra, la, c, entry, *, chunk: int = 128,
-                           interpret: bool = False, name: str = "ssd_apply"):
-    """Adds the inter-chunk contribution. entry: (BH, nc, S, P)."""
-    BH, L, P = y_intra.shape
-    S = c.shape[-1]
-    nc = L // chunk
-    col, _ = _vector_specs(chunk)
-    return pl.pallas_call(
-        _inter_kernel,
-        grid=(BH, nc),
-        in_specs=[
-            pl.BlockSpec((1, chunk, P), lambda i, j: (i, j, 0)),
-            col,
-            pl.BlockSpec((1, chunk, S), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, S, P), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk, P), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, L, P), y_intra.dtype),
-        compiler_params=compiler_params("parallel", "parallel"),
-        interpret=interpret,
-        name=name,
-    )(y_intra, la[:, :, None], c, entry)

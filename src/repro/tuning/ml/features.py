@@ -33,9 +33,8 @@ from repro.kernels.blocks.plan import plan_for
 # the version and loading a stale one fails fast instead of mis-predicting.
 # v4: device feature columns (hardware-profile geometry/limits), so one
 # forest can pool rows measured on different profiles.
-# v5: "fuse" column — the chain-fusion boundary knob (ssd/rglru chains);
-# the plan columns (log2_passes) already see its effect, the raw knob lets
-# the forest separate fusion from blocking at equal pass counts.
+# v5: a column for the chain-fusion boundary knob of ssd/rglru, so the
+# forest could separate fusion from blocking at equal pass counts.
 # v6: "log2_vmem" / "vmem_fits" read a launch's full VMEM — double-buffered
 # blocks, scratch and fold temporaries, bounded by the compile limit — where
 # they read the resident io blocks before.
@@ -44,14 +43,15 @@ from repro.kernels.blocks.plan import plan_for
 # "radix_rank" still reads rule 4's larger-radix value.
 # v8: "ana_rank_pct" orders an SSD chain's chunk by its modelled
 # intra-chunk and per-chunk time instead of by the fewest chunks.
-FEATURE_VERSION = 8
+# v9: the chain-fusion column is gone with its knob: ssd and rglru run one
+# chain each.
+FEATURE_VERSION = 9
 
 FEATURE_NAMES = (
     # workload (Input Parameters `A`)
     "log2_n", "log2_batch", "dtype_bytes", "variant_id",
     # raw knobs (Performance Parameters `B`); 0.0 when a knob is absent
     "log2_tile_n", "log2_rows", "log2_radix", "log2_unroll", "in_register",
-    "fuse",
     "log2_block_q", "log2_block_k", "log2_block_m", "log2_block_n",
     # StagePlan stack (the exact staged execution the drivers launch:
     # launches/HBM passes, stage count, carry-chain depth, raggedness,
@@ -135,7 +135,6 @@ def _encode(space: SearchSpace, cfg: Mapping[str, int]):
         "dtype_bytes": float(dtype_bytes(wl.dtype)),
         "variant_id": variant_id(wl.variant),
         "in_register": float(cfg.get("in_register", 0)),
-        "fuse": float(cfg.get("fuse", 0)),
         "log2_grid": _log2(res["grid"]),
         "log2_vmem": _log2(res["vmem"]),
         "occupancy": float(res["occupancy"]),

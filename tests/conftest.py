@@ -201,9 +201,9 @@ def _run_rglru(dtype, batch, n):
 
 
 def _run_ssd_fused(dtype, batch, n):
-    """Chain-fusion differential: fuse=1 (fused state-apply launch) must
-    match both fuse=0 (phase-B through the shared linrec block) and the
-    sequential reference on the same odd/prime grid."""
+    """Several chunks under a pinned config: the intra launch and the
+    sequential state-and-apply launch must match the sequential reference
+    on an odd/prime grid."""
     import jax
 
     from repro.kernels.ssd.ops import ssd
@@ -213,18 +213,14 @@ def _run_ssd_fused(dtype, batch, n):
     a = jax.random.uniform(ks[1], (batch, n, 2), minval=0.85, maxval=0.999)
     b = jax.random.normal(ks[2], (batch, n, 8)) * 0.3
     c = jax.random.normal(ks[3], (batch, n, 8)) * 0.3
-    cfg = {"tile_n": min(128, n), "radix": 2}
-    fused = ssd(x, a, b, c, config=dict(cfg, fuse=1), interpret=True,
-                use_pallas=True)
-    unfused = ssd(x, a, b, c, config=dict(cfg, fuse=0), interpret=True,
-                  use_pallas=True)
-    assert_kernel_close(fused, unfused, dtype, scale=10.0)
-    assert_kernel_close(fused, ssd_ref(x, a, b, c), dtype, scale=10.0)
+    got = ssd(x, a, b, c, config={"tile_n": min(128, n)}, interpret=True,
+              use_pallas=True)
+    assert_kernel_close(got, ssd_ref(x, a, b, c), dtype, scale=10.0)
 
 
 def _run_rglru_fused(dtype, batch, n):
-    """fuse=1 folds the gate into the scan kernel's first stage; must
-    match the unfused chain (XLA gate pass) and the oracle."""
+    """The gate folded into the scan kernel's first stage, under a pinned
+    config, must match the oracle."""
     import jax
 
     from repro.kernels.rglru.ops import rglru
@@ -233,12 +229,8 @@ def _run_rglru_fused(dtype, batch, n):
     a = jax.random.uniform(ks[0], (batch, n, 16), minval=0.8, maxval=0.99)
     u = jax.random.normal(ks[1], (batch, n, 16))
     cfg = {"tile_n": min(128, n), "rows_per_program": 8, "radix": 2}
-    fused = rglru(a, u, config=dict(cfg, fuse=1), interpret=True,
-                  use_pallas=True)
-    unfused = rglru(a, u, config=dict(cfg, fuse=0), interpret=True,
-                    use_pallas=True)
-    assert_kernel_close(fused, unfused, dtype, scale=10.0)
-    assert_kernel_close(fused, rglru_ref(a, u), dtype, scale=10.0)
+    got = rglru(a, u, config=cfg, interpret=True, use_pallas=True)
+    assert_kernel_close(got, rglru_ref(a, u), dtype, scale=10.0)
 
 
 def _run_prefix_sum_radix(radix):
@@ -329,9 +321,8 @@ _KERNEL_TABLE = {
     # matmul shapes: (batch*11) x 65 x n — every dim odd or prime-factored
     "matmul": (_run_matmul, ("float32", "bfloat16"), ((3, 96), (5, 128))),
     "ssd": (_run_ssd, ("float32",), ((3, 96),)),
-    # chain-fusion differentials: fused == unfused == oracle. ssd shapes
-    # pick nc = 2 and nc = 3 chunks — odd nc has no valid phase-B linrec
-    # config, so fuse=0 crosses the XLA fallback while fuse=1 stays fused
+    # pinned multi-chunk / in-kernel-gate configs vs the oracle: the ssd
+    # shapes pick nc = 2 and nc = 3 chunks
     "ssd_fused": (_run_ssd_fused, ("float32",), ((3, 256), (5, 384))),
     "rglru": (_run_rglru, ("float32",), ((3, 96), (5, 128))),
     "rglru_fused": (_run_rglru_fused, ("float32",), ODD_BATCH_SHAPES),

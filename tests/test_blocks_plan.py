@@ -7,6 +7,7 @@ grid must match what the rebuilt scan/fft/tridiag kernels actually launch
 the kernels' own BlockSpec arithmetic, so the plan cannot drift from the
 execution without failing this file.
 """
+import dataclasses
 import math
 
 import numpy as np
@@ -73,15 +74,10 @@ def test_plans_report_shift_folds_of_their_fold_circuit():
     assert multi.kind == "multipass"
     assert multi.shift_folds == shift_fold_counts((4, 4, 4, 4),
                                                   V5E.lane_count)
-    # SSD: only the unfused chain runs a fold circuit (phase B, the child)
-    ssd = Workload(op="ssd", n=512, batch=16, variant="")
-    unfused = plan_for(ssd, {"tile_n": 128, "radix": 2, "fuse": 0},
-                       profile=V5E)
-    assert unfused.shift_folds == unfused.children[0].shift_folds == (2, 0)
-    assert plan_for(ssd, {"tile_n": 128, "radix": 2, "fuse": 1},
-                    profile=V5E).shift_folds is None
-    # butterflies, PCR and stage-less plans carry no fold circuit
-    for wl in (Workload(op="fft", n=128, batch=8, variant="stockham"),
+    # butterflies, PCR, the SSD's chunk kernels and stage-less plans carry
+    # no fold circuit
+    for wl in (Workload(op="ssd", n=512, batch=16, variant=""),
+               Workload(op="fft", n=128, batch=8, variant="stockham"),
                Workload(op="tridiag", n=128, batch=8, variant="pcr"),
                Workload(op="attention", n=256, batch=4, variant="flash")):
         cfg = build_space(wl, V5E).enumerate_valid()[0]
@@ -120,10 +116,9 @@ def test_plan_invariants_over_valid_space(wl):
             assert math.prod(plan.stages) >= plan.n
         # valid configs fit the VMEM limit the kernels compile under
         assert plan.vmem_bytes <= V5E.vmem_budget
-        # HBM pass count == launch count + the chain's XLA links for
-        # pallas-backed plans (rglru's unfused gate is an XLA pass)
+        # HBM pass count == launch count for pallas-backed plans
         if plan.launches:
-            assert plan.passes == len(plan.launches) + plan.xla_passes
+            assert plan.passes == len(plan.launches)
         assert plan.seq_tiles >= 1 and plan.grid_size >= 1
         res = plan.resources()
         assert res["passes"] == plan.passes
@@ -148,7 +143,7 @@ def test_paper_sizes_plan_within_compile_vmem_limit(op, variant, n):
         assert plan.check(V5E) == []
         assert all(l.vmem_bytes <= V5E.vmem_budget for l in plan.launches)
     widest = {"tile_n": n, "rows_per_program": 512, "radix": 8, "unroll": 1,
-              "in_register": 0, "fuse": 1}
+              "in_register": 0}
     widest = {k: v for k, v in widest.items()
               if k in {p.name for p in space.params}}
     over = plan_for(wl, widest, profile=V5E).vmem_bytes > V5E.vmem_budget
@@ -385,73 +380,72 @@ def test_lf_multipass_matches_lf():
 
 
 # ---------------------------------------------------------------------------
-# Chain plans: op sequences staged as one plan
+# Composite ops: one chain each
 # ---------------------------------------------------------------------------
 
 def test_chain_plans_check_clean_over_valid_spaces():
+    """Every plan over the valid ssd and rglru spaces passes ``check``:
+    its passes are its launches, each within the compile limit."""
     from repro.hw.profiles import get_profile
-    from repro.kernels.blocks.plan import plan_for_chain
     spec = get_profile("tpu_v5e")
     for wl in (Workload(op="rglru", n=256, batch=32),
                Workload(op="ssd", n=512, batch=16, variant="chunked")):
         space = build_space(wl)
-        assert any(c.get("fuse") for c in space.enumerate_valid())
-        for cfg in space.enumerate_valid():
+        cfgs = space.enumerate_valid()
+        assert cfgs
+        for cfg in cfgs:
             norm = normalizer_for(wl.op)(cfg, wl, None)
-            chain = plan_for_chain(wl, dict(cfg, **norm)
-                                   if wl.op == "rglru" else cfg)
-            assert chain.check(spec) == []
-            # chain launches are exactly the plan's, chain passes the
-            # plan's total (kernel passes + XLA links)
-            assert tuple(chain.launches) == tuple(chain.plan.launches)
-            assert chain.passes + chain.plan.xla_passes == chain.plan.passes \
-                or chain.passes == chain.plan.passes
+            plan = plan_for(wl, dict(cfg, **norm)
+                            if wl.op == "rglru" else cfg)
+            assert plan.check(spec) == []
+            assert plan.passes == len(plan.launches)
 
 
-def test_rglru_chain_fuse_folds_gate_link():
-    from repro.kernels.blocks.plan import plan_for_chain
-    wl = Workload(op="rglru", n=256, batch=32)
+def test_rglru_plan_runs_the_gate_in_the_scan_launches():
+    """The gate is the scan kernel's first stage: an rglru plan has the
+    launches (but for their name) and passes of the plain
+    linear-recurrence plan."""
+    def shapes(plan):
+        return [dataclasses.replace(l, name="") for l in plan.launches]
+
     cfg = {"tile_n": 128, "rows_per_program": 8, "radix": 2}
-    unfused = plan_for_chain(wl, dict(cfg, fuse=0))
-    fused = plan_for_chain(wl, dict(cfg, fuse=1))
-    assert [l.kind for l in unfused.links] == ["xla", "pallas"]
-    assert [l.kind for l in fused.links] == ["fused", "pallas"]
-    assert unfused.plan.xla_passes == 1 and fused.plan.xla_passes == 0
-    assert fused.plan.passes == unfused.plan.passes - 1
+    for n in (256, 2**14):            # fused, then past the seq limit
+        rglru = plan_for(Workload(op="rglru", n=n, batch=32), cfg)
+        scan = plan_for(Workload(op="scan", n=n, batch=32,
+                                 variant="linrec"), cfg)
+        assert shapes(rglru) == shapes(scan)
+        assert rglru.passes == scan.passes == len(rglru.launches)
 
 
-def test_ssd_chain_fuse_collapses_phases():
-    from repro.kernels.blocks.plan import plan_for_chain
+def test_ssd_plan_has_one_launch_per_phase():
+    """One chunk: the intra launch alone.  Several: the intra launch and
+    the sequential recurrence-and-apply launch, two HBM passes."""
     wl = Workload(op="ssd", n=512, batch=16, variant="chunked")
-    cfg = {"tile_n": 128, "radix": 2}
-    unfused = plan_for_chain(wl, dict(cfg, fuse=0), dims=(8, 16))
-    fused = plan_for_chain(wl, dict(cfg, fuse=1), dims=(8, 16))
-    assert [l.name for l in unfused.links] == ["intra", "linrec", "apply"]
-    assert unfused.plan.kind == "three-phase" and unfused.passes == 3
-    assert fused.plan.kind == "two-phase" and fused.passes == 2
-    assert len(fused.launches) < len(unfused.launches)
+    one = plan_for(wl, {"tile_n": 512})
+    assert one.kind == "fused" and one.passes == 1
+    assert [l.name for l in one.launches] == ["ssd-intra"]
+    two = plan_for(wl, {"tile_n": 128})
+    assert two.kind == "two-phase" and two.passes == 2
+    assert [l.name for l in two.launches] == ["ssd-intra", "ssd-state-apply"]
+    assert all(l.grid == (16, 4) for l in two.launches)
 
 
-def test_ssd_chain_odd_chunk_count_models_xla_fallback():
-    """nc = 3 has no valid linrec space config; the unfused chain's middle
-    link must be an XLA link (mirroring driver._linrec_space_valid), while
-    the fused chain's sequential carry needs no fallback."""
-    from repro.kernels.blocks.plan import plan_for_chain
+def test_ssd_plan_odd_chunk_count_has_two_launches():
+    """nc = 3: the sequential carry walks any chunk count, so the plan is
+    the same two launches as at an even count."""
     wl = Workload(op="ssd", n=384, batch=16, variant="chunked")
-    unfused = plan_for_chain(wl, {"tile_n": 128, "fuse": 0}, dims=(8, 16))
-    assert [l.kind for l in unfused.links] == ["pallas", "xla", "pallas"]
-    fused = plan_for_chain(wl, {"tile_n": 128, "fuse": 1}, dims=(8, 16))
-    assert [l.kind for l in fused.links] == ["pallas", "fused", "pallas"]
-    assert len(fused.launches) == 2
+    plan = plan_for(wl, {"tile_n": 128})
+    assert plan.kind == "two-phase" and plan.seq_tiles == 3
+    assert [l.grid for l in plan.launches] == [(16, 3), (16, 3)]
 
 
 def test_ssd_plan_models_intra_work_against_per_chunk_cost():
-    """The SSD chain's two modelled terms: the intra-chunk matrix work
+    """The SSD plan's two modelled terms: the intra-chunk matrix work
     grows with the chunk (six bf16 passes of 2·(2·Q·128 + 128²) flops an
     element), the chunk's own costs shrink per element; other ops report
     neither."""
     wl = Workload(op="ssd", n=8192, batch=64, variant="chunked")
-    res = [plan_for(wl, {"tile_n": q, "fuse": 1}, profile=V5E).resources()
+    res = [plan_for(wl, {"tile_n": q}, profile=V5E).resources()
            for q in (128, 256, 512, 1024)]
     intra = [r["intra_s"] for r in res]
     chunk = [r["chunk_s"] for r in res]

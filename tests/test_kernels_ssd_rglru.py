@@ -61,105 +61,97 @@ def test_rglru_matches_ref():
 
 
 # ---------------------------------------------------------------------------
-# Chain fusion + embedded-block config resolution
+# Config resolution and plan conformance
 # ---------------------------------------------------------------------------
 
-def test_ssd_override_reaches_embedded_phase_b():
-    """Regression: the enclosing ssd resolution must be threaded into the
-    embedded phase-B linrec block.  Before the fix, ``linrec_rows`` ran a
-    fresh ``config=None`` resolution, so ``ssd(config=...)`` (and
-    ``overrides(ssd=...)``) could never change the phase-B launch — here a
-    radix override must flip its in-kernel stage decomposition."""
+def _chunk_launches(rec):
+    return [l for l in rec if l.name == "ssd-intra"]
+
+
+def test_ssd_config_reaches_chunk_launch():
+    """``ssd(config=...)`` picks the chunk the intra-chunk launch runs."""
     from repro.kernels.blocks import driver
 
-    x, a, b, c = _ssd_inputs(L=512)        # chunk 128 -> nc = 4
-    traces = {}
-    for radix in (2, 4):
+    x, a, b, c = _ssd_inputs(L=512)
+    for chunk in (128, 256):
         with driver.capture_launches() as rec:
-            got = ssd(x, a, b, c,
-                      config={"tile_n": 128, "radix": radix, "fuse": 0},
+            got = ssd(x, a, b, c, config={"tile_n": chunk},
                       interpret=True, use_pallas=True)
         np.testing.assert_allclose(got, ssd_ref(x, a, b, c),
                                    rtol=1e-3, atol=1e-3)
-        traces[radix] = [l for l in rec if l.name == "scan"]
-    assert traces[2] and traces[4]
-    assert traces[2][0].stages == (2, 2)    # nc = 4 under radix 2
-    assert traces[4][0].stages == (4,)      # the override reached phase B
+        (intra,) = _chunk_launches(rec)
+        assert intra.block_shape == (1, chunk)
+        assert intra.grid == (4, 512 // chunk)
 
 
-def test_ssd_overrides_context_reaches_embedded_phase_b():
+def test_ssd_overrides_context_reaches_chunk_launch():
     from repro.kernels.blocks import driver
     from repro.tuning import overrides
 
     x, a, b, c = _ssd_inputs(L=512)
-    with overrides(ssd={"tile_n": 128, "radix": 4, "fuse": 0}):
+    with overrides(ssd={"tile_n": 256}):
         with driver.capture_launches() as rec:
             ssd(x, a, b, c, interpret=True, use_pallas=True)
-    scans = [l for l in rec if l.name == "scan"]
-    assert scans and scans[0].stages == (4,)
+    (intra,) = _chunk_launches(rec)
+    assert intra.block_shape == (1, 256)
 
 
-@pytest.mark.parametrize("op", ["ssd", "rglru"])
-def test_fused_chain_issues_strictly_fewer_launches(op):
-    """The fused chain must issue strictly fewer launches than the
-    unfused one for at least this config (ssd: 3 -> 2 kernel launches;
-    rglru: the multipass chain drops the XLA gate pass, counted through
-    the plan since XLA ops don't appear in the Pallas launch trace)."""
+def _run_traced(op, cfg, n):
+    """Run ``op`` under ``cfg`` at length ``n``; return its workload and
+    the launches it executed."""
     from repro.core.space import Workload
     from repro.kernels.blocks import driver
-    from repro.kernels.blocks.plan import plan_for_chain
 
-    traces = {}
-    if op == "ssd":
-        x, a, b, c = _ssd_inputs(L=512)
-        for fuse in (0, 1):
-            cfg = {"tile_n": 128, "radix": 2, "fuse": fuse}
-            with driver.capture_launches() as rec:
-                ssd(x, a, b, c, config=cfg, interpret=True, use_pallas=True)
-            traces[fuse] = list(rec)
-        assert len(traces[1]) < len(traces[0])
-    else:
-        ks = jax.random.split(KEY, 2)
-        a = jax.random.uniform(ks[0], (2, 256, 16), minval=0.8, maxval=0.99)
-        u = jax.random.normal(ks[1], (2, 256, 16))
-        wl = Workload(op="rglru", n=256, batch=32)
-        passes = {}
-        for fuse in (0, 1):
-            cfg = {"tile_n": 128, "rows_per_program": 8, "radix": 2,
-                   "fuse": fuse}
-            chain = plan_for_chain(wl, cfg)
-            with driver.capture_launches() as rec:
-                rglru(a, u, config=cfg, interpret=True, use_pallas=True)
-            assert tuple(rec) == tuple(chain.launches)
-            passes[fuse] = chain.plan.passes
-        assert passes[1] < passes[0]
-
-
-@pytest.mark.parametrize("fuse", [0, 1])
-def test_ssd_executed_launches_equal_chain_plan(fuse):
-    """Conformance: the executed launch list is exactly the chain plan's
-    (dims pin the embedded phase-B geometry, so equality is structural)."""
-    from repro.core.space import Workload
-    from repro.kernels.blocks import driver
-    from repro.kernels.blocks.plan import plan_for_chain
-
-    x, a, b, c = _ssd_inputs(L=512)
-    B, L, H, P = x.shape
-    S = b.shape[-1]
-    wl = Workload(op="ssd", n=L, batch=B * H, variant="chunked")
-    cfg = {"tile_n": 128, "radix": 2, "fuse": fuse}
-    chain = plan_for_chain(wl, cfg, dims=(S, P))
     with driver.capture_launches() as rec:
-        ssd(x, a, b, c, config=cfg, interpret=True, use_pallas=True)
-    assert tuple(rec) == tuple(chain.launches)
+        if op == "ssd":
+            x, a, b, c = _ssd_inputs(L=n)
+            B, L, H, _ = x.shape
+            wl = Workload(op="ssd", n=L, batch=B * H, variant="chunked")
+            ssd(x, a, b, c, config=cfg, interpret=True, use_pallas=True)
+        else:
+            ks = jax.random.split(KEY, 2)
+            a = jax.random.uniform(ks[0], (2, n, 16), minval=0.8,
+                                   maxval=0.99)
+            u = jax.random.normal(ks[1], (2, n, 16))
+            wl = Workload(op="rglru", n=n, batch=32)
+            rglru(a, u, config=cfg, interpret=True, use_pallas=True)
+    return wl, tuple(rec)
+
+
+@pytest.mark.parametrize("op,cfg,n,kind", [
+    ("ssd", {"tile_n": 128}, 128, "fused"),
+    ("ssd", {"tile_n": 128}, 512, "two-phase"),
+    ("rglru", {"tile_n": 128, "rows_per_program": 8, "radix": 2}, 256,
+     "fused"),
+    ("rglru", {"tile_n": 128, "rows_per_program": 8, "radix": 2}, 2**14,
+     "multipass"),
+], ids=["ssd-one-chunk", "ssd-four-chunks", "rglru-fused",
+        "rglru-multipass"])
+def test_executed_launches_equal_plan(op, cfg, n, kind):
+    """Conformance: the executed launch list is exactly ``plan_for``'s,
+    and the plan's HBM passes are its launches (the rglru gate runs in
+    the scan kernel, so no pass goes unlaunched)."""
+    from repro.kernels.blocks.plan import plan_for
+    from repro.tuning import normalizer_for
+
+    wl, executed = _run_traced(op, cfg, n)
+    plan = plan_for(wl, normalizer_for(op)(cfg, wl, None)
+                    if op == "rglru" else cfg)
+    assert plan.kind == kind
+    assert executed == tuple(plan.launches)
+    assert plan.passes == len(plan.launches)
 
 
 def test_ssd_fused_handles_odd_chunk_count():
-    """nc = 3: unfused phase B has no valid linrec config (XLA fallback);
-    the fused sequential carry runs in-kernel and must still match."""
+    """nc = 3: the sequential carry walks an odd chunk count in-kernel;
+    the plan holds two launches and the output matches."""
+    from repro.kernels.blocks.plan import plan_for
+
+    wl, executed = _run_traced("ssd", {"tile_n": 128}, 384)
+    assert executed == tuple(plan_for(wl, {"tile_n": 128}).launches)
+    assert len(executed) == 2
     x, a, b, c = _ssd_inputs(L=384)
-    ref = ssd_ref(x, a, b, c)
-    for fuse in (0, 1):
-        got = ssd(x, a, b, c, config={"tile_n": 128, "fuse": fuse},
-                  interpret=True, use_pallas=True)
-        np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-3)
+    got = ssd(x, a, b, c, config={"tile_n": 128}, interpret=True,
+              use_pallas=True)
+    np.testing.assert_allclose(got, ssd_ref(x, a, b, c), rtol=1e-3,
+                               atol=1e-3)

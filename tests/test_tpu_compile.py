@@ -155,19 +155,18 @@ def test_ssd_compiles(compile_for):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("fuse,want", [
-    (1, ["ssd_carry", "ssd_chunk"]),
-    (0, ["ssd_apply", "ssd_carry", "ssd_chunk"]),
+@pytest.mark.parametrize("L,want", [
+    (8192, ["ssd_carry", "ssd_chunk"]),
+    (256, ["ssd_chunk"]),
 ])
-def test_ssd_kernels_named(compile_for, fuse, want):
+def test_ssd_kernels_named(compile_for, L, want):
     """A device trace tells the SSD launches apart by their HLO
-    instruction names: ``ssd_chunk`` (intra-chunk), ``ssd_carry`` (the
-    fused state-and-apply launch, or unfused the embedded linear
-    recurrence) and ``ssd_apply`` (unfused apply); granite-4.0-h-micro's
-    widths at 8192 tokens."""
+    instruction names: ``ssd_chunk`` (intra-chunk) and ``ssd_carry`` (the
+    inter-chunk state-and-apply launch, absent with a single chunk);
+    granite-4.0-h-micro's widths at chunk 256."""
     from repro.kernels.ssd.ops import ssd
-    B, L, H, P, S = 1, 8192, 64, 64, 128
-    config = {"tile_n": 256, "radix": 2, "fuse": fuse}
+    B, H, P, S = 1, 64, 64, 128
+    config = {"tile_n": 256}
     text = compile_for(
         lambda x, a, b, c: ssd(x, a, b, c, config=config, **COMPILED),
         ((B, L, H, P), F32), ((B, L, H), F32), ((B, L, S), F32),

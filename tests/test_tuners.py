@@ -194,12 +194,54 @@ def test_rule4_suggestions_unchanged_where_stages_sync_or_do_not_fold(
                                                          variant, n]
 
 
+# (profile, op, n, batch) -> (tile_n, rows_per_program, radix, unroll,
+# in_register): the SSD and RG-LRU suggestions at the registered configs'
+# shapes — granite-4.0-h-micro (64 heads), mamba2-130m (24 heads) and
+# recurrentgemma-9b (lru width 4096) — as the model ranked them when each
+# op still carried a second, unfused chain.
+_CHAIN_PINNED = {
+    ("cpu_interpret", "ssd", 8192, 64): (128, 64, 2, 8, 0),
+    ("cpu_interpret", "ssd", 256, 24): (256, 4, 4, 8, 0),
+    ("cpu_interpret", "ssd", 1024, 24): (128, 8, 2, 8, 0),
+    ("cpu_interpret", "ssd", 8192, 24): (128, 8, 2, 8, 0),
+    ("cpu_interpret", "rglru", 256, 4096): (256, 128, 4, 1, 0),
+    ("cpu_interpret", "rglru", 2048, 4096): (2048, 32, 2, 1, 0),
+    ("cpu_interpret", "rglru", 8192, 4096): (8192, 8, 2, 1, 0),
+    ("gpu_sm", "ssd", 8192, 64): (128, 64, 2, 8, 0),
+    ("gpu_sm", "ssd", 256, 24): (256, 4, 4, 8, 0),
+    ("gpu_sm", "ssd", 1024, 24): (128, 8, 2, 8, 0),
+    ("gpu_sm", "ssd", 8192, 24): (128, 8, 2, 8, 0),
+    ("gpu_sm", "rglru", 256, 4096): (256, 256, 4, 1, 0),
+    ("gpu_sm", "rglru", 2048, 4096): (2048, 64, 2, 1, 0),
+    ("gpu_sm", "rglru", 8192, 4096): (8192, 16, 2, 1, 0),
+    ("tpu_v5e", "ssd", 8192, 64): (256, 64, 4, 8, 0),
+    ("tpu_v5e", "ssd", 256, 24): (128, 8, 2, 8, 1),
+    ("tpu_v5e", "ssd", 1024, 24): (256, 8, 4, 8, 1),
+    ("tpu_v5e", "ssd", 8192, 24): (256, 8, 4, 8, 0),
+    ("tpu_v5e", "rglru", 256, 4096): (256, 512, 2, 1, 1),
+    ("tpu_v5e", "rglru", 2048, 4096): (2048, 256, 2, 1, 0),
+    ("tpu_v5e", "rglru", 8192, 4096): (8192, 64, 2, 1, 0),
+}
+
+
+@pytest.mark.parametrize("profile,op,n,batch", sorted(_CHAIN_PINNED))
+def test_ssd_and_rglru_suggestions_pinned(profile, op, n, batch):
+    from repro.hw.profiles import get_profile
+    variant = "chunked" if op == "ssd" else ""
+    space = build_space(Workload(op, n=n, batch=batch, variant=variant),
+                        get_profile(profile))
+    cfg = AnalyticalTuner().suggest(space)
+    knobs = ("tile_n", "rows_per_program", "radix", "unroll", "in_register")
+    assert tuple(cfg[k] for k in knobs) == _CHAIN_PINNED[profile, op, n,
+                                                         batch]
+
+
 def test_tpu_ssd_chunk_ranks_by_intra_chunk_work():
     """granite-4.0-h-micro's SSD at 8,192 tokens (64 heads): chunk 256,
-    the fastest of 128 ... 1024 on a TPU v5e (PERF.md), with phases B + C
-    fused.  Fewest chunks (1024) would quadruple the intra-chunk matmuls."""
+    the fastest of 128 ... 1024 on a TPU v5e (PERF.md).  Fewest chunks
+    (1024) would quadruple the intra-chunk matmuls."""
     from repro.hw.profiles import get_profile
     space = build_space(Workload("ssd", n=8192, batch=64, variant="chunked"),
                         get_profile("tpu_v5e"))
     cfg = AnalyticalTuner().suggest(space)
-    assert (cfg["tile_n"], cfg["fuse"]) == (256, 1)
+    assert cfg["tile_n"] == 256
