@@ -73,8 +73,9 @@ def _kernel_fft(x: jax.Array, plan: StagePlan, inverse: bool,
     record_launch(plan.launches[0])
     yre, yim = fft_pallas(re, im, rows_per_program=plan.rows,
                           stages=plan.stages, inverse=inverse,
+                          reorder=plan.launches[0].reorder,
                           interpret=interpret)
-    return (yre + 1j * yim).astype(jnp.complex64)
+    return jax.lax.complex(yre, yim)
 
 
 def dispatch_fft(x: jax.Array, plan: StagePlan, *, inverse: bool,

@@ -161,6 +161,15 @@ def is_ragged(stages: Tuple[int, ...], nominal: int, span: int) -> bool:
     return bool(stages) and stages[-1] != min(nominal, span)
 
 
+def fft_reorder_site(n: int) -> str:
+    """Where an n-point FFT launch's digit reversal can run: ``"kernel"``
+    (in VMEM, so the launch writes natural order) when n is a power of
+    two, so every stage is too and the reversal is a permutation of bit
+    fields; ``"xla"`` (a reshape/transpose after the launch) otherwise.
+    The plan also sends it to XLA where its scratch would not fit."""
+    return "kernel" if n & (n - 1) == 0 else "xla"
+
+
 def _round_up(v: int, m: int) -> int:
     return -(-max(int(v), 1) // m) * m
 
@@ -229,6 +238,8 @@ class Launch:
     stages: Tuple[int, ...]         # in-kernel stage radices
     vmem_bytes: int                 # pipeline buffers + scratch + fold
     #                                 temporaries, tile-padded
+    reorder: Optional[str] = None   # FFT: where the digit reversal runs,
+    #                                 "kernel" | "xla" (fft_reorder_site)
 
     @property
     def programs(self) -> int:
@@ -566,9 +577,24 @@ def _fft_fused_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
             + 2 * 2 * vmem_tile_bytes(coef_rows, n, 4, spec)
             + (_FFT_FOLD_TEMPS + 4 * max(stages, default=1))
             * vmem_tile_bytes(rows, n, 4, spec))
+    # the in-VMEM reversal (``primitives.digit_reverse_rows``): the
+    # transposed (n, rows) scratch, its lanes padded to a whole tile at
+    # small rows, and one chunk of lanes in flight both ways.  Where that
+    # would not fit (large n), the launch keeps the XLA reorder, so no
+    # config the space admitted without it drops out
+    lanes = min(rows, spec.lane_count)
+    chunk = min(n, spec.lane_count)
+    reorder_vmem = (vmem_tile_bytes(n, lanes, 4, spec)
+                    + 2 * vmem_tile_bytes(chunk, lanes, 4, spec)
+                    + vmem_tile_bytes(lanes, chunk, 4, spec))
+    reorder = fft_reorder_site(n)
+    if reorder == "kernel" and vmem + reorder_vmem <= spec.vmem_budget:
+        vmem += reorder_vmem
+    else:
+        reorder = "xla"
     trailing, lane, sub, occ = _occ(n, rows, spec)
     grid = (batch // rows,)
-    launch = Launch("fft", grid, (rows, n), stages, vmem)
+    launch = Launch("fft", grid, (rows, n), stages, vmem, reorder)
     return StagePlan(
         op="fft", variant=wl.variant, n=n, batch=batch, dtype=wl.dtype,
         kind="fused", tile_n=n, rows=rows, radix=radix, stages=stages,

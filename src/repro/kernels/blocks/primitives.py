@@ -9,7 +9,9 @@ operating on the trailing (lane) dimension of VMEM-resident tiles:
                        monoid (composition order fixed by the algebra);
   * ``butterfly``    — the radix-r complex DFT fold + twiddles of one
                        in-place DIF stage, as lane shifts times per-lane
-                       coefficients (``dif_coefficients``);
+                       coefficients (``dif_coefficients``), and
+                       ``digit_reverse_rows``, the layout shuffle that
+                       puts its output in natural order in VMEM;
   * ``carry chain``  — init/fold/store of the cross-tile VMEM carry that
                        turns a column-tiled grid into one streaming pass.
 
@@ -159,7 +161,7 @@ def butterfly(re: jax.Array, im: jax.Array, coef_re, coef_im, row: int,
     of ``coef_re``/``coef_im`` (refs of ``dif_coefficients``), so a stage
     is lane shifts and elementwise complex multiply-adds — no lane
     shuffle or reshape, which Mosaic cannot lower across the lane dim.
-    The outputs land in digit-reversed order; the caller reorders them.
+    The outputs land in digit-reversed order (``digit_reverse_rows``).
     """
     acc_re = jnp.zeros_like(re)
     acc_im = jnp.zeros_like(im)
@@ -176,11 +178,134 @@ def butterfly(re: jax.Array, im: jax.Array, coef_re, coef_im, row: int,
 def digit_reverse(y: jax.Array, stages: Sequence[int]) -> jax.Array:
     """Reorder (batch, n) DIF outputs into natural order (an XLA
     reshape/transpose outside the kernel): position (j1, .., jk), j1 most
-    significant, holds frequency j1 + r1 j2 + ..."""
+    significant, holds frequency j1 + r1 j2 + ...  The launches of an n
+    that is not a power of two use it (``plan.fft_reorder_site``)."""
     batch, n = y.shape
     k = len(stages)
     y = y.reshape((batch,) + tuple(int(r) for r in stages))
     return jnp.transpose(y, (0,) + tuple(range(k, 0, -1))).reshape(batch, n)
+
+
+def _log2(v: int) -> int:
+    return int(v).bit_length() - 1
+
+
+def _lo_stages(stages: Sequence[int], n: int) -> int:
+    """How many leading stages the in-VMEM reversal gathers at once: the
+    fewest whose fan-ins multiply to a whole sublane tile (8 rows), or to
+    n when n is smaller."""
+    lo, size = 0, 1
+    while size < min(8, n):
+        size *= stages[lo]
+        lo += 1
+    return lo
+
+
+def _lo_natural(c, stages: Sequence[int], lo: int):
+    """Natural index (r1 least significant) of the leading ``lo`` digits
+    whose DIF position (r1 most significant) is ``c``: a bit-field
+    permutation, so it adds over disjoint bits and takes traced ints."""
+    span = 1
+    for r in stages[:lo]:
+        span *= r
+    out, weight = 0, 1
+    for r in stages[:lo]:
+        span //= r
+        out = out + (((c >> _log2(span)) & (r - 1)) << _log2(weight))
+        weight *= r
+    return out
+
+
+def _hi_position(f, stages: Sequence[int], lo: int, n: int):
+    """DIF row offset of the trailing digits (after the first ``lo``)
+    whose natural index (r_lo+1 least significant) is ``f``; additive over
+    disjoint bits like ``_lo_natural``."""
+    out, shift, span = 0, 0, n
+    for t, r in enumerate(stages):
+        span //= r
+        if t >= lo:
+            out = out + (((f >> shift) & (r - 1)) << _log2(span))
+            shift += _log2(r)
+    return out
+
+
+# lanes of one vector register: the reversal transposes (128, 128) tiles
+LANE_TILE = 128
+
+
+def digit_reverse_rows(ref, tmp_ref, stages: Sequence[int]) -> None:
+    """Rewrite the (rows, n) DIF outputs held in ``ref`` in natural order,
+    inside the kernel; n a power of two (``plan.fft_reorder_site``).
+
+    Mosaic cannot reshape a row into its digit axes, so the reversal goes
+    through ``tmp_ref``, an (n, min(rows, 128)) VMEM scratch in which the
+    transform index runs down sublanes and the problems across lanes.
+    Split n = R * M, R the fan-ins of the first stages (``_lo_stages``):
+    row c * M + h (c their DIF digits) holds frequency lo(c) + R * hi(h).
+
+      * each chunk of 128 lanes is transposed into ``tmp_ref``, its M-row
+        blocks stored at lo(c) * M: a static permutation of whole blocks;
+      * each chunk of 128 output frequencies gathers 128 / R strided loads
+        of R rows at stride M (frequencies lo = 0 .. R-1 of one hi),
+        transposed back into ``ref``.
+
+    Both passes loop over the n / 128 chunks (``fori_loop``), so the
+    unrolled code is one chunk's whatever n is.
+    """
+    if len(stages) < 2:                   # one digit: already in order
+        return
+    rows, n = ref.shape
+    lo = _lo_stages(stages, n)
+    size = 1
+    for r in stages[:lo]:
+        size *= r
+    span = n // size
+    chunk = min(n, LANE_TILE)
+    chunks = n // chunk
+    lanes = tmp_ref.shape[1]
+
+    def lane_slice(q):
+        if chunks == 1:
+            return slice(None)
+        return pl.ds(pl.multiple_of(q * chunk, chunk), chunk)
+
+    def sublane_start(start, align):
+        return start if chunks == 1 else pl.multiple_of(start, align)
+
+    def loop(body):
+        if chunks == 1:
+            body(0, 0)
+        else:
+            jax.lax.fori_loop(0, chunks, body, 0)
+
+    for g in range(0, rows, lanes):
+        def scatter(q, carry):
+            t = ref[pl.ds(g, lanes), lane_slice(q)].T       # (chunk, lanes)
+            first = (q * chunk) >> _log2(span)
+            if span >= chunk:
+                dest = (_lo_natural(first, stages, lo) * span
+                        + (q * chunk) % span)
+                tmp_ref[pl.ds(sublane_start(dest, chunk), chunk), :] = t
+            else:
+                base = _lo_natural(first, stages, lo) * span
+                for b in range(chunk // span):
+                    dest = base + _lo_natural(b, stages, lo) * span
+                    tmp_ref[pl.ds(sublane_start(dest, span), span), :] = \
+                        t[b * span:(b + 1) * span]
+            return carry
+
+        def gather(q, carry):
+            per = chunk // size
+            base = _hi_position(q * per, stages, lo, n)
+            parts = [tmp_ref[pl.ds(base + _hi_position(i, stages, lo, n),
+                                   size, stride=span), :]
+                     for i in range(per)]
+            t = jnp.concatenate(parts, axis=0) if per > 1 else parts[0]
+            ref[pl.ds(g, lanes), lane_slice(q)] = t.T
+            return carry
+
+        loop(scatter)
+        loop(gather)
 
 
 # ---------------------------------------------------------------------------

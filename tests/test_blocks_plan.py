@@ -248,12 +248,16 @@ def test_fft_conformance_every_valid_config():
         rows = plan.rows
         assert launch.grid == (4 // rows,) == plan.launches[0].grid
         assert math.prod(launch.stages) == 64
-        # re/im in+out double-buffered, the coefficient tables, and the
-        # widest stage's 4 r + 10 temporaries
+        # re/im in+out double-buffered, the coefficient tables, the
+        # widest stage's 4 r + 10 temporaries, and the in-VMEM digit
+        # reversal: the (64, rows) scratch and one chunk each way
         coef_rows = sum(2 * r - 1 for r in launch.stages)
+        assert launch.reorder == "kernel"
         assert launch.vmem_bytes == (
             (8 + 10 + 4 * max(launch.stages)) * _vmem_tile(rows, 64)
-            + 4 * _vmem_tile(coef_rows, 64)) == plan.vmem_bytes
+            + 4 * _vmem_tile(coef_rows, 64)
+            + 3 * _vmem_tile(64, rows) + _vmem_tile(rows, 64)) \
+            == plan.vmem_bytes
         err = np.max(np.abs(np.asarray(got) - ref)) / np.max(np.abs(ref))
         assert err < 1e-4
 

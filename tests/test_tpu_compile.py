@@ -146,6 +146,44 @@ def test_fft_compiles(compile_for, n):
     assert "tpu_custom_call" in text
 
 
+def _ops_over(text, shape):
+    """Sorted opcodes (a custom call by its target) of the entry
+    computation's instructions whose result has ``shape``."""
+    import re
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    dims = "[" + ",".join(str(d) for d in shape) + "]"
+    out = []
+    for line in entry.splitlines()[2:]:
+        m = re.match(r"(\(.*?\)|\S+) ([\w\-]+)\(", line.partition(" = ")[2])
+        if m and dims in m.group(1) and m.group(2) not in (
+                "parameter", "get-tuple-element"):
+            target = re.search(r'custom_call_target="([^"]+)"', line)
+            out.append(target.group(1) if target else m.group(2))
+    return sorted(out)
+
+
+GLUE = ["X64Combine", "X64SplitHigh", "X64SplitLow", "tpu_custom_call"]
+
+
+@pytest.mark.parametrize("n,want", [
+    # n = 64: the c64 input and the result are relaid out, whatever the
+    # launch does
+    (64, ["copy"] + GLUE[:3] + ["copy"] + GLUE[3:]),
+    (128, GLUE), (256, GLUE), (512, GLUE), (1024, GLUE), (2048, GLUE),
+    (4096, GLUE),
+])
+def test_fft_glue_inventory(compile_for, n, want):
+    """At the ops.fft_pcr sizes the launch writes natural order: around
+    it only the complex split and combine touch a (batch, n) array, with
+    no copy, transpose or fusion that would reorder it in XLA."""
+    from repro.kernels.fft.ops import fft
+    shape = (TOTAL // n, n)
+    text = compile_for(lambda x: fft(x, interpret=False),
+                       (shape, jnp.complex64))
+    assert _ops_over(text, shape) == sorted(want)
+
+
 def test_ssd_compiles(compile_for):
     from repro.kernels.ssd.ops import ssd
     B, L, H, P, S = 4, 2048, 24, 64, 128    # mamba2-130m widths
