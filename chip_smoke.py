@@ -143,8 +143,8 @@ def _kernel_cases(jax, jnp):
         return (jax.random.normal(ks[0], (B, L, H, P), jnp.float32),
                 jax.random.uniform(ks[1], (B, L, H), jnp.float32, 0.85,
                                    0.999),
-                jax.random.normal(ks[2], (B, L, S), jnp.float32) * 0.3,
-                jax.random.normal(ks[3], (B, L, S), jnp.float32) * 0.3)
+                jax.random.normal(ks[2], (B, L, 1, S), jnp.float32) * 0.3,
+                jax.random.normal(ks[3], (B, L, 1, S), jnp.float32) * 0.3)
 
     cases.append((f"ssd mamba2-130m B={B} L={L} H={H} P={P} S={S}",
                   Workload("ssd", L, B * H, variant="chunked"),

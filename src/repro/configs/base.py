@@ -33,20 +33,38 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_top_k: int = 0
     d_ff_expert: int = 0
+    d_ff_shared: int = 0                  # 0: d_ff_expert * n_shared_experts
     capacity_factor: float = 1.25
     moe_group_size: int = 1024            # GShard routing group S
+    # routing: "softmax" top-k, or "sigmoid" scores whose top-k is picked
+    # by score + a learned correction bias and weighted by the unbiased
+    # scores; either way the picked weights are normalised to sum 1, then
+    # scaled by moe_routed_scale
+    moe_router: str = "softmax"
+    moe_routed_scale: float = 1.0
+    # expert share (the hybrid "moe" layers): this chip holds experts
+    # [moe_held_offset, moe_held_offset + moe_held) of the n_experts the
+    # router scores; 0 = all of them
+    moe_held: int = 0
+    moe_held_offset: int = 0
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
+    ssm_heads: int = 0                    # 0: ssm_expand * d_model / head dim
+    ssm_groups: int = 1                   # B/C groups, each shared by
+    #                                       ssm_heads / ssm_groups heads
     conv_width: int = 4
     # the published Mamba-2 mixer (arXiv:2405.21060): input x * dt into the
     # state, a per-head skip y += D x, and a bias on the depthwise conv
     ssm_dt_input: bool = False
     ssm_d_skip: bool = False
     ssm_conv_bias: bool = False
-    # hybrid: layer pattern period of "rec" (RG-LRU), "ssd" (Mamba-2) and
-    # "attn" layers, each followed by its MLP, e.g. ("rec","rec","attn")
+    # hybrid: layer pattern period of "rec" (RG-LRU), "ssd" (Mamba-2),
+    # "attn" and "moe" (expert share) layers, e.g. ("rec","rec","attn");
+    # with d_ff each rec, ssd and attn layer is followed by its MLP, without
+    # it each layer is x + mixer(rmsnorm(x)).  A stack with no period gives
+    # its whole pattern (n_layers == len(block_pattern))
     block_pattern: Tuple[str, ...] = ()
     lru_width: int = 0
     # enc-dec (whisper)
@@ -89,18 +107,26 @@ class ModelConfig:
         return self.n_heads * self.head_dim
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test variant: same family/topology, tiny dimensions."""
-        period = max(len(self.block_pattern), 1)
-        n_layers = max(2 * period, 2) if self.n_layers else 0
+        """Smoke-test variant: same family/topology, tiny dimensions (a
+        stack with no period keeps its shortest prefix that holds every
+        kind)."""
+        pattern = self.block_pattern
+        n_layers = max(2 * len(pattern), 2) if self.n_layers else 0
+        if pattern and self.n_layers == len(pattern):
+            n_layers = next(i for i in range(1, len(pattern) + 1)
+                            if set(pattern[:i]) == set(pattern))
+            pattern = pattern[:n_layers]
         return dataclasses.replace(
             self,
             n_layers=min(self.n_layers, n_layers) or self.n_layers,
+            block_pattern=pattern,
             d_model=min(self.d_model, 64) if self.d_model else 0,
             n_heads=min(self.n_heads, 4) or self.n_heads,
             n_kv_heads=max(min(self.n_kv_heads, 2), 1) if self.n_kv_heads else 0,
             head_dim=min(self.head_dim, 16) or self.head_dim,
             d_ff=min(self.d_ff, 128) if self.d_ff else 0,
             d_ff_expert=min(self.d_ff_expert, 64) if self.d_ff_expert else 0,
+            d_ff_shared=min(self.d_ff_shared, 128),
             vocab=min(self.vocab, 512),
             n_experts=min(self.n_experts, 8) if self.n_experts else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
@@ -109,6 +135,9 @@ class ModelConfig:
             #                        (capacity effects are exercised at scale)
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=min(self.ssm_head_dim, 8),
+            ssm_heads=min(self.ssm_heads, 8),
+            ssm_groups=min(self.ssm_groups, 2),
+            moe_held=min(self.moe_held, 8),
             lru_width=min(self.lru_width, 64) if self.lru_width else 0,
             n_enc_layers=min(self.n_enc_layers, 2) if self.n_enc_layers else 0,
             n_dec_layers=min(self.n_dec_layers, 2) if self.n_dec_layers else 0,
@@ -163,7 +192,7 @@ def _load_all() -> None:
     for mod in ("gemma_2b", "minitron_4b", "qwen15_05b", "granite_34b",
                 "whisper_large_v3", "llama32_vision_90b", "qwen2_moe_a27b",
                 "qwen3_moe_30b_a3b", "recurrentgemma_9b", "mamba2_130m",
-                "granite_4_0_h_micro"):
+                "granite_4_0_h_micro", "nemotron_3_nano_30b_a3b"):
         importlib.import_module(f"repro.configs.{mod}")
 
 

@@ -13,6 +13,11 @@ Two launches (Dao & Gu 2024, adapted to TPU tiling):
 Tunables: chunk length Q (the VMEM tile; tile_n in the tuning space) and
 rows via the grid. Q is hardware-aligned to the 128-lane MXU edge.
 
+B and C come one row per (batch, group), not per head: the ``heads`` rows
+of x that share a group read the same B/C row through the launch's index
+map (row i of x reads row i // heads), so no per-head copy of B or C is
+ever made.
+
 Each launch carries a name, which becomes its HLO instruction name on a
 device trace: ``ssd_chunk`` (intra) and ``ssd_carry`` (recurrence +
 apply) — the stage names of the multi-pass scan driver, so the two read
@@ -84,11 +89,19 @@ def _vector_specs(chunk: int):
             pl.BlockSpec((1, 1, chunk), lambda i, j: (i, 0, j)))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
-def ssd_intra_pallas(x, la, b, c, *, chunk: int = 128,
+def _group_spec(chunk: int, s: int, heads: int):
+    """BlockSpec of a (BG, L, S) B or C input: x row i reads group row
+    i // heads, ``heads`` being the heads a group serves."""
+    return pl.BlockSpec((1, chunk, s), lambda i, j: (i // heads, j, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret",
+                                             "name"))
+def ssd_intra_pallas(x, la, b, c, *, chunk: int = 128, heads: int = 1,
                      interpret: bool = False, name: str = "ssd_chunk"):
     """x: (BH, L, P); la: (BH, L) chunk-cumulative log decay
-    (``chunk_log_decay``); b, c: (BH, L, S) — b/c pre-broadcast.
+    (``chunk_log_decay``); b, c: (BG, L, S), one row per group of
+    ``heads`` consecutive x rows (BH = BG * heads).
 
     Returns (y_intra (BH, L, P), state (BH, nc, S, P)).
     """
@@ -102,8 +115,8 @@ def ssd_intra_pallas(x, la, b, c, *, chunk: int = 128,
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda i, j: (i, j, 0)),
             col, row,
-            pl.BlockSpec((1, chunk, S), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, chunk, S), lambda i, j: (i, j, 0)),
+            _group_spec(chunk, S, heads),
+            _group_spec(chunk, S, heads),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, P), lambda i, j: (i, j, 0)),
@@ -145,14 +158,17 @@ def _state_apply_kernel(y_ref, lac_ref, c_ref, ac_ref, st_ref, o_ref,
     carry_ref[...] = ac * ent + st
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "name"))
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret",
+                                             "name"))
 def ssd_state_apply_pallas(y_intra, la, c, state, *, chunk: int = 128,
-                           interpret: bool = False, name: str = "ssd_carry"):
+                           heads: int = 1, interpret: bool = False,
+                           name: str = "ssd_carry"):
     """Inter-chunk recurrence + apply in one launch.
 
     y_intra: (BH, L, P); la: (BH, L) chunk-cumulative log decay;
-    c: (BH, L, S); state: (BH, nc, S, P) chunk state injections straight
-    out of ``ssd_intra_pallas``.  The sequential carry walks any nc.
+    c: (BG, L, S), one row per group of ``heads`` x rows; state: (BH, nc,
+    S, P) chunk state injections straight out of ``ssd_intra_pallas``.
+    The sequential carry walks any nc.
     The chunk transitions a_chunk = exp(la[chunk end]) enter as (1, P)
     rows, which Mosaic broadcasts over the state's sublanes.
     """
@@ -169,7 +185,7 @@ def ssd_state_apply_pallas(y_intra, la, c, state, *, chunk: int = 128,
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda i, j: (i, j, 0)),
             col,
-            pl.BlockSpec((1, chunk, S), lambda i, j: (i, j, 0)),
+            _group_spec(chunk, S, heads),
             pl.BlockSpec((1, 1, 1, P), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, S, P), lambda i, j: (i, j, 0, 0)),
         ],

@@ -1,6 +1,8 @@
 """Tuned SSD op: chunked state-space dual as two planned launches.
 
-`ssd(x, a, b, c)` with shapes (B, L, H, P), (B, L, H), (B, L, S), (B, L, S).
+`ssd(x, a, b, c)` with shapes (B, L, H, P), (B, L, H), (B, L, G, S),
+(B, L, G, S): G groups of B and C, group g serving heads
+[g H/G, (g + 1) H/G) (G = 1: one B and C shared by every head).
 The chunk length comes from the TunerSession (op="ssd" shares the scan
 space; tile_n -> chunk). On CPU hosts the pure-jnp chunked formulation runs
 (same math, XLA-fused); the Pallas path is exercised in interpret mode by
@@ -42,7 +44,7 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         config: Optional[dict] = None, interpret: Optional[bool] = None,
         use_pallas: Optional[bool] = None) -> jax.Array:
     B, L, H, P = x.shape
-    S = b.shape[-1]
+    G, S = b.shape[-2:]
     wl = Workload(op="ssd", n=L, batch=B * H, variant="chunked")
     chunk = default_session().resolve(wl, config=config)["chunk"]
     use_pallas, interpret = plan_execution(use_pallas, interpret)
@@ -51,22 +53,24 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
 
     plan = plan_for(wl, {"tile_n": chunk})
 
-    # reshape to (BH, L, ...) rows; broadcast b/c over heads (n_groups=1)
+    # reshape to (BH, L, ...) rows and b/c to (BG, L, S) rows: each launch
+    # reads a head's group row through its index map
     xbh = jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, L, P)
     abh = jnp.transpose(a, (0, 2, 1)).reshape(B * H, L)
-    bbh = jnp.broadcast_to(b[:, None], (B, H, L, S)).reshape(B * H, L, S)
-    cbh = jnp.broadcast_to(c[:, None], (B, H, L, S)).reshape(B * H, L, S)
+    bbg = jnp.transpose(b, (0, 2, 1, 3)).reshape(B * G, L, S)
+    cbg = jnp.transpose(c, (0, 2, 1, 3)).reshape(B * G, L, S)
+    heads = H // G
 
     la = chunk_log_decay(abh, chunk)
     y, state = driver.launch(
-        ssd_intra_pallas, plan.launches[0], xbh, la, bbh, cbh,
-        chunk=chunk, interpret=interpret)
+        ssd_intra_pallas, plan.launches[0], xbh, la, bbg, cbg,
+        chunk=chunk, heads=heads, interpret=interpret)
     if len(plan.launches) > 1:
         # the inter-chunk recurrence and its apply in one sequential
         # launch: the (S, P) VMEM carry is the recurrence state.  With a
         # single chunk the entry state is zero and the intra kernel alone
         # is the answer.
         y = driver.launch(ssd_state_apply_pallas, plan.launches[1],
-                          y, la, cbh, state, chunk=chunk,
+                          y, la, cbg, state, chunk=chunk, heads=heads,
                           interpret=interpret)
     return jnp.transpose(y.reshape(B, H, L, P), (0, 2, 1, 3))

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from repro.configs.base import ModelConfig
+from repro.models.layers import UNGATED
 
 
 def _attn_params(cfg: ModelConfig) -> int:
@@ -11,16 +12,35 @@ def _attn_params(cfg: ModelConfig) -> int:
             + cfg.n_heads * hd * d)        # wo
 
 
-def _mlp_params(d: int, f: int) -> int:
+def _mlp_params(d: int, f: int, activation: str = "swiglu") -> int:
+    if activation in UNGATED:
+        return 2 * d * f                   # up, down
     return 3 * d * f                       # gate, up, down
 
 
 def _ssd_params(cfg: ModelConfig) -> int:
     d = cfg.d_model
-    d_inner = cfg.ssm_expand * d
-    n_heads = d_inner // cfg.ssm_head_dim
-    return (d * (2 * d_inner + 2 * cfg.ssm_state + n_heads)   # in_proj
+    n_heads = cfg.ssm_heads or cfg.ssm_expand * d // cfg.ssm_head_dim
+    d_inner = n_heads * cfg.ssm_head_dim
+    bc = 2 * cfg.ssm_groups * cfg.ssm_state
+    return (d * (2 * d_inner + bc + n_heads)                   # in_proj
             + d_inner * d)                                     # out_proj
+
+
+def _hybrid_params(cfg: ModelConfig, experts: int) -> int:
+    """Matmul parameters of a hybrid stack, with ``experts`` routed experts
+    counted in each MoE layer; with d_ff every other layer has its MLP."""
+    d, act = cfg.d_model, cfg.activation
+    w = cfg.lru_width or d
+    moe = (d * cfg.n_experts + experts * _mlp_params(d, cfg.d_ff_expert, act)
+           + _mlp_params(d, cfg.d_ff_shared or cfg.d_ff_expert
+                         * cfg.n_shared_experts, act))
+    mixer = {"rec": 2 * d * w + 2 * w * w + w * d,
+             "ssd": _ssd_params(cfg), "attn": _attn_params(cfg), "moe": moe}
+    mlp = _mlp_params(d, cfg.d_ff) if cfg.d_ff else 0
+    groups = cfg.n_layers // max(len(cfg.block_pattern), 1)
+    return groups * sum(mixer[k] + (mlp if k != "moe" else 0)
+                        for k in cfg.block_pattern)
 
 
 def active_param_count(cfg: ModelConfig) -> int:
@@ -52,14 +72,7 @@ def active_param_count(cfg: ModelConfig) -> int:
             per_layer += _mlp_params(d, cfg.d_ff)
         return per_layer * cfg.n_layers
     if cfg.family == "hybrid":
-        w = cfg.lru_width or d
-        mixer = {"rec": 2 * d * w + 2 * w * w + w * d,
-                 "ssd": _ssd_params(cfg), "attn": _attn_params(cfg)}
-        period = cfg.block_pattern
-        groups = cfg.n_layers // max(len(period), 1)
-        # every layer of the period has its MLP
-        return groups * sum(mixer[k] + _mlp_params(d, cfg.d_ff)
-                            for k in period)
+        return _hybrid_params(cfg, cfg.moe_top_k)
     raise ValueError(cfg.family)
 
 
@@ -77,6 +90,10 @@ def total_param_count(cfg: ModelConfig) -> int:
     """All parameters incl. embeddings and full expert banks."""
     d = cfg.d_model
     total = cfg.vocab * d                  # tied embedding
+    if not cfg.tie_embeddings:
+        total += cfg.vocab * d             # the lm_head
+    if cfg.family == "hybrid":
+        return total + _hybrid_params(cfg, cfg.n_experts)
     if cfg.family == "moe":
         per_layer = _attn_params(cfg)
         per_layer += cfg.n_experts * _mlp_params(d, cfg.d_ff_expert)

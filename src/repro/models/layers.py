@@ -49,22 +49,33 @@ def _act(name: str, x: jax.Array) -> jax.Array:
         return jax.nn.silu(x)
     if name in ("geglu", "gelu"):
         return jax.nn.gelu(x, approximate=True)
+    if name == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(name)
+
+
+# activations of an MLP without a gate: down(act(up(x)))
+UNGATED = ("relu2",)
 
 
 def init_mlp(key, d_model: int, d_ff: int, activation: str, dtype) -> Dict:
     k1, k2, k3 = jax.random.split(key, 3)
-    return {
-        "wi": init_dense(k1, d_model, d_ff, dtype),       # gate proj
+    p = {
         "wu": init_dense(k2, d_model, d_ff, dtype),       # up proj
         "wo": init_dense(k3, d_ff, d_model, dtype),
     }
+    if activation not in UNGATED:
+        p["wi"] = init_dense(k1, d_model, d_ff, dtype)    # gate proj
+    return p
 
 
 def mlp(p: Dict, x: jax.Array, activation: str, compute_dtype) -> jax.Array:
-    g = _act(activation, dense(p["wi"], x, compute_dtype))
-    u = dense(p["wu"], x, compute_dtype)
-    return dense(p["wo"], g * u, compute_dtype)
+    if "wi" in p:
+        h = _act(activation, dense(p["wi"], x, compute_dtype)) \
+            * dense(p["wu"], x, compute_dtype)
+    else:
+        h = _act(activation, dense(p["wu"], x, compute_dtype))
+    return dense(p["wo"], h, compute_dtype)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:

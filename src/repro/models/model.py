@@ -6,6 +6,9 @@ is the architecture's repeating pattern:
   dense/moe: 1 block;  hybrid: its block_pattern, e.g. (rec, rec,
   local-attn) or (ssd x5, attn, ssd x4);  vlm: 4 standard +
   1 cross-attn block;  ssm: 1 SSD block;  audio: enc stack + dec stack.
+A hybrid whose stack has no period gives it whole as one period (one
+group); without d_ff its layers are x + mixer(rmsnorm(x)) with no MLP of
+their own, the "moe" kind an expert-share MoE.
 
 Caches are pytrees with a leading group dimension threaded through the same
 scan. Modality frontends (whisper conv, vision patching) are STUBS per the
@@ -25,7 +28,8 @@ from repro.models import attention as attn_mod
 from repro.models import layers as L
 from repro.models import recurrent as rec_mod
 from repro.models import ssm as ssm_mod
-from repro.models.moe import init_moe, moe_block
+from repro.models.moe import (expert_share_block, init_expert_share,
+                              init_moe, moe_block)
 
 PyTree = Any
 
@@ -84,9 +88,11 @@ def _embed_scale(cfg: ModelConfig, dtype) -> jax.Array:
 
 
 def _logits(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Final norm, tied unembedding, softcap and logits divisor."""
+    """Final norm, unembedding (tied, or the lm_head), softcap and logits
+    divisor."""
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.unembed(params["embed"], x, cfg.logits_softcap)
+    logits = L.unembed(params.get("lm_head", params["embed"]), x,
+                       cfg.logits_softcap)
     if cfg.logits_scaling != 1.0:
         logits = logits / cfg.logits_scaling
     return logits
@@ -106,7 +112,8 @@ def _init_std_block(key, cfg: ModelConfig, dtype, cross: bool = False) -> Dict:
     if cfg.family == "moe":
         p["moe"] = init_moe(km, cfg, dtype)
     else:
-        p["mlp"] = L.init_mlp(km, cfg.d_model, cfg.d_ff, cfg.activation, dtype)
+        p["mlp"] = L.init_mlp(km, cfg.d_model, cfg.d_ff, cfg.activation,
+                              dtype) if cfg.d_ff else None
     if cross:
         p["lnx"] = jnp.zeros((cfg.d_model,), dtype)
         p["xattn"] = attn_mod.init_attention(kc, cfg, dtype, cross=True)
@@ -125,6 +132,8 @@ def _std_block(p: Dict, x, cfg: ModelConfig, *, positions, cache=None,
         x = _residual(x, attn_mod.cross_attention(
             p["xattn"], L.rms_norm(x, p["lnx"], cfg.norm_eps), memory, cfg,
             cd), cfg)
+    if p.get("mlp") is None and "moe" not in p:
+        return x, new_cache, aux
     y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.family == "moe":
         h, aux = moe_block(p["moe"], y, cfg, cd)
@@ -160,8 +169,11 @@ def _init_hybrid_group(key, cfg, dtype):
     group = []
     for k, kind in zip(ks, cfg.block_pattern):
         if kind == "ssd":
-            # a Mamba-2 mixer, each with its own MLP
+            # a Mamba-2 mixer, each with its own MLP where there is d_ff
             group.append(_init_ssd_group(k, cfg, dtype))
+        elif kind == "moe":
+            group.append({"ln": jnp.zeros((cfg.d_model,), dtype),
+                          "moe": init_expert_share(k, cfg, dtype)})
         elif kind == "rec":
             k1, k2 = jax.random.split(k)
             group.append({"ln1": jnp.zeros((cfg.d_model,), dtype),
@@ -202,6 +214,14 @@ def _hybrid_group(p, x, cfg, *, positions, cache=None, compute_dtype=None):
                 # stays live during the group's backward replay
                 run_rec = jax.checkpoint(run_rec)
             x, nc = run_rec(x)
+        elif "moe" in blk:
+            def run_moe(xx, blk=blk):
+                return _residual(xx, expert_share_block(
+                    blk["moe"], L.rms_norm(xx, blk["ln"], cfg.norm_eps), cfg,
+                    cd), cfg)
+            if cache is None and cfg.remat == "full":
+                run_moe = jax.checkpoint(run_moe)
+            x, nc = run_moe(x), sub_cache
         else:
             def run_att(xx, blk=blk):
                 return _std_block(blk, xx, cfg, positions=positions,
@@ -284,6 +304,9 @@ class Model:
                 jax.random.split(k_blocks, self.n_groups)),
             "final_norm": jnp.zeros((cfg.d_model,), dtype),
         }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = L.init_embedding(
+                jax.random.fold_in(k_embed, 1), cfg.vocab, cfg.d_model, dtype)
         if cfg.is_enc_dec:
             n_enc = cfg.n_enc_layers or cfg.n_layers
             enc_cfg = dataclasses.replace(cfg, family="dense")
@@ -391,6 +414,8 @@ class Model:
                     elif kind == "rec":
                         out.append(rec_mod.init_recurrent_cache(cfg, batch,
                                                                 dtype))
+                    elif kind == "moe":
+                        out.append({})
                     else:
                         out.append(kv_cache(min(max_len,
                                                 cfg.attn_window or max_len),

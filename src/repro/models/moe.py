@@ -1,4 +1,5 @@
-"""Mixture-of-Experts block (grouped GShard top-k dispatch, EP-shardable).
+"""Mixture-of-Experts blocks: GShard top-k dispatch (EP-shardable), and the
+expert share of one chip (``expert_share_block``).
 
 Routing is computed per GROUP of `moe_group_size` tokens (GShard's S):
 dispatch/combine tensors are (G, S, E, C) with the group dim inheriting the
@@ -8,6 +9,14 @@ cells; grouping makes C ~ S/E and the whole object linear in T.
 
 With tokens on ("pod","data") and experts on "model", XLA lowers the
 dispatch einsums to all-to-alls (verified by the roofline parser).
+
+The expert share is told which experts it holds and routes over all of
+them: the (token, choice) pairs routed to its experts are ranked by expert
+(a counting sort), laid out one padded group of row tiles per expert, and
+run through two grouped-matmul launches (up with the activation, down),
+with no capacity and no pair dropped; each token then sums its pairs'
+rows, weighted, and the shared expert.  What the experts held elsewhere
+add is left out: on one chip the layer runs without its exchange.
 """
 from __future__ import annotations
 
@@ -19,7 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import _act, dense, init_dense, init_mlp, mlp
+from repro.kernels.matmul.ops import grouped_config, grouped_matmul
+from repro.models.layers import UNGATED, _act, dense, init_dense, init_mlp, mlp
 
 
 def padded_experts(cfg: ModelConfig) -> int:
@@ -82,10 +92,7 @@ def moe_block(p: Dict, x: jax.Array, cfg: ModelConfig, compute_dtype
     g = bsz * (l // s)
     xg = x.reshape(g, s, d)
 
-    gate_logits = dense(p["router"], xg, jnp.float32)            # (G, S, E)
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    gate_w, gate_idx = jax.lax.top_k(probs, k)                   # (G, S, k)
-    gate_w = gate_w / jnp.clip(gate_w.sum(-1, keepdims=True), 1e-9)
+    probs, gate_w, gate_idx = route(p, xg, cfg)                  # (G, S, k)
 
     # load-balance aux (Switch): E * mean_e(f_e * P_e), averaged over groups
     me = jnp.mean(probs, axis=1)                                 # (G, E)
@@ -126,3 +133,111 @@ def moe_block(p: Dict, x: jax.Array, cfg: ModelConfig, compute_dtype
     if "shared" in p:
         out = out + mlp(p["shared"], x, cfg.activation, compute_dtype)
     return out.astype(x.dtype), aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# routing, shared by both blocks
+# ---------------------------------------------------------------------------
+
+def route(p: Dict, x: jax.Array, cfg: ModelConfig
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(scores (..., E), weights (..., k), experts (..., k)) of x (..., D).
+
+    softmax: the top k of the softmax.  sigmoid: scores sigmoid(logits)
+    from an f32 router at full precision; the top k of score +
+    ``router_bias`` are picked and weighted by their unbiased scores.
+    Either way the k weights are normalised to sum 1, then scaled by
+    ``moe_routed_scale``.
+    """
+    k = cfg.moe_top_k
+    if cfg.moe_router == "sigmoid":
+        logits = jnp.matmul(x.astype(jnp.float32), p["router"]["w"],
+                            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["router_bias"], k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        scores = jax.nn.softmax(dense(p["router"], x, jnp.float32), axis=-1)
+        w, idx = jax.lax.top_k(scores, k)
+    w = w / jnp.clip(w.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_routed_scale != 1.0:
+        w = w * cfg.moe_routed_scale
+    return scores, w, idx
+
+
+# ---------------------------------------------------------------------------
+# expert share
+# ---------------------------------------------------------------------------
+
+def held_experts(cfg: ModelConfig) -> int:
+    return cfg.moe_held or cfg.n_experts
+
+
+def init_expert_share(key, cfg: ModelConfig, dtype) -> Dict:
+    """The router over all n_experts (f32), the held experts' up and down
+    weights, and the shared expert."""
+    if cfg.activation not in UNGATED:
+        raise ValueError("the expert share runs experts without a gate")
+    kr, ke, ks = jax.random.split(key, 3)
+    d, f, held = cfg.d_model, cfg.d_ff_expert, held_experts(cfg)
+    p = {
+        "router": init_dense(kr, d, cfg.n_experts, jnp.float32),
+        "wu": (jax.random.normal(ke, (held, d, f), jnp.float32)
+               / math.sqrt(d)).astype(dtype),
+        "wo": (jax.random.normal(jax.random.fold_in(ke, 1), (held, f, d),
+                                 jnp.float32) / math.sqrt(f)).astype(dtype),
+    }
+    if cfg.moe_router == "sigmoid":
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(ks, d, cfg.d_ff_shared
+                               or cfg.d_ff_expert * cfg.n_shared_experts,
+                               cfg.activation, dtype)
+    return p
+
+
+def expert_share_block(p: Dict, x: jax.Array, cfg: ModelConfig,
+                       compute_dtype) -> jax.Array:
+    """The held experts' part of the layer, plus the shared expert.
+    x: (B, L, D)."""
+    bsz, l, d = x.shape
+    xf = x.reshape(bsz * l, d)
+    t, k = xf.shape[0], cfg.moe_top_k
+    held = p["wu"].shape[0]
+    _, w, idx = route(p, xf, cfg)                                # (T, k)
+    local = idx - cfg.moe_held_offset
+    mine = (local >= 0) & (local < held)
+    expert = jnp.where(mine, local, held).reshape(-1)            # held: elsewhere
+
+    # counting sort of the pairs by expert: each pair's rank among its
+    # expert's pairs, each expert's group of rows padded to whole tiles
+    cap = t * min(k, held)                       # the most pairs held here
+    up = grouped_config(cap, d, p["wu"].shape[-1])
+    bm = up["block_m"]
+    down = grouped_config(cap, p["wu"].shape[-1], d,
+                          config={"block_m": bm})
+    onehot = jax.nn.one_hot(expert, held + 1, dtype=jnp.int32)   # (T k, E+1)
+    ranks = jnp.cumsum(onehot, axis=0)
+    counts = ranks[-1, :held]
+    rank = jnp.take_along_axis(ranks, expert[:, None], axis=1)[:, 0] - 1
+    group_tiles = (counts + bm - 1) // bm
+    starts = jnp.append((jnp.cumsum(group_tiles) - group_tiles) * bm, 0)
+    rows = (-(-cap // bm) + held) * bm          # the padded groups fit
+    row = jnp.where(expert < held, starts[expert] + rank, rows)  # per pair
+    token = jnp.zeros((rows,), jnp.int32).at[row].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+
+    h = grouped_matmul(xf.astype(compute_dtype)[token],
+                       p["wu"].astype(compute_dtype), group_tiles,
+                       config=up, activation=cfg.activation,
+                       name="moe_gmm_up", use_pallas=cfg.use_pallas or None)
+    y = grouped_matmul(h, p["wo"].astype(compute_dtype), group_tiles,
+                       config=down, name="moe_gmm_down",
+                       use_pallas=cfg.use_pallas or None)
+    # rows of pairs held elsewhere (and tiles never computed) read garbage
+    got = jnp.take(y, row, axis=0, mode="clip").reshape(t, k, d)
+    got = jnp.where(mine[..., None], got, 0)
+    out = jnp.einsum("tk,tkd->td", w.astype(compute_dtype), got)
+    if "shared" in p:
+        out = out + mlp(p["shared"], xf, cfg.activation, compute_dtype)
+    return out.reshape(bsz, l, d).astype(x.dtype)

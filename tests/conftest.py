@@ -174,6 +174,30 @@ def _run_matmul(dtype, batch, n):
     assert_kernel_close(got, matmul_ref(a, b), dtype, scale=10.0)
 
 
+def _run_grouped_matmul(dtype, batch, n):
+    """``batch`` groups (one of them empty) of row tiles times their own
+    (65, n) weights, blocks resolved by the session, against the oracle
+    over the rows the groups hold."""
+    import jax.numpy as jnp
+
+    from repro.kernels.matmul.ops import grouped_config, grouped_matmul
+    from repro.kernels.matmul.ref import gmm_ref
+    rng = _rng(f"gmm{batch}x{n}")
+    k = 65                      # prime inner dim
+    cfg = grouped_config(batch * 64, k, n)
+    tiles = rng.integers(1, 3, size=batch)
+    tiles[batch // 2] = 0
+    rows = int(tiles.sum() + 1) * cfg["block_m"]
+    x = jnp.asarray(rng.normal(size=(rows, k)), getattr(jnp, dtype))
+    w = jnp.asarray(rng.normal(size=(batch, k, n)), getattr(jnp, dtype))
+    group_tiles = jnp.asarray(tiles, jnp.int32)
+    got = grouped_matmul(x, w, group_tiles, config=cfg, interpret=True,
+                         use_pallas=True)
+    used = int(tiles.sum()) * cfg["block_m"]
+    want = gmm_ref(x, w, group_tiles * cfg["block_m"])
+    assert_kernel_close(got[:used], want[:used], dtype, scale=10.0)
+
+
 def _run_ssd(dtype, batch, n):
     import jax
 
@@ -182,8 +206,8 @@ def _run_ssd(dtype, batch, n):
     ks = jax.random.split(jax.random.PRNGKey(batch * 1000 + n), 4)
     x = jax.random.normal(ks[0], (batch, n, 2, 16))
     a = jax.random.uniform(ks[1], (batch, n, 2), minval=0.85, maxval=0.999)
-    b = jax.random.normal(ks[2], (batch, n, 8)) * 0.3
-    c = jax.random.normal(ks[3], (batch, n, 8)) * 0.3
+    b = jax.random.normal(ks[2], (batch, n, 1, 8)) * 0.3
+    c = jax.random.normal(ks[3], (batch, n, 1, 8)) * 0.3
     got = ssd(x, a, b, c, interpret=True)
     assert_kernel_close(got, ssd_ref(x, a, b, c), dtype, scale=10.0)
 
@@ -211,8 +235,8 @@ def _run_ssd_fused(dtype, batch, n):
     ks = jax.random.split(jax.random.PRNGKey(batch * 1000 + n), 4)
     x = jax.random.normal(ks[0], (batch, n, 2, 16))
     a = jax.random.uniform(ks[1], (batch, n, 2), minval=0.85, maxval=0.999)
-    b = jax.random.normal(ks[2], (batch, n, 8)) * 0.3
-    c = jax.random.normal(ks[3], (batch, n, 8)) * 0.3
+    b = jax.random.normal(ks[2], (batch, n, 1, 8)) * 0.3
+    c = jax.random.normal(ks[3], (batch, n, 1, 8)) * 0.3
     got = ssd(x, a, b, c, config={"tile_n": min(128, n)}, interpret=True,
               use_pallas=True)
     assert_kernel_close(got, ssd_ref(x, a, b, c), dtype, scale=10.0)
@@ -320,6 +344,8 @@ _KERNEL_TABLE = {
     "fft_radix8": (_run_fft_radix(8), ("complex64",), ((7, 96), (3, 768))),
     # matmul shapes: (batch*11) x 65 x n — every dim odd or prime-factored
     "matmul": (_run_matmul, ("float32", "bfloat16"), ((3, 96), (5, 128))),
+    "grouped_matmul": (_run_grouped_matmul, ("float32", "bfloat16"),
+                       ((3, 96), (5, 128))),
     "ssd": (_run_ssd, ("float32",), ((3, 96),)),
     # pinned multi-chunk / in-kernel-gate configs vs the oracle: the ssd
     # shapes pick nc = 2 and nc = 3 chunks

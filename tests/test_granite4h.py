@@ -48,7 +48,7 @@ def test_new_fields_default_to_the_layer_the_other_configs_compute():
     for field, value in NEW_FIELDS.items():
         assert getattr(ModelConfig(arch="x", family="dense"), field) == value
     for arch in all_archs():
-        if arch == ARCH:
+        if arch in (ARCH, "nemotron-3-nano-30b-a3b"):   # both set them
             continue
         cfg = get_arch(arch)
         assert {f: getattr(cfg, f) for f in NEW_FIELDS} == NEW_FIELDS, arch

@@ -12,12 +12,12 @@ from repro.kernels.ssd.ref import ssd_chunked_ref, ssd_ref
 KEY = jax.random.PRNGKey(0)
 
 
-def _ssd_inputs(B=2, L=256, H=2, P=16, S=8):
+def _ssd_inputs(B=2, L=256, H=2, P=16, S=8, G=1):
     ks = jax.random.split(KEY, 4)
     x = jax.random.normal(ks[0], (B, L, H, P))
     a = jax.random.uniform(ks[1], (B, L, H), minval=0.85, maxval=0.999)
-    b = jax.random.normal(ks[2], (B, L, S)) * 0.3
-    c = jax.random.normal(ks[3], (B, L, S)) * 0.3
+    b = jax.random.normal(ks[2], (B, L, G, S)) * 0.3
+    c = jax.random.normal(ks[3], (B, L, G, S)) * 0.3
     return x, a, b, c
 
 
@@ -39,6 +39,24 @@ def test_ssd_pallas_pipeline(chunk):
     ref = ssd_ref(x, a, b, c)
     got = ssd(x, a, b, c, config={"tile_n": chunk}, interpret=True)
     np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("groups", [1, 2, 4])
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_grouped_ssd_matches_sequential(groups, use_pallas):
+    """B and C in G groups, head h reading group h // (H / G): the op (the
+    chunked XLA form, and the two launches in interpret mode, whose index
+    maps read a head's group row) matches the sequential oracle, and
+    differs from it when every head reads group 0."""
+    x, a, b, c = _ssd_inputs(L=256, H=4, G=groups)
+    ref = ssd_ref(x, a, b, c)
+    got = ssd(x, a, b, c, config={"tile_n": 128}, interpret=True,
+              use_pallas=use_pallas)
+    np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-3)
+    if groups > 1:
+        shared = ssd_ref(x, a, jnp.repeat(b[:, :, :1], groups, axis=2),
+                         jnp.repeat(c[:, :, :1], groups, axis=2))
+        assert float(jnp.max(jnp.abs(got - shared))) > 0.1
 
 
 def test_ssd_small_decay_no_nan_grads():

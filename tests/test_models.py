@@ -86,4 +86,4 @@ def test_shape_applicability_rules():
             n_skip += 1
         else:
             assert cfg.sub_quadratic
-    assert n_skip == 9      # exactly the 9 archs with full attention skip
+    assert n_skip == 10     # exactly the 10 archs with full attention skip

@@ -189,7 +189,7 @@ def test_ssd_compiles(compile_for):
     B, L, H, P, S = 4, 2048, 24, 64, 128    # mamba2-130m widths
     text = compile_for(lambda x, a, b, c: ssd(x, a, b, c, **COMPILED),
                        ((B, L, H, P), F32), ((B, L, H), F32),
-                       ((B, L, S), F32), ((B, L, S), F32))
+                       ((B, L, 1, S), F32), ((B, L, 1, S), F32))
     assert "tpu_custom_call" in text
 
 
@@ -207,8 +207,8 @@ def test_ssd_kernels_named(compile_for, L, want):
     config = {"tile_n": 256}
     text = compile_for(
         lambda x, a, b, c: ssd(x, a, b, c, config=config, **COMPILED),
-        ((B, L, H, P), F32), ((B, L, H), F32), ((B, L, S), F32),
-        ((B, L, S), F32))
+        ((B, L, H, P), F32), ((B, L, H), F32), ((B, L, 1, S), F32),
+        ((B, L, 1, S), F32))
     assert _kernel_names(text) == want
 
 
@@ -218,3 +218,103 @@ def test_flash_attention_compiles(compile_for):
     text = compile_for(lambda q, k, v: attention(q, k, v, **COMPILED),
                        shape, shape, shape)
     assert "tpu_custom_call" in text
+
+
+def _entry_ops(text):
+    """Counter of the entry computation's instructions by opcode (a custom
+    call by its target), with their result shapes."""
+    import collections
+    import re
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    ops = collections.Counter()
+    shapes = collections.defaultdict(list)
+    for line in entry.splitlines()[2:]:
+        m = re.match(r"(\(.*?\)|\S+) ([\w\-]+)\(", line.partition(" = ")[2])
+        if not m or m.group(2) in ("parameter", "get-tuple-element",
+                                   "tuple"):
+            continue
+        target = re.search(r'custom_call_target="([^"]+)"', line)
+        op = target.group(1) if target else m.group(2)
+        ops[op] += 1
+        shapes[op].append(line)
+    return ops, shapes
+
+
+def _mixer_text(compile_for, monkeypatch, arch, **fields):
+    """One Mamba-2 mixer of ``arch`` over 8,192 positions, compiled with
+    its Pallas launches (the program's own CPU check steered off)."""
+    import dataclasses
+    from repro.configs.base import get_arch
+    from repro.models import ssm
+    monkeypatch.setattr("repro.tuning.dispatch.on_cpu", lambda: False)
+    cfg = dataclasses.replace(get_arch(arch), use_pallas=True, **fields)
+    params = jax.eval_shape(
+        lambda k: ssm.init_ssd_block(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    leaves, tree = jax.tree.flatten(params)
+
+    def mixer(x, *leaves):
+        p = jax.tree.unflatten(tree, leaves)
+        return ssm.ssd_block(p, x, cfg)[0]
+    return compile_for(mixer, ((1, 8192, cfg.d_model), jnp.bfloat16),
+                       *[(leaf.shape, leaf.dtype) for leaf in leaves])
+
+
+# granite-4.0-h-micro's mixer as it compiled before B and C took groups:
+# its computing instructions, which every later compile must keep
+GRANITE_MIXER_OPS = {"fusion": 17, "tpu_custom_call": 2, "reduce-window": 1,
+                     "slice": 2, "ConcatBitcast": 3, "constant": 1}
+
+
+def test_granite_mixer_keeps_its_ops_but_the_bc_broadcast(compile_for,
+                                                           monkeypatch):
+    """granite's mixer computes as it did: the same fusions, kernels and
+    slices; the per-head B and C broadcasts (two f32[64,8192,128]) are
+    gone, the kernels reading the one (1, 8192, 128) group row instead.
+    Layout copies and prefetches, which XLA places by itself, are not
+    pinned."""
+    ops, lines = _entry_ops(_mixer_text(compile_for, monkeypatch,
+                                        "granite-4.0-h-micro"))
+    assert {op: ops[op] for op in GRANITE_MIXER_OPS} == GRANITE_MIXER_OPS
+    assert not any("[64,8192,128]" in line for line in lines["broadcast"])
+    assert ops["broadcast"] == 1         # the carry's chunk decay rows
+    for line in lines["tpu_custom_call"]:
+        assert "f32[1,8192,128]" in line.partition("operand_layout")[2]
+
+
+def test_nemotron_mixer_reads_bc_per_group(compile_for, monkeypatch):
+    """nemotron-3-nano-30b-a3b's mixer (64 heads, 8 groups): both SSD
+    launches take B/C as (B G, L, S) = f32[8,8192,128], and no per-head
+    copy of B or C is made."""
+    _, lines = _entry_ops(_mixer_text(compile_for, monkeypatch,
+                                      "nemotron-3-nano-30b-a3b"))
+    launches = lines["tpu_custom_call"]
+    assert _kernel_names("\n".join(launches)) == ["ssd_carry", "ssd_chunk"]
+    for line in launches:
+        assert "f32[8,8192,128]" in line.partition("operand_layout")[2]
+    assert not any(shape in line for line in lines["broadcast"]
+                   for shape in ("[64,8192,128]", "[1,8192,64,128]"))
+
+
+def test_expert_share_launches_named(compile_for, monkeypatch):
+    """One MoE layer of nemotron-3-nano-30b-a3b at 8,192 tokens with its 8
+    held experts: the grouped matmuls compile within the scoped VMEM and
+    are named ``moe_gmm_up`` and ``moe_gmm_down``."""
+    import dataclasses
+    from repro.configs.base import get_arch
+    from repro.models import moe
+    monkeypatch.setattr("repro.tuning.dispatch.on_cpu", lambda: False)
+    cfg = dataclasses.replace(get_arch("nemotron-3-nano-30b-a3b"),
+                              use_pallas=True, moe_held=8)
+    params = jax.eval_shape(
+        lambda k: moe.init_expert_share(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    leaves, tree = jax.tree.flatten(params)
+
+    def layer(x, *leaves):
+        return moe.expert_share_block(jax.tree.unflatten(tree, leaves), x,
+                                      cfg, jnp.bfloat16)
+    text = compile_for(layer, ((1, 8192, cfg.d_model), jnp.bfloat16),
+                       *[(leaf.shape, leaf.dtype) for leaf in leaves])
+    assert _kernel_names(text) == ["moe_gmm_down", "moe_gmm_up"]
