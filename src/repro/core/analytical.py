@@ -22,7 +22,10 @@ TPU guideline (re-derivation of the paper's four rules):
      while a farther one only re-indexes whole registers.  So circuits of
      ``shift_fold`` / ``linrec_level`` stages rank by fewer in-vreg folds,
      then fewer cross-vreg folds (which picks radix 2 for power-of-two
-     tiles); FFT butterflies and tridiagonal stages keep the larger radix.
+     tiles).  A radix-r DIF butterfly stage folds 2r - 1 shifted
+     neighbours, each one complex multiply-add, so FFT circuits rank by
+     fewer complex terms, then fewer in-vreg shifts (radix 2 again).
+     Tridiagonal stages keep the larger radix.
 
 Ahead of rule 4, after the tier and the pass count, a carry chain ranks
 by fewer sequential tiles.  An SSD chain is the exception: its phase A
@@ -51,7 +54,9 @@ OCCUPANCY_BAND = (0.60, 1.00)
 RESOURCE_KEYS = ("grid", "vmem", "occupancy", "ilp", "radix", "passes",
                  "block_bytes", "seq_tiles", "stage_count", "steps_per_pass",
                  "ragged", "lane_eff", "sublane_eff", "shift_circuit",
-                 "lane_folds", "vreg_folds", "intra_s", "chunk_s")
+                 "lane_folds", "vreg_folds", "intra_s", "chunk_s",
+                 "butterfly_terms", "butterfly_in_vreg",
+                 "butterfly_cross_vreg")
 
 
 @dataclasses.dataclass
@@ -153,11 +158,16 @@ def _seq_rank(res: Dict[str, float]) -> float:
 
 def _circuit_rank(res: Dict[str, float], spec: HardwareProfile,
                   exact: int) -> Tuple[float, float, float]:
-    """Rule 4's rank of the stage circuit (module docstring): fewer
-    lane-shifted folds for a shift-fold circuit on a barrier-free profile,
-    else the larger radix."""
-    if res["shift_circuit"] and spec.stage_sync_s == 0:
-        return (float(exact), -res["lane_folds"], -res["vreg_folds"])
+    """Rule 4's rank of the stage circuit (module docstring): on a
+    barrier-free profile, fewer lane-shifted folds for a shift-fold
+    circuit and fewer complex terms for a butterfly; else the larger
+    radix."""
+    if spec.stage_sync_s == 0:
+        if res["shift_circuit"]:
+            return (float(exact), -res["lane_folds"], -res["vreg_folds"])
+        if res["butterfly_terms"]:
+            return (float(exact), -res["butterfly_terms"],
+                    -res["butterfly_in_vreg"])
     return (float(exact), math.log2(max(res["radix"], 2)), 0.0)
 
 

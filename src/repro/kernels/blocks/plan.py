@@ -149,6 +149,45 @@ def shift_fold_counts(stages: Tuple[int, ...],
     return in_vreg, cross_vreg
 
 
+def butterfly_offsets(n: int, stages: Tuple[int, ...]
+                      ) -> Tuple[Tuple[int, ...], ...]:
+    """Lane offsets each in-place DIF stage of an n-point row folds.
+
+    Stage t of fan-in r and span h (the row is blocks of r * h lanes)
+    reads the 2r - 1 neighbours at ``d * h`` for d in 1 - r .. r - 1, one
+    complex term each; ``primitives.dif_coefficients`` builds one
+    coefficient row per offset from this list.
+    """
+    out = []
+    span = n
+    for r in stages:
+        h = span // r
+        out.append(tuple(d * h for d in range(1 - r, r)))
+        span = h
+    return tuple(out)
+
+
+def butterfly_counts(n: int, stages: Tuple[int, ...],
+                     lane_count: int) -> Tuple[int, int, int]:
+    """Work per element of the DIF stage loop (``butterfly_offsets``).
+
+    Returns ``(terms, in_vreg, cross_vreg)``: complex multiply-adds, then
+    the shifted neighbours among them whose offset is not a multiple of
+    ``lane_count`` (rotated and selected inside a vector register) and
+    those whose offset is (whole registers re-indexed).  Offset 0 is a
+    term and no shift.
+    """
+    terms = in_vreg = cross_vreg = 0
+    for offsets in butterfly_offsets(n, stages):
+        terms += len(offsets)
+        for off in offsets:
+            if off % lane_count:
+                in_vreg += 1
+            elif off:
+                cross_vreg += 1
+    return terms, in_vreg, cross_vreg
+
+
 def is_ragged(stages: Tuple[int, ...], nominal: int, span: int) -> bool:
     """Mixed-radix tail check shared by every plan builder.
 
@@ -290,6 +329,9 @@ class StagePlan:
     # (intra-chunk matrix-unit work, the chunk's own costs spread over its
     # elements); None for every other op
     chunk_cost: Optional[Tuple[float, float]] = None
+    # (complex terms, in-vreg shifts, cross-vreg shifts) per element of an
+    # FFT's butterfly stages (``butterfly_counts``); None for every other op
+    butterfly: Optional[Tuple[int, int, int]] = None
 
     @property
     def stage_count(self) -> int:
@@ -359,6 +401,7 @@ class StagePlan:
         """
         folds = self.shift_folds or (0, 0)
         intra_s, chunk_s = self.chunk_cost or (0.0, 0.0)
+        terms, in_vreg, cross_vreg = self.butterfly or (0, 0, 0)
         return {
             "grid": float(self.grid_size),
             "vmem": float(self.vmem_bytes),
@@ -378,6 +421,9 @@ class StagePlan:
             "vreg_folds": float(folds[1]),
             "intra_s": float(intra_s),
             "chunk_s": float(chunk_s),
+            "butterfly_terms": float(terms),
+            "butterfly_in_vreg": float(in_vreg),
+            "butterfly_cross_vreg": float(cross_vreg),
         }
 
 
@@ -602,7 +648,8 @@ def _fft_fused_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile
         block_bytes=rows * n * eb, element_bytes=eb, trailing=trailing,
         lane_eff=lane, sublane_eff=sub, occupancy=occ,
         ilp=int(cfg.get("unroll", 1)), ragged=is_ragged(stages, radix, n),
-        steps_per_pass=float(len(stages)))
+        steps_per_pass=float(len(stages)),
+        butterfly=butterfly_counts(n, stages, spec.lane_count))
 
 
 def _large_fft_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
@@ -638,7 +685,9 @@ def _large_fft_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile,
         element_bytes=row.element_bytes, trailing=row.trailing,
         lane_eff=row.lane_eff, sublane_eff=row.sublane_eff,
         occupancy=row.occupancy, ilp=row.ilp, ragged=row.ragged,
-        steps_per_pass=row.steps_per_pass, children=(col, row))
+        steps_per_pass=row.steps_per_pass, children=(col, row),
+        # every element passes through one column and one row transform
+        butterfly=tuple(c + r for c, r in zip(col.butterfly, row.butterfly)))
 
 
 def _attention_plan(wl: Workload, cfg: Mapping[str, int], spec: HardwareProfile

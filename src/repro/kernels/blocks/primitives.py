@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.hw.profiles import active_profile
+from repro.kernels.blocks.plan import butterfly_offsets
 
 
 def compiler_params(*dimension_semantics: str) -> pltpu.CompilerParams:
@@ -129,27 +130,26 @@ def dif_coefficients(n: int, stages: Sequence[int], inverse: bool):
     output is a sum over the 2r - 1 lane offsets d*h (d = k - j) of a
     neighbour times a per-lane complex coefficient, zero where k falls
     outside the block.  Returns (re, im) as (rows, n) float32 arrays, one
-    row per (stage, offset), and the offsets per stage.
+    row per (stage, offset), and the offsets per stage
+    (``plan.butterfly_offsets``).
     """
     sign = 1.0 if inverse else -1.0
     lane = np.arange(n)
-    re, im, offsets = [], [], []
+    re, im = [], []
+    offsets = butterfly_offsets(n, tuple(stages))
     span = n
-    for r in stages:
+    for r, stage_offsets in zip(stages, offsets):
         h = span // r
         q, j = lane % h, (lane // h) % r
-        stage_offsets = []
-        for d in range(1 - r, r):
-            k = j + d
+        for off in stage_offsets:
+            k = j + off // h
             ang = sign * 2.0 * np.pi * (((j * k) % r) / r + (j * q) / span)
             live = (k >= 0) & (k < r)
             re.append(np.where(live, np.cos(ang), 0.0))
             im.append(np.where(live, np.sin(ang), 0.0))
-            stage_offsets.append(d * h)
-        offsets.append(tuple(stage_offsets))
         span = h
     return (np.asarray(re, np.float32), np.asarray(im, np.float32),
-            tuple(offsets))
+            offsets)
 
 
 def butterfly(re: jax.Array, im: jax.Array, coef_re, coef_im, row: int,

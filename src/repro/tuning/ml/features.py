@@ -45,7 +45,9 @@ from repro.kernels.blocks.plan import plan_for
 # intra-chunk and per-chunk time instead of by the fewest chunks.
 # v9: the chain-fusion column is gone with its knob: ssd and rglru run one
 # chain each.
-FEATURE_VERSION = 9
+# v10: "ana_rank_pct" orders an FFT's butterfly circuit by its complex
+# terms, then its in-vreg shifts, on barrier-free profiles.
+FEATURE_VERSION = 10
 
 FEATURE_NAMES = (
     # workload (Input Parameters `A`)

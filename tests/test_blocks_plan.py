@@ -17,9 +17,9 @@ from repro.core.space import Workload, build_space
 from repro.hw.profiles import TPU_V5E as V5E
 from repro.kernels.blocks import driver
 from repro.kernels.blocks.plan import (DEFAULT_SEQ_LIMIT, build_plan,
-                                       plan_for, shift_fold_counts,
-                                       stage_radices, stage_strides,
-                                       wm_chunk)
+                                       butterfly_counts, plan_for,
+                                       shift_fold_counts, stage_radices,
+                                       stage_strides, wm_chunk)
 from repro.tuning.registry import normalizer_for
 
 
@@ -57,6 +57,61 @@ def test_stage_radices_prefers_nominal_fan_in():
 def test_shift_fold_counts(tile, radix, folds):
     assert shift_fold_counts(stage_radices(tile, radix), V5E.lane_count) \
         == folds
+
+
+@pytest.mark.parametrize("n,radix,counts", [
+    (4096, 16, (93, 58, 32)),   # spans 256 | 16 | 1: 30 cross | 28 + 2 | 30
+    (4096, 2, (36, 14, 10)),    # spans 2048-128 cross, 64-1 in-vreg
+    (64, 8, (30, 28, 0)),       # a row narrower than a vreg: all in-vreg
+    (256, 16, (62, 58, 2)),
+    (512, 8, (45, 36, 6)),
+    (1024, 4, (35, 22, 8)),
+    (2048, 2, (33, 14, 8)),
+    (96, 8, (29, 26, 0)),       # ragged (8, 6, 2)
+])
+def test_butterfly_counts(n, radix, counts):
+    assert butterfly_counts(n, stage_radices(n, radix), V5E.lane_count) \
+        == counts
+
+
+@pytest.mark.parametrize("n,radix", [(64, 8), (256, 16), (1024, 4),
+                                     (4096, 2), (96, 8)])
+def test_butterfly_counts_follow_the_kernels_coefficient_rows(n, radix):
+    """The counts read the offsets the kernel's coefficient table is
+    built from: one term per row, a shift per nonzero offset."""
+    from repro.kernels.blocks.primitives import dif_coefficients
+    stages = stage_radices(n, radix)
+    coef_re, _, offsets = dif_coefficients(n, stages, False)
+    flat = [off for stage in offsets for off in stage]
+    lanes = V5E.lane_count
+    assert butterfly_counts(n, stages, lanes) == (
+        coef_re.shape[0], sum(1 for o in flat if o % lanes),
+        sum(1 for o in flat if o and o % lanes == 0))
+
+
+def test_fft_plans_report_butterfly_counts():
+    fft = plan_for(Workload(op="fft", n=4096, batch=2**14,
+                            variant="stockham"),
+                   {"rows_per_program": 16, "radix": 16}, profile=V5E)
+    assert fft.butterfly == (93, 58, 32)
+    res = fft.resources()
+    assert (res["butterfly_terms"], res["butterfly_in_vreg"],
+            res["butterfly_cross_vreg"]) == (93.0, 58.0, 32.0)
+    # four-step: every element passes one column and one row transform
+    four = plan_for(Workload(op="large_fft", n=2**20, batch=4,
+                             variant="stockham"),
+                    {"tile_n": 4096, "rows_per_program": 4, "radix": 2},
+                    profile=V5E)
+    col, row = four.children
+    assert four.butterfly == tuple(c + r for c, r in zip(col.butterfly,
+                                                          row.butterfly))
+    assert four.butterfly == (60, 28, 12)
+    for wl in (Workload(op="scan", n=256, batch=8, variant="ks"),
+               Workload(op="tridiag", n=128, batch=8, variant="pcr")):
+        cfg = build_space(wl, V5E).enumerate_valid()[0]
+        plan = plan_for(wl, cfg, profile=V5E)
+        assert plan.butterfly is None
+        assert plan.resources()["butterfly_terms"] == 0.0
 
 
 def test_plans_report_shift_folds_of_their_fold_circuit():

@@ -123,6 +123,25 @@ def test_tpu_scan_suggestion_has_fewest_in_vreg_folds(variant, n):
     assert cfg["radix"] == 2 and folds[2] < min(folds[4], folds[8])
 
 
+@pytest.mark.parametrize("n", (64,) + _PAPER_SCAN_NS)
+def test_tpu_fft_suggestion_has_fewest_butterfly_terms(n):
+    """A butterfly stage's work is its complex terms, so on a barrier-free
+    profile every power-of-two n resolves radix 2, with the most rows the
+    smaller coefficient tables leave room for."""
+    from repro.kernels.blocks.plan import plan_for
+    space, cfg = _paper_suggestion("tpu_v5e", "fft", "stockham", n)
+    wl, spec = space.workload, space.spec
+    counts = {c["radix"]: plan_for(wl, c, profile=spec).butterfly
+              for c in space.enumerate_valid()}
+    best = plan_for(wl, cfg, profile=spec).butterfly
+    assert best == min(counts.values())
+    assert cfg["radix"] == 2
+    assert all(best[0] <= t[0] and best[1] <= t[1] for t in counts.values())
+    assert cfg["rows_per_program"] == max(
+        c["rows_per_program"] for c in space.enumerate_valid()
+        if c["radix"] == 2)
+
+
 @pytest.mark.parametrize("n", _PAPER_SCAN_NS)
 def test_tpu_ks_and_lf_resolve_one_config(n, tmp_path):
     """ks and lf run the same kernel, so one config compiles one program."""
@@ -137,9 +156,9 @@ def test_tpu_ks_and_lf_resolve_one_config(n, tmp_path):
 
 
 # (profile, op, variant, n) -> (tile_n, rows_per_program, radix, unroll,
-# in_register): the suggestions rule 4 gave before it ranked fold
-# circuits.  The GPU profile pays a barrier per stage and keeps the larger
-# radix; FFT and tridiagonal stages keep it on every profile.
+# in_register): the suggestions rule 4 gave before it ranked fold and
+# butterfly circuits.  The GPU profile pays a barrier per stage and keeps
+# the larger radix; tridiagonal stages keep it on every profile.
 _RULE4_PINNED = {
     ("gpu_sm", "scan", "ks", 128): (128, 512, 2, 8, 1),
     ("gpu_sm", "scan", "ks", 256): (256, 512, 4, 8, 0),
@@ -159,13 +178,13 @@ _RULE4_PINNED = {
     ("gpu_sm", "scan", "linrec", 1024): (1024, 64, 4, 1, 0),
     ("gpu_sm", "scan", "linrec", 2048): (2048, 64, 2, 1, 0),
     ("gpu_sm", "scan", "linrec", 4096): (4096, 16, 8, 1, 0),
-    ("tpu_v5e", "fft", "stockham", 64): (64, 256, 8, 4, 0),
-    ("tpu_v5e", "fft", "stockham", 128): (128, 256, 2, 4, 0),
-    ("tpu_v5e", "fft", "stockham", 256): (256, 256, 16, 4, 0),
-    ("tpu_v5e", "fft", "stockham", 512): (512, 256, 8, 4, 0),
-    ("tpu_v5e", "fft", "stockham", 1024): (1024, 128, 4, 4, 0),
-    ("tpu_v5e", "fft", "stockham", 2048): (2048, 128, 2, 4, 0),
-    ("tpu_v5e", "fft", "stockham", 4096): (4096, 16, 16, 4, 0),
+    ("gpu_sm", "fft", "stockham", 64): (64, 256, 8, 4, 0),
+    ("gpu_sm", "fft", "stockham", 128): (128, 256, 2, 4, 0),
+    ("gpu_sm", "fft", "stockham", 256): (256, 64, 16, 4, 0),
+    ("gpu_sm", "fft", "stockham", 512): (512, 64, 8, 4, 0),
+    ("gpu_sm", "fft", "stockham", 1024): (1024, 32, 4, 4, 0),
+    ("gpu_sm", "fft", "stockham", 2048): (2048, 32, 2, 4, 0),
+    ("gpu_sm", "fft", "stockham", 4096): (4096, 4, 8, 4, 0),
     ("tpu_v5e", "tridiag", "pcr", 64): (64, 256, 2, 4, 1),
     ("tpu_v5e", "tridiag", "pcr", 128): (128, 256, 2, 4, 1),
     ("tpu_v5e", "tridiag", "pcr", 256): (256, 256, 2, 4, 1),
